@@ -3,7 +3,7 @@
 Five PRs in, the expensive bug classes moved from checker math into
 harness hygiene: recompile storms from unstable jit-cache keys, donated
 buffers read after donation, host syncs hidden in chunk loops, and the
-thread/event-loop races ADVICE r5 and BENCH_r05 already bit us with
+thread/event-loop races ADVICE r5 and the round-5 bench already bit us with
 (ISSUE 7). Every one is statically detectable; this package detects
 them — AST-only, jax-free, fast enough for tier-1.
 

@@ -3,7 +3,7 @@
 The refactored core of ``tools/check_limits_doc.py`` (which remains as
 a thin CLI shim): every ``KernelLimits`` field must appear in
 doc/perf.md's "KernelLimits reference" table with its
-``[worker]/[arch]/[tunable]`` provenance tag and its ``lo..hi`` safe
+``[envelope]/[arch]/[tunable]`` provenance tag and its ``lo..hi`` safe
 range, both MATCHING ``ops/limits.py field_meta()`` — the autotuner's
 search bounds are the documented bounds, enforced (ISSUE 4; now ISSUE
 7 moves it onto the shared rule-runner so doc lint and code lint share
@@ -68,7 +68,7 @@ def doc_problems(doc_path: Path) -> list[tuple[str, Optional[int], str]]:
             problems.append((name, line0,
                              f"{name}: no table row carries its "
                              f"provenance tag {want_tag} (tags: "
-                             f"[worker]/[arch]/[tunable])"))
+                             f"[envelope]/[arch]/[tunable])"))
         if not has_cell:
             problems.append((name, line0,
                              f"{name}: no table row carries its safe "
@@ -107,7 +107,7 @@ class LimitsDocRule(ProjectRule):
         "contradicting ops/limits.py field_meta drifts the enforced "
         "bounds from the documented ones.")
     hint = ("fix the 'KernelLimits reference' table in doc/perf.md: "
-            "every field needs a row with its [worker]/[arch]/"
+            "every field needs a row with its [envelope]/[arch]/"
             "[tunable] tag and its lo..hi safe range")
     doc_relpath = "doc/perf.md"
 

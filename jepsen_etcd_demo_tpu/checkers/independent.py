@@ -181,12 +181,13 @@ def _batched_linearizable_traced(lin: Linearizable,
         from .. import sched
 
         keys = list(event_encs)
-        batch, _kernel, _stats = sched.check_corpus(
+        batch, kernel, _stats = sched.check_corpus(
             [event_encs[k] for k in keys], lin.model)
         return {
             k: {
                 "valid": one["valid"],
                 "backend": "jax-dense-batched",
+                "kernel": kernel,
                 "op_count": one["op_count"],
                 "dead_step": one["dead_step"],
                 "max_frontier": one["max_frontier"],

@@ -45,7 +45,8 @@ class FakeKVStore:
                  reorder_prob: float = 0.0,
                  duplicate_delivery_prob: float = 0.0,
                  partial_apply_prob: float = 0.5,
-                 op_delay_s: float = 0.0):
+                 op_delay_s: float = 0.0,
+                 timeout_s: float = 5.0):
         self.nodes = nodes or ["n1", "n2", "n3", "n4", "n5"]
         self.data: dict[str, Any] = {}
         self.queues: dict[str, list[Any]] = {}
@@ -59,6 +60,11 @@ class FakeKVStore:
         self.duplicate_delivery_prob = duplicate_delivery_prob
         self.partial_apply_prob = partial_apply_prob
         self.op_delay_s = op_delay_s
+        # How long a client waits on a partitioned node before the op
+        # reports a timeout: the reference client's 5 s. 0 (tests only)
+        # reports at once, which lets one thread pile up an indeterminate
+        # op per generator tick for the whole partition.
+        self.timeout_s = timeout_s
         # jtlint: disable=JTL202 -- lifetime argument: a FakeKVStore is
         # built per test iteration (compose.fake_test constructs a fresh
         # one inside each cmd_test loop turn), so this lock never
@@ -78,6 +84,10 @@ class FakeKVStore:
         if len(self.snapshots) > 64:
             self.snapshots.pop(0)
 
+    async def _time_out(self) -> None:
+        if self.timeout_s:
+            await asyncio.sleep(self.timeout_s)
+
     async def _enter(self, node: str):
         if self.op_delay_s:
             await asyncio.sleep(self.op_delay_s * self.rng.random())
@@ -85,6 +95,7 @@ class FakeKVStore:
             # Partitioned node: the op MAY still land (it raced the
             # partition). Apply-then-timeout gives the checker real
             # indeterminacy to reason about.
+            await self._time_out()
             raise Timeout(f"node {node} partitioned")
 
     # -- the 5-call surface ----------------------------------------------
@@ -100,12 +111,14 @@ class FakeKVStore:
     async def reset(self, node: str, key: str, value: Any) -> None:
         maybe_timeout = node in self.isolated
         if maybe_timeout and self.rng.random() >= self.partial_apply_prob:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned")
         async with self.lock:
             self._snapshot()
             if self.rng.random() >= self.lost_write_prob:
                 self.data[key] = value
         if maybe_timeout:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned (op applied)")
         if self.op_delay_s:
             await asyncio.sleep(self.op_delay_s * self.rng.random())
@@ -113,6 +126,7 @@ class FakeKVStore:
     async def cas(self, node: str, key: str, old: Any, new: Any) -> bool:
         maybe_timeout = node in self.isolated
         if maybe_timeout and self.rng.random() >= self.partial_apply_prob:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned")
         async with self.lock:
             if key not in self.data:
@@ -127,6 +141,7 @@ class FakeKVStore:
                 self._snapshot()
                 self.data[key] = new  # bug: acked :fail but applied
         if maybe_timeout:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned (op applied)")
         if self.op_delay_s:
             await asyncio.sleep(self.op_delay_s * self.rng.random())
@@ -143,6 +158,7 @@ class FakeKVStore:
         elle-detectable anomalies)."""
         maybe_timeout = node in self.isolated
         if maybe_timeout and self.rng.random() >= self.partial_apply_prob:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned")
         out = []
         written: set = set()
@@ -172,6 +188,7 @@ class FakeKVStore:
                 else:
                     raise ValueError(f"unknown micro-op {f!r}")
         if maybe_timeout:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned (txn applied)")
         if self.op_delay_s:
             await asyncio.sleep(self.op_delay_s * self.rng.random())
@@ -186,6 +203,7 @@ class FakeKVStore:
         both surface as elle anomalies (G-single-realtime and friends)."""
         maybe_timeout = node in self.isolated
         if maybe_timeout and self.rng.random() >= self.partial_apply_prob:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned")
         out = []
         overlay: dict = {}   # own writes, so read-your-writes holds even
@@ -213,6 +231,7 @@ class FakeKVStore:
                 else:
                     raise ValueError(f"unknown register micro-op {f!r}")
         if maybe_timeout:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned (txn applied)")
         if self.op_delay_s:
             await asyncio.sleep(self.op_delay_s * self.rng.random())
@@ -225,10 +244,12 @@ class FakeKVStore:
         reset(): on a partitioned node the op may land and then time out."""
         maybe_timeout = node in self.isolated
         if maybe_timeout and self.rng.random() >= self.partial_apply_prob:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned")
         async with self.lock:
             self.queues.setdefault(key, []).append(value)
         if maybe_timeout:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned (op applied)")
         if self.op_delay_s:
             await asyncio.sleep(self.op_delay_s * self.rng.random())
@@ -246,11 +267,13 @@ class FakeKVStore:
                                     (element delivered twice)"""
         maybe_timeout = node in self.isolated
         if maybe_timeout and self.rng.random() >= self.partial_apply_prob:
+            await self._time_out()
             raise Timeout(f"node {node} partitioned")
         async with self.lock:
             q = self.queues.get(key)
             if not q:
                 if maybe_timeout:
+                    await self._time_out()
                     raise Timeout(f"node {node} partitioned")
                 raise NotFound(key)
             i = (self.rng.randrange(len(q))

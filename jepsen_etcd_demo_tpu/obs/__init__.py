@@ -1,8 +1,8 @@
 """obs — harness-wide telemetry: span tracing + metrics + per-run artifacts.
 
 The harness used to be a black box: the only observability was post-hoc
-charts over the op history, so a wedged backend (BENCH_r05's
-`wgl_check_throughput = 0`) had nothing to say about where time went.
+charts over the op history, so a wedged backend had nothing to say
+about where time went.
 This package is the in-band answer — stdlib-only, near-zero-cost when
 idle:
 
@@ -394,9 +394,7 @@ def _capture_kernel_cost(name: str, fn: Callable, args, kwargs,
     the pre-registered zeros stand."""
     try:
         lowered = fn.lower(*args, **kwargs)
-        ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):   # older jax: one per device
-            ca = ca[0] if ca else {}
+        ca = lowered.cost_analysis() or {}
         flops = float(ca.get("flops", 0.0) or 0.0)
         nbytes = float(ca.get("bytes accessed", 0.0) or 0.0)
         if flops > 0:

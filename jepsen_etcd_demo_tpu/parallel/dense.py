@@ -31,10 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..models.base import Model
 from .. import obs
@@ -206,10 +203,8 @@ def sharded_batch_checker_pallas(model: Model, cfg: DenseConfig, mesh: Mesh,
                      in_specs=(P(axis), P(axis, None),
                                P(axis, None, None, None)),
                      out_specs=P(axis, None))
-        try:   # pallas_call out_shapes carry no vma: disable the check
-            sharded = shard_map(local, check_vma=False, **specs)
-        except TypeError:  # older jax names it check_rep
-            sharded = shard_map(local, check_rep=False, **specs)
+        # pallas_call out_shapes carry no vma: disable the check
+        sharded = shard_map(local, check_vma=False, **specs)
         return instrument_kernel("wgl3-pallas-sharded", jax.jit(sharded))
 
     def check(slot_tabs, slot_active, targets):
@@ -219,6 +214,7 @@ def sharded_batch_checker_pallas(model: Model, cfg: DenseConfig, mesh: Mesh,
         cm, tg, ln = prep(slot_tabs, slot_active, targets)
         return sharded_launch(b // d, r)(ln, tg, cm)
 
+    check.sharded_launch = sharded_launch   # the launch half alone
     _CACHE[key] = check
     return check
 
@@ -245,13 +241,9 @@ def batch_multiple(model: Model, cfg: DenseConfig, mesh: Mesh,
 
     axis = _resolve_axis(mesh, axis)
     d = _axis_size(mesh, axis)
-    sp = max(8, (cfg.n_states + 7) // 8 * 8)
-    G = limits().pallas_group
     local_batch = None if batch is None else (batch + d - 1) // d
-    if (sp == 8 and G > 1 and local_batch is not None and local_batch >= G
-            and wgl3_pallas.use_pallas(
-                cfg, n_steps, (local_batch + G - 1) // G * G)):
-        return d * G
+    if wgl3_pallas.use_pallas(cfg, n_steps):
+        return d * wgl3_pallas.pallas_group_size(cfg, n_steps, local_batch)
     return d
 
 

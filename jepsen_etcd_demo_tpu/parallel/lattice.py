@@ -47,10 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..models.base import Model
 from ..obs import instrument_kernel, record_check_result
@@ -528,10 +525,7 @@ def make_lattice_chunk_fn(model: Model, cfg: DenseConfig, mesh: Mesh,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(None, axis), P(), P(), P(), P()))
-    try:
-        sharded = shard_map(run, check_vma=False, **specs)
-    except TypeError:
-        sharded = shard_map(run, check_rep=False, **specs)
+    sharded = shard_map(run, check_vma=False, **specs)
     # obs/ compile/execute attribution (the PR 1 invariant, enforced by
     # jtlint JTL105): this lane shipped uninstrumented in PR 3 — under
     # virtual-device CI it IS the production wide-geometry path.

@@ -299,9 +299,9 @@ def plan_dense_batch(model, cfg, n_steps: Optional[int] = None,
     Sp=8 models: wider states spill Mosaic's scoped VMEM at full group
     size, and the reduced group that fits (G=4 at Sp=32) measured 14%
     SLOWER than per-history. Small batches stay per-history (grouping
-    would pad them with dead work), and feasibility is checked for the
-    PADDED batch — grouping rounds B up to a G multiple and the
-    prefetch envelope is a worker-kill edge."""
+    would pad them with dead work). A batch whose targets overflow SMEM
+    runs as several launches inside the kernel's jit
+    (wgl3_pallas.launch_parts), never on a different kernel."""
     import jax
 
     from ..ops import wgl3_pallas
@@ -325,11 +325,9 @@ def plan_dense_batch(model, cfg, n_steps: Optional[int] = None,
             MeshSpec.from_mesh(mesh)
         d = spec.total
         local_batch = None if batch is None else (batch + d - 1) // d
-        if wgl3_pallas.use_pallas(cfg, n_steps, local_batch):
-            G = limits().pallas_group
-            sp = max(8, (cfg.n_states + 7) // 8 * 8)
-            if (sp == 8 and G > 1 and local_batch is not None
-                    and local_batch >= G and local_batch % G == 0):
+        if wgl3_pallas.use_pallas(cfg, n_steps):
+            G = wgl3_pallas.pallas_group_size(cfg, n_steps, local_batch)
+            if G > 1 and local_batch % G == 0:
                 return build_plan(
                     "wgl3-pallas-sharded", model, cfg,
                     label="wgl3-dense-pallas-grouped-sharded",
@@ -342,12 +340,9 @@ def plan_dense_batch(model, cfg, n_steps: Optional[int] = None,
         return build_plan(
             "wgl3-dense-sharded", model, cfg, label="wgl3-dense-sharded",
             n_steps=n_steps, batch=batch, mesh=spec, provenance=prov)
-    if wgl3_pallas.use_pallas(cfg, n_steps, batch):
-        G = limits().pallas_group
-        sp = max(8, (cfg.n_states + 7) // 8 * 8)
-        b_pad = None if batch is None else (batch + G - 1) // G * G
-        if (sp == 8 and G > 1 and batch is not None and batch >= G
-                and wgl3_pallas.pallas_feasible(cfg, n_steps, b_pad)):
+    if wgl3_pallas.use_pallas(cfg, n_steps):
+        G = wgl3_pallas.pallas_group_size(cfg, n_steps, batch)
+        if G > 1:
             return build_plan("wgl3-pallas-grouped", model, cfg,
                               label="wgl3-dense-pallas-grouped",
                               n_steps=n_steps, batch=batch, group=G,

@@ -423,20 +423,15 @@ async def _run_test_inner(test: dict, store) -> dict:
 
     checker = test.get("checker")
     opts = {"store_dir": str(store.path)} if store else {}
-    # Check phase compiles WGL kernels: point jax's persistent compile
-    # cache under the store first (sched/compile_cache.py; idempotent —
-    # a CLI-level enable wins), so embedding callers of run_test get the
-    # cross-process compile reuse too, not only `jepsen-tpu test`.
+    # Check phase compiles WGL kernels into the one persistent compile
+    # cache (sched/compile_cache.py; idempotent — a CLI-level enable
+    # wins), so embedding callers of run_test get the cross-process
+    # compile reuse too. A completed check is a passive health proof
+    # (obs/health.py) feeding /healthz.
     from ..sched import enable_persistent_cache
 
-    enable_persistent_cache(test.get("store_root"))
-    # Backend health (obs/health.py): the check phase periodically
-    # drives the supervisor's active probe (rate-limited — a fresh
-    # process never pays the subprocess inside its first interval), and
-    # a completed check is a passive health proof. The supervisor's
-    # state feeds /healthz and the bench record.
+    enable_persistent_cache()
     supervisor = obs.health.get_supervisor()
-    supervisor.maybe_probe(source="runner.check")
     with tracer.span("check") as sp, \
             obs.maybe_jax_trace(store.path if store else None):
         if session is not None:

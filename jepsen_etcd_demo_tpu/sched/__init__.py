@@ -8,7 +8,7 @@ the hardware allows:
                        bounded compilations per kernel)
   * pipeline.py      — double-buffered chunk pipelining primitives used
                        by the resumable sweeps in ops/wgl2 + ops/wgl3
-  * compile_cache.py — the persistent (on-disk, JEPSEN_TPU_COMPILE_CACHE)
+  * compile_cache.py — the persistent (on-disk, one fixed directory)
                        and in-process (per-bucket-shape LRU) compilation
                        caches, with the hit accounting behind the bench's
                        cache_hit_rate field

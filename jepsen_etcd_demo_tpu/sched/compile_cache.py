@@ -2,19 +2,11 @@
 
 1. The PERSISTENT cache: jax's on-disk compilation cache, so the second
    process-lifetime run of any (kernel, geometry, bucket shape) skips the
-   XLA compile tax entirely (bench_100k.json measured it at ~3.3 s of the
-   3.9 s cold start). Directory precedence:
-
-     JEPSEN_TPU_COMPILE_CACHE          explicit harness-level override
-     JAX_COMPILATION_CACHE_DIR         the stock jax env var
-     <store_root>/.xla-cache           when a store root is known (the
-                                       cache travels with the results it
-                                       accelerated re-checking)
-     ~/.cache/jepsen_tpu_xla           per-user fallback
-
-   JEPSEN_TPU_NO_COMPILE_CACHE=1 disables it. Enabling is idempotent and
-   first-caller-wins within a process (jax reads the config at compile
-   time; flipping directories mid-process would split the cache).
+   XLA compile tax. ONE directory for every caller: JAX_COMPILATION_CACHE_DIR
+   when the environment sets it, else the fixed ``<checkout>/.xla-cache``
+   (git-ignored). The path is part of the cache key, so it never moves
+   with a store or a temp dir. JEPSEN_TPU_NO_COMPILE_CACHE=1 disables it.
+   Enabling is idempotent within a process.
 
 2. The IN-PROCESS kernel LRU: one resolved checker callable per
    (kernel, model, bucket-shape) key, with hit/miss accounting surfaced
@@ -39,39 +31,34 @@ _enabled_dir: str | None = None
 _enable_lock = threading.Lock()
 
 
-def compile_cache_dir(store_root: str | os.PathLike | None = None) -> str:
-    env = os.environ.get("JEPSEN_TPU_COMPILE_CACHE")
-    if env:
-        return env
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env:
-        return env
-    if store_root is not None:
-        return os.path.join(str(store_root), ".xla-cache")
-    return os.path.expanduser("~/.cache/jepsen_tpu_xla")
+# The checkout root: the directory holding the package.
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def enable_persistent_cache(store_root: str | os.PathLike | None = None
-                            ) -> str | None:
+def compile_cache_dir() -> str:
+    """THE persistent compile-cache directory (module docstring); the
+    tuned profile and the calibration record live next to it."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(CHECKOUT, ".xla-cache"))
+
+
+def enable_persistent_cache() -> str | None:
     """Point jax's persistent compilation cache at compile_cache_dir().
-    Returns the active directory (None when disabled/unavailable)."""
+    Returns the active directory (None when disabled)."""
     global _enabled_dir
     if os.environ.get("JEPSEN_TPU_NO_COMPILE_CACHE"):
         return None
     with _enable_lock:
-        if _enabled_dir is not None:
-            return _enabled_dir
-        try:
+        if _enabled_dir is None:
             import jax
 
-            cache_dir = compile_cache_dir(store_root)
+            cache_dir = compile_cache_dir()
             os.makedirs(cache_dir, exist_ok=True)
             jax.config.update("jax_compilation_cache_dir", cache_dir)
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", 0.5)
             _enabled_dir = cache_dir
-        except Exception:   # the cache is an optimization, never a failure
-            return None
         return _enabled_dir
 
 

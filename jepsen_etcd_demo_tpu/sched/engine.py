@@ -258,12 +258,9 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
 
     with obs.get_tracer().span("sched.check_corpus",
                                histories=len(encs)) as sp:
-        # Backend health (obs/health.py): corpus dispatch is one of the
-        # supervisor's periodic drivers — rate-limited active probe on
-        # entry (a no-op inside the probe interval), passive ok/failure
-        # notes from the launch drain below.
+        # Backend health (obs/health.py): passive ok/failure notes from
+        # the launch drain below.
         supervisor = obs.health.get_supervisor()
-        supervisor.maybe_probe(source="sched.dispatch")
         results: list[Any] = [None] * len(encs)
         kernels: set[str] = set()
 

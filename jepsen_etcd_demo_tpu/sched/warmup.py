@@ -60,7 +60,6 @@ def step_rungs(n: int, floor: Optional[int] = None) -> list[int]:
 
 def warmup_plans(model=None, mesh=None, *, k_slots: int = 16,
                  rungs: int = 2, max_value: int = 8,
-                 store_root: Optional[str] = None,
                  encoder: bool = True) -> dict[str, Any]:
     """Pre-compile the plan-family corpus for this platform into the
     persistent XLA cache. Returns the warmup record: per-family launch
@@ -80,7 +79,7 @@ def warmup_plans(model=None, mesh=None, *, k_slots: int = 16,
     from ..plan import plan_dense_batch, resolve
 
     t0 = time.monotonic()
-    cache_dir = enable_persistent_cache(store_root)
+    cache_dir = enable_persistent_cache()
     if model is None:
         model = CASRegister()
     if mesh is None:
@@ -146,8 +145,7 @@ def warmup_plans(model=None, mesh=None, *, k_slots: int = 16,
     }
 
 
-def startup_warmup(store_root: Optional[str] = None, *,
-                   source: str = "startup") -> Optional[dict]:
+def startup_warmup(*, source: str = "startup") -> Optional[dict]:
     """The serve/campaign startup hook: one cheap rung through
     warmup_plans, swallowing every failure (a warmup must never take a
     daemon down) and honoring the JEPSEN_TPU_NO_WARMUP kill switch.
@@ -155,7 +153,7 @@ def startup_warmup(store_root: Optional[str] = None, *,
     if os.environ.get(NO_WARMUP_ENV):
         return None
     try:
-        rec = warmup_plans(rungs=1, store_root=store_root)
+        rec = warmup_plans(rungs=1)
     except Exception as e:   # never fatal — warmup is an optimization
         print(f"warmup ({source}): skipped ({type(e).__name__}: {e})",
               file=sys.stderr)

@@ -16,11 +16,18 @@ a three-line protocol:
 3. drain the old replica (poll /serve/stats until pending+inflight hit
    zero, bounded) and only then terminate it — in-flight verdicts land.
 
-Every replica shares one store root, which is the fleet-wide warm
-state: one persistent XLA compile cache (<store>/.xla-cache — passed
-explicitly via JEPSEN_TPU_COMPILE_CACHE so sharing never depends on a
-warmup's side effects) and one O_EXCL-locked tuned-profile file next to
-it (tune/profile.py), so one replica's tune benefits all.
+Every replica shares the fleet-wide warm state: the one persistent XLA
+compile cache (sched/compile_cache.compile_cache_dir) and the
+O_EXCL-locked tuned-profile file next to it (tune/profile.py), so one
+replica's tune benefits all.
+
+Replicas are separate processes, and a TPU chip belongs to one process
+at a time: every replica on an accelerator would claim every chip. So
+the supervisor runs several replicas only on the CPU backend
+(JAX_PLATFORMS=cpu in the replicas' env). Otherwise `spawn_replica`
+refuses to start a replica while another is alive
+(:class:`FleetPlatformError`), and a restart stops the old replica
+before its replacement starts — one process per host, with downtime.
 
 `serve_fleet` is the CLI entry (`jepsen-tpu serve --check --fleet`):
 supervisor + router + the fleet HTTP surface (web/server.py StoreHandler
@@ -55,6 +62,10 @@ READY_TIMEOUT_S = 180.0
 #: Drain bound for a replaced replica: in-flight verdicts get this long
 #: to land before the old process is terminated anyway.
 DRAIN_TIMEOUT_S = 60.0
+
+
+class FleetPlatformError(RuntimeError):
+    """More than one replica process asked for on an accelerator."""
 
 
 class ReplicaProc:
@@ -168,21 +179,46 @@ class FleetSupervisor:
         env["PYTHONPATH"] = pkg_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH")
             else "")
-        # Fleet-wide warm state: pin every replica's persistent XLA
-        # cache (and therefore the tuned-profile file next to it) to
-        # the shared store root, unless the operator pinned it already.
-        env.setdefault("JEPSEN_TPU_COMPILE_CACHE",
-                       str(Path(self.store_root) / ".xla-cache"))
         env.update(self.env_overrides)
         return env
 
+    def _on_accelerator(self) -> bool:
+        return self._child_env().get("JAX_PLATFORMS", "").lower() != "cpu"
+
+    def _platform_error(self, what: str) -> FleetPlatformError:
+        platforms = self._child_env().get("JAX_PLATFORMS", "")
+        return FleetPlatformError(
+            f"refusing to start {what} on "
+            f"JAX_PLATFORMS={platforms or '<default accelerator>'}: "
+            f"each replica process would claim every chip, and a chip "
+            f"serves one process. Run one replica per host, or set "
+            f"JAX_PLATFORMS=cpu for a CPU fleet.")
+
     def spawn_replica(self) -> ReplicaProc:
-        """Start one `serve --check` subprocess (not yet routed)."""
+        """Start one `serve --check` subprocess (not yet routed). Off
+        the CPU backend, refuses while another replica is alive: the
+        check, the spawn and the registration are one critical section,
+        so two concurrent spawns cannot both pass."""
+        accel = self._on_accelerator()
+        env = self._child_env()
         with self._lock:
+            live = [r for r, rp in self._procs.items()
+                    if rp.proc.poll() is None]
+            if accel and live:
+                raise self._platform_error(
+                    f"a serve replica beside live replica(s) {live}")
             rid = f"r{self._seq}"
             self._seq += 1
-        ready_file = os.path.join(self._tmpdir, f"{rid}.ready.json")
-        log_path = os.path.join(self._tmpdir, f"{rid}.log")
+            ready_file = os.path.join(self._tmpdir, f"{rid}.ready.json")
+            log_path = os.path.join(self._tmpdir, f"{rid}.log")
+            with open(log_path, "wb") as logf:
+                proc = subprocess.Popen(self._replica_cmd(ready_file),
+                                        stdout=logf, stderr=logf, env=env)
+            rp = ReplicaProc(rid, proc, ready_file, log_path)
+            self._procs[rid] = rp
+        return rp
+
+    def _replica_cmd(self, ready_file: str) -> list[str]:
         cmd = [sys.executable, "-m", "jepsen_etcd_demo_tpu.cli.main",
                "serve", "--check", "--host", self.host, "--port", "0",
                "--store", self.store_root, "--model", self.default_model,
@@ -193,31 +229,29 @@ class FleetSupervisor:
             cmd += ["--max-batch", str(self.max_batch)]
         if self.max_inflight is not None:
             cmd += ["--max-inflight", str(self.max_inflight)]
-        logf = open(log_path, "wb")
-        try:
-            proc = subprocess.Popen(cmd, stdout=logf, stderr=logf,
-                                    env=self._child_env())
-        finally:
-            logf.close()
-        rp = ReplicaProc(rid, proc, ready_file, log_path)
-        with self._lock:
-            self._procs[rid] = rp
-        return rp
+        return cmd
 
     def start(self) -> None:
         """Spawn the fleet, wait for every ready-file, admit everyone
         READY, start the router's health poller."""
+        if self.n > 1 and self._on_accelerator():
+            raise self._platform_error(f"{self.n} serve replicas")
         procs = [self.spawn_replica() for _ in range(self.n)]
         for rp in procs:
-            rec = rp.wait_ready(self.ready_timeout_s)
-            url = rec["serving"]
-            with self._lock:
-                rp.url = url
-            if self.warm_corpus:
-                self.warm_replica(url)
-            self.router.add_replica(url, rid=rp.id, state=READY)
+            self.router.add_replica(self._bring_up(rp), rid=rp.id,
+                                    state=READY)
         self.router.refresh_gauges()
         self.router.start()
+
+    def _bring_up(self, rp: ReplicaProc) -> str:
+        """Wait for a spawned replica's ready-file, publish its url and
+        warm it; returns the url."""
+        url = rp.wait_ready(self.ready_timeout_s)["serving"]
+        with self._lock:
+            rp.url = url
+        if self.warm_corpus:
+            self.warm_replica(url)
+        return url
 
     def adopt(self, url: str, rid: Optional[str] = None):
         """Route to a replica this supervisor did not spawn (it owns
@@ -253,23 +287,33 @@ class FleetSupervisor:
 
     def restart_replica(self, rid: str) -> str:
         """Zero-downtime restart: replacement up + warm first, swap the
-        hash slice, drain the old replica, then terminate it. Returns
+        hash slice, drain the old replica, then terminate it. Off the
+        CPU backend the two cannot run at once (one process per chip):
+        the old replica is evicted, drained and stopped first. Returns
         the replacement's id."""
         with self._lock:
             if rid not in self._procs:
                 raise KeyError(f"no replica {rid!r}")
+        if self._on_accelerator():
+            self.router.remove_replica(rid)
+            self._retire(rid)
+            new = self.spawn_replica()
+            self.router.add_replica(self._bring_up(new), rid=new.id,
+                                    state=READY)
+            obs.get_metrics().counter("fleet.restarts").add(1)
+            self.router.refresh_gauges()
+            return new.id
         new = self.spawn_replica()
-        rec = new.wait_ready(self.ready_timeout_s)
-        new_url = rec["serving"]
-        with self._lock:
-            new.url = new_url
-        if self.warm_corpus:
-            self.warm_replica(new_url)
-        self.router.swap_replica(rid, new_url, rid=new.id)
+        self.router.swap_replica(rid, self._bring_up(new), rid=new.id)
         self.router.refresh_gauges()
-        # Re-validate under this acquisition and bind what the dict
-        # actually holds (JTL503): the drained/terminated process is
-        # exactly the one popped, not the earlier peek.
+        self._retire(rid)
+        return new.id
+
+    def _retire(self, rid: str) -> None:
+        """Drain a replica the router no longer routes to, then stop it.
+        Re-validate under this acquisition and bind what the dict
+        actually holds (JTL503): the drained/terminated process is
+        exactly the one popped, not an earlier peek."""
         with self._lock:
             old = self._procs.pop(rid, None)
             old_url = old.url if old is not None else None
@@ -277,12 +321,12 @@ class FleetSupervisor:
             if old_url:
                 self._drain(old_url)
             old.terminate()
-        return new.id
 
     def rolling_restart(self) -> list[str]:
         """Restart every replica one at a time (config/code rollout):
-        the fleet never drops below n-0 routable replicas because each
-        replacement is admitted before its predecessor drains."""
+        on the CPU backend the fleet never drops below n-0 routable
+        replicas because each replacement is admitted before its
+        predecessor drains."""
         with self._lock:
             rids = list(self._procs)
         return [self.restart_replica(rid) for rid in rids]

@@ -11,7 +11,7 @@ Groups (the `group` metadata on KernelLimits fields, ops/limits.py):
   dense_sweep  — the host-chunked dense long sweep
                  (wgl3.check_steps3_long): events/s vs `long_scan_chunk`
                  and `dense_cell_budget_chunked` (conservative-down
-                 candidates only — [worker] envelope fields).
+                 candidates only — [envelope] envelope fields).
   sparse       — the sparse active-tile engine's crossover
                  (ops/wgl3_sparse.py): live-tile density sweep tuning
                  `sparse_density_threshold_pct` / `sparse_min_tiles`

@@ -16,7 +16,7 @@ not search-space cleverness:
     the defaults for everything not yet measured (a partial profile is
     valid — un-searched knobs simply stay at their dataclass defaults);
   * safety envelopes — candidates come from each field's safe range
-    (ops/limits.py field metadata); [worker] fields are additionally
+    (ops/limits.py field metadata); [envelope] fields are additionally
     clamped to the conservative side of their default, so the tuner can
     never produce a profile that probes PAST a kill threshold the
     default encodes;
@@ -89,7 +89,7 @@ def resolve_knobs(spec: str | None) -> list[str]:
 def candidates_for(name: str, probe) -> list[int]:
     """Candidate values: the probe's geometry-aware list when it offers
     one, else a multiplicative ladder around the default — always
-    clamped to the safe range, and for [worker] fields to the
+    clamped to the safe range, and for [envelope] fields to the
     conservative side of the default."""
     m = field_meta()[name]
     default = m["default"]

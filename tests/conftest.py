@@ -9,7 +9,15 @@ from jepsen_etcd_demo_tpu.utils.platform import force_virtual_cpu
 
 force_virtual_cpu(8)
 
+import os  # noqa: E402
 import random  # noqa: E402
+import tempfile  # noqa: E402
+
+# The program keeps its compile cache (and the tuned profile beside it)
+# where JAX_COMPILATION_CACHE_DIR says; a test worker's own temp dir
+# keeps CPU-compiled entries out of the checkout's .xla-cache.
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                      tempfile.mkdtemp(prefix="jt-xla-cache-"))
 
 import pytest  # noqa: E402
 

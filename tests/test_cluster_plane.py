@@ -748,3 +748,27 @@ def test_swap_retry_exhaustion_is_determinate_fail():
             await contended_swap()
 
     asyncio.run(scenario())
+
+
+def test_fake_partitioned_op_waits_the_client_timeout():
+    """A partitioned op reports its timeout only after timeout_s, as a
+    real client would: with 0 a thread turns the whole partition into a
+    stream of forever-pending ops (one per generator tick)."""
+    import time
+
+    from jepsen_etcd_demo_tpu.clients.fake_kv import FakeKVStore
+
+    async def run(store):
+        store.isolate({"n1"})
+        t0 = time.monotonic()
+        with pytest.raises(Timeout):
+            await store.reset("n1", "k", 1)
+        return time.monotonic() - t0
+
+    assert asyncio.run(run(FakeKVStore(seed=1, timeout_s=0.2))) >= 0.2
+    assert asyncio.run(run(FakeKVStore(seed=1, timeout_s=0))) < 0.2
+    # The default is the reference client's 5 s, and the fake
+    # composition (CLI --fake and campaigns alike) keeps it.
+    from jepsen_etcd_demo_tpu.compose import fake_test
+    assert FakeKVStore().timeout_s == 5.0
+    assert fake_test({"no_nemesis": True})["fake_store"].timeout_s == 5.0

@@ -74,8 +74,7 @@ def _get_url(url, timeout=30):
 
 @pytest.fixture
 def healthy_supervisor():
-    fake = health.BackendSupervisor(probe=lambda: (True, "", False),
-                                    probe_interval_s=3600.0)
+    fake = health.BackendSupervisor()
     prev = health.reset_supervisor(fake)
     try:
         yield fake
@@ -568,3 +567,73 @@ class TestBenchFleetLane:
                "fleet": obs.fleet_stats(None),
                "detail": {"fleet": lane}}
         assert bench_compare.check_fleet_record(rec) == []
+
+
+class TestFleetPlatformGuard:
+    def test_refuses_several_replicas_on_an_accelerator(self, tmp_path):
+        """Each replica process would claim every chip: off the CPU
+        backend the supervisor refuses more than one, before spawning."""
+        from jepsen_etcd_demo_tpu.serve.fleet import FleetPlatformError
+
+        sup = FleetSupervisor(str(tmp_path / "store"), n=2,
+                              env={"JAX_PLATFORMS": "tpu"})
+        with pytest.raises(FleetPlatformError, match="2 serve replicas"):
+            sup.start()
+        assert sup.replica_urls() == {}
+
+    @staticmethod
+    def _live_replica(sup, rid: str):
+        """Register a stand-in replica process (alive, never ready)."""
+        import subprocess
+        import sys
+
+        from jepsen_etcd_demo_tpu.serve.fleet import ReplicaProc
+
+        rp = ReplicaProc(rid, subprocess.Popen(
+            [sys.executable, "-c", "import time; time.sleep(120)"]), "", "")
+        with sup._lock:
+            sup._procs[rid] = rp
+        return rp
+
+    def test_spawn_refuses_a_second_live_replica_on_an_accelerator(
+            self, tmp_path):
+        """The guard sits in spawn_replica, so no path (start, restart,
+        rolling restart) starts a replica beside a live one off CPU."""
+        from jepsen_etcd_demo_tpu.serve.fleet import FleetPlatformError
+
+        sup = FleetSupervisor(str(tmp_path / "store"), n=1,
+                              env={"JAX_PLATFORMS": "tpu"})
+        try:
+            self._live_replica(sup, "r0")
+            with pytest.raises(FleetPlatformError, match="beside live"):
+                sup.spawn_replica()
+            assert list(sup._procs) == ["r0"]
+        finally:
+            sup.close()
+
+    def test_restart_on_an_accelerator_stops_the_old_replica_first(
+            self, tmp_path, monkeypatch):
+        from jepsen_etcd_demo_tpu.serve.fleet import ReplicaProc
+
+        sup = FleetSupervisor(str(tmp_path / "store"), n=1,
+                              env={"JAX_PLATFORMS": "tpu"})
+        old = self._live_replica(sup, "r0")
+        sup.router.add_replica("http://127.0.0.1:9", rid="r0", state=READY)
+        old_exit_at_spawn = []
+
+        def spawn():
+            old_exit_at_spawn.append(old.proc.poll())
+            rp = ReplicaProc("r1", old.proc, "", "")
+            with sup._lock:
+                sup._procs["r1"] = rp
+            return rp
+
+        monkeypatch.setattr(sup, "spawn_replica", spawn)
+        monkeypatch.setattr(sup, "_bring_up",
+                            lambda rp: "http://127.0.0.1:10")
+        try:
+            assert sup.rolling_restart() == ["r1"]
+            assert old_exit_at_spawn[0] is not None
+            assert sup.router.replica_ids() == ["r1"]
+        finally:
+            sup.close()

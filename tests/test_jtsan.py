@@ -310,8 +310,7 @@ def test_witnessed_lock_orders_are_predicted_under_serve_load(
     monkeypatch.setenv(obs_sync.SYNC_TRACE_ENV, "1")
     obs_sync.reset_witness()
     # Constructed AFTER the env gate so every lock is wrapped.
-    fake = health.BackendSupervisor(probe=lambda: (True, "", False),
-                                    probe_interval_s=3600.0)
+    fake = health.BackendSupervisor()
     prev = health.reset_supervisor(fake)
     try:
         with obs.capture() as cap:
@@ -404,8 +403,7 @@ def test_model_for_returns_one_instance_under_race(monkeypatch):
     from jepsen_etcd_demo_tpu.obs import health
     from jepsen_etcd_demo_tpu.serve import CoalescingScheduler
 
-    fake = health.BackendSupervisor(probe=lambda: (True, "", False),
-                                    probe_interval_s=3600.0)
+    fake = health.BackendSupervisor()
     prev = health.reset_supervisor(fake)
     try:
         s = CoalescingScheduler(coalesce_ms=5, max_batch=2)
@@ -460,8 +458,7 @@ def test_daemon_close_finalizes_open_sessions(tmp_path):
     from jepsen_etcd_demo_tpu.obs import health
     from jepsen_etcd_demo_tpu.serve import ServeDaemon
 
-    fake = health.BackendSupervisor(probe=lambda: (True, "", False),
-                                    probe_interval_s=3600.0)
+    fake = health.BackendSupervisor()
     prev = health.reset_supervisor(fake)
     try:
         d = ServeDaemon(store_root=str(tmp_path / "store"),

@@ -1,6 +1,6 @@
 """Tier-1 wiring of tools/check_limits_doc.py: every KernelLimits field
 (ops/limits.py) must appear — as a backticked code span — in doc/perf.md's
-"KernelLimits reference" table, WITH its [worker]/[arch]/[tunable]
+"KernelLimits reference" table, WITH its [envelope]/[arch]/[tunable]
 provenance tag and its lo..hi safe range matching the dataclass field
 metadata (ISSUE 4 satellite: the autotuner's search bounds are the
 documented bounds, enforced)."""
@@ -45,12 +45,12 @@ def test_lint_detects_wrong_tag(tmp_path):
     doc = tmp_path / "perf.md"
     text = check_limits_doc.DOC.read_text(encoding="utf-8")
     bad = text.replace(
-        "| `long_scan_chunk` | [worker]",
+        "| `long_scan_chunk` | [envelope]",
         "| `long_scan_chunk` | [tunable]")
     assert bad != text
     doc.write_text(bad)
     errs = check_limits_doc.doc_errors(doc)
-    assert any("long_scan_chunk" in e and "[worker]" in e for e in errs)
+    assert any("long_scan_chunk" in e and "[envelope]" in e for e in errs)
 
 
 def test_lint_detects_wrong_range(tmp_path):

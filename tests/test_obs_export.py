@@ -1,6 +1,6 @@
 """Live observability plane (ISSUE 8): Prometheus exposition golden
 output, subscription-bus ordering under concurrent writers, backend
-health state-machine transitions with a fake probe, /metrics /healthz
+health state-machine transitions from passive signals, /metrics /healthz
 /live(+SSE) endpoint smoke on the web harness, and the kernel_phases
 flops/bytes contract on the CPU path."""
 
@@ -172,8 +172,7 @@ class TestSubscriptionBus:
 
 class TestHealthStateMachine:
     def test_consecutive_failures_walk_degraded_then_wedged(self):
-        sup = health.BackendSupervisor(probe=lambda: (True, "", False),
-                                       fail_degraded=1, fail_wedged=3)
+        sup = health.BackendSupervisor(fail_degraded=1, fail_wedged=3)
         assert sup.state == health.HEALTHY
         sup.note_failure("err A", source="test")
         assert sup.state == health.DEGRADED
@@ -188,24 +187,18 @@ class TestHealthStateMachine:
         assert sup.state == health.WEDGED
         assert sup.snapshot()["consecutive_failures"] == 3
 
-    def test_probe_timeout_escalates_straight_to_wedged_and_back(self):
-        """The acceptance shape: a simulated wedged-backend probe drives
-        healthy -> wedged, recovery drives it back."""
-        outcomes = iter([
-            (False, "trivial jit round trip exceeded 1s — remote TPU "
-                    "tunnel down/wedged?", True),    # timeout
-            (True, "", False),                       # recovered
-        ])
-        sup = health.BackendSupervisor(probe=lambda: next(outcomes))
-        assert sup.probe(source="test") is False
+    def test_wedged_failure_escalates_straight_to_wedged_and_back(self):
+        """A caller that saw the device hang (wedged=True) drives
+        healthy -> wedged at once; any later success drives it back."""
+        sup = health.BackendSupervisor()
+        sup.note_failure("dispatch hung", source="test", wedged=True)
         assert sup.state == health.WEDGED
         lt = sup.snapshot()["last_transition"]
         assert lt["from"] == "healthy" and lt["to"] == "wedged"
-        assert sup.probe(source="test") is True
+        sup.note_ok(source="test")
         assert sup.state == health.HEALTHY
         lt = sup.snapshot()["last_transition"]
         assert lt["from"] == "wedged" and lt["to"] == "healthy"
-        assert sup.snapshot()["probes_run"] == 2
 
     def test_success_resets_consecutive_failures(self):
         sup = health.BackendSupervisor(fail_degraded=2, fail_wedged=3)
@@ -214,27 +207,6 @@ class TestHealthStateMachine:
         sup.note_failure("y")
         assert sup.state == health.HEALTHY   # streak broken in between
         assert sup.snapshot()["consecutive_failures"] == 1
-
-    def test_maybe_probe_is_rate_limited(self):
-        calls = []
-        sup = health.BackendSupervisor(
-            probe=lambda: calls.append(1) or (True, "", False),
-            probe_interval_s=3600.0)
-        # Inside the first interval: never probes (fresh processes pay
-        # nothing), with or without repeated calls.
-        assert sup.maybe_probe() is None
-        assert sup.maybe_probe() is None
-        assert calls == []
-        sup._last_probe_mono -= 7200.0   # age the clock past the interval
-        assert sup.maybe_probe() is True
-        assert calls == [1]
-
-    def test_maybe_probe_env_disable(self, monkeypatch):
-        monkeypatch.setenv("JEPSEN_TPU_HEALTH_PROBE", "0")
-        sup = health.BackendSupervisor(
-            probe=lambda: (_ for _ in ()).throw(AssertionError("probed")))
-        sup._last_probe_mono -= 7200.0
-        assert sup.maybe_probe() is None
 
     def test_transitions_recorded_as_obs_events_and_gauge(self):
         with obs.capture() as cap:
@@ -249,7 +221,7 @@ class TestHealthStateMachine:
         assert snap["health.state"]["max"] == 1.0    # visited degraded
 
     def test_process_supervisor_swap(self):
-        fake = health.BackendSupervisor(probe=lambda: (True, "", False))
+        fake = health.BackendSupervisor()
         prev = health.reset_supervisor(fake)
         try:
             assert health.get_supervisor() is fake
@@ -310,7 +282,7 @@ class TestWebEndpoints:
         # Drive the supervisor wedged: /healthz turns 503 and carries
         # the transition provenance.
         health.get_supervisor().note_failure(
-            "jit probe timeout", source="test", wedged=True)
+            "dispatch hung", source="test", wedged=True)
         try:
             with pytest.raises(urllib.error.HTTPError) as e:
                 urllib.request.urlopen(web_server + "/healthz")
@@ -318,7 +290,7 @@ class TestWebEndpoints:
             hz = json.load(e.value)
             assert hz["status"] == "wedged"
             assert hz["last_transition"]["to"] == "wedged"
-            assert "jit probe timeout" in hz["last_transition"]["reason"]
+            assert "dispatch hung" in hz["last_transition"]["reason"]
         finally:
             health.get_supervisor().note_ok(source="test")
 

@@ -290,8 +290,7 @@ def test_launch_pipeline_default_depth_is_knob():
 def test_warmup_plans_record_passes_ledger_contract(tmp_path):
     from jepsen_etcd_demo_tpu.sched import warmup_plans
 
-    rec = warmup_plans(rungs=1, k_slots=8,
-                       store_root=str(tmp_path / "store"))
+    rec = warmup_plans(rungs=1, k_slots=8)
     assert rec["launches"] >= 1 and rec["value"] == rec["launches"]
     assert any(f.startswith("wgl3-dense") for f in rec["families"])
     assert rec["cache_dir"] is None or Path(rec["cache_dir"]).exists()
@@ -306,7 +305,7 @@ def test_warmup_env_kill_switch(tmp_path, monkeypatch):
     from jepsen_etcd_demo_tpu.sched.warmup import NO_WARMUP_ENV
 
     monkeypatch.setenv(NO_WARMUP_ENV, "1")
-    assert startup_warmup(str(tmp_path)) is None
+    assert startup_warmup() is None
 
 
 def _att(wall, execute, padding, straggler):

@@ -280,19 +280,31 @@ class TestCompileCache:
         assert built == ["a", "b", "c", "a"]   # "a" evicted, rebuilt
         assert c.stats()["entries"] == 2
 
-    def test_persistent_cache_dir_precedence(self, tmp_path, monkeypatch):
+    def test_persistent_cache_dir(self, monkeypatch):
+        """One directory for every caller: JAX_COMPILATION_CACHE_DIR
+        when set, else the fixed <checkout>/.xla-cache."""
+        from pathlib import Path
+
         from jepsen_etcd_demo_tpu.sched.compile_cache import \
             compile_cache_dir
 
-        monkeypatch.delenv("JEPSEN_TPU_COMPILE_CACHE", raising=False)
+        checkout = Path(__file__).resolve().parent.parent
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        assert compile_cache_dir(tmp_path / "store") == \
-            str(tmp_path / "store" / ".xla-cache")
+        assert compile_cache_dir() == str(checkout / ".xla-cache")
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/jaxdir")
-        assert compile_cache_dir(tmp_path / "store") == "/jaxdir"
-        monkeypatch.setenv("JEPSEN_TPU_COMPILE_CACHE", "/harness")
-        assert compile_cache_dir(tmp_path / "store") == "/harness"
-        assert compile_cache_dir(None) == "/harness"
+        assert compile_cache_dir() == "/jaxdir"
+
+    def test_tuned_profile_and_calibration_live_beside_the_cache(
+            self, tmp_path, monkeypatch):
+        from pathlib import Path
+
+        from jepsen_etcd_demo_tpu.ops.calibrate import calibration_path
+        from jepsen_etcd_demo_tpu.tune.profile import profile_path
+
+        monkeypatch.delenv("JEPSEN_TPU_TUNE_PROFILE", raising=False)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert Path(profile_path()).parent == tmp_path
+        assert Path(calibration_path()).parent == tmp_path
 
 
 class TestEncodeCache:

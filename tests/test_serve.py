@@ -47,11 +47,9 @@ def _posthoc(enc):
 
 @pytest.fixture
 def healthy_supervisor():
-    """A fresh supervisor with active probing disabled — serve tests
-    must not inherit another test's degraded state or pay a subprocess
-    probe."""
-    fake = health.BackendSupervisor(probe=lambda: (True, "", False),
-                                    probe_interval_s=3600.0)
+    """A fresh supervisor — serve tests must not inherit another
+    test's degraded state."""
+    fake = health.BackendSupervisor()
     prev = health.reset_supervisor(fake)
     try:
         yield fake
@@ -169,8 +167,8 @@ class TestCoalescingScheduler:
     def test_degraded_sheds_to_cpu_oracle_with_identical_verdicts(
             self, rng):
         fake = health.BackendSupervisor(
-            probe=lambda: (True, "", False), fail_degraded=1,
-            fail_wedged=3, probe_interval_s=3600.0)
+            fail_degraded=1,
+            fail_wedged=3)
         prev = health.reset_supervisor(fake)
         try:
             fake.note_failure("synthetic wobble", source="test")
@@ -197,8 +195,7 @@ class TestCoalescingScheduler:
             health.reset_supervisor(prev)
 
     def test_wedged_rejects_503_then_drains_on_recovery(self, rng):
-        fake = health.BackendSupervisor(
-            probe=lambda: (True, "", False), probe_interval_s=3600.0)
+        fake = health.BackendSupervisor()
         prev = health.reset_supervisor(fake)
         try:
             with obs.capture() as cap:
@@ -208,7 +205,7 @@ class TestCoalescingScheduler:
                     # Admitted while healthy; sits in the coalesce
                     # window when the backend wedges.
                     queued = s.submit("t", e, model_name="cas-register")
-                    fake.note_failure("tunnel hang", source="test",
+                    fake.note_failure("device hang", source="test",
                                       wedged=True)
                     assert fake.snapshot()["state"] == health.WEDGED
                     with pytest.raises(Rejected) as exc:
@@ -499,8 +496,7 @@ class TestServeDaemonHTTP:
             daemon.close()
 
     def test_session_manager_wedged_rejects_503(self, rng):
-        fake = health.BackendSupervisor(
-            probe=lambda: (True, "", False), probe_interval_s=3600.0)
+        fake = health.BackendSupervisor()
         prev = health.reset_supervisor(fake)
         try:
             fake.note_failure("hang", source="test", wedged=True)

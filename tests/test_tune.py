@@ -327,7 +327,7 @@ class TestKnobResolution:
 
         cands = candidates_for("long_scan_chunk", probe=object())
         default = field_meta()["long_scan_chunk"]["default"]
-        assert all(v <= default for v in cands)       # [worker], down
+        assert all(v <= default for v in cands)       # [envelope], down
         assert default in cands and len(cands) >= 2
 
     def test_candidates_stay_in_safe_range(self):
@@ -470,7 +470,7 @@ class TestReportingSurfaces:
         rep = profile.report()
         f = rep["fields"]["long_scan_chunk"]
         assert f["value"] == 4096 and f["provenance"] == "tuned"
-        assert f["range"] == [256, 1 << 20] and f["kind"] == "worker"
+        assert f["range"] == [256, 1 << 20] and f["kind"] == "envelope"
         assert rep["profile_hash"] == rec["hash"]
         json.dumps(rep)
 

@@ -265,3 +265,53 @@ def test_resumable_long_sweep_matches_xla_chunked():
                 assert got[f] == ref[f], (trial, f, got, ref)
     finally:
         set_limits(prev)
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_smem_split_launch_matches_one_launch_and_oracle(group):
+    """A batch too big for one launch's SMEM runs as several pallas calls
+    (launch_parts), each indexing its own histories' colmask blocks by
+    offset: per-history (group 1) and grouped kernel verdicts must equal
+    the single-launch result bit for bit, and the oracle."""
+    from dataclasses import replace
+
+    import numpy as np
+
+    from jepsen_etcd_demo_tpu.ops.limits import set_limits
+
+    rng = random.Random(0x5917 + group)
+    encs = []
+    for i in range(13):          # ragged parts: [7, 6] / 4 x 4 padded
+        h = gen_register_history(rng, n_ops=30, n_procs=6)
+        if i % 3 == 1:
+            h = mutate_history(rng, h)
+        encs.append(encode_register_history(h, k_slots=16))
+    cfg, steps, r_cap = wgl3.batch_steps3(encs, MODEL)
+    arrays = wgl3.stack_steps3(steps, r_cap)
+
+    def run():
+        # Fresh launchers: the launch shape (and its parts) is cached
+        # per launcher instance.
+        if group == 1:
+            prep = wgl3_pallas._cached_prep(MODEL, cfg)
+            cm, tg, ln = prep(*arrays)
+            launch = wgl3_pallas.local_pallas_launcher(MODEL, cfg, True)
+            return np.asarray(launch(*arrays[2].shape)(ln, tg, cm))
+        return np.asarray(wgl3_pallas.make_batch_checker_pallas_grouped(
+            MODEL, cfg, group=group, interpret=True)(*arrays))
+
+    one = run()
+    b_pad = -(-len(encs) // group) * group
+    assert len(wgl3_pallas.launch_parts(b_pad, r_cap, group)) == 1
+    prev = set_limits(replace(limits(), max_prefetch_pallas=1 << 10))
+    try:
+        parts = wgl3_pallas.launch_parts(b_pad, r_cap, group)
+        assert len(parts) >= 2 and sum(parts) == b_pad, parts
+        split = run()
+    finally:
+        set_limits(prev)
+    np.testing.assert_array_equal(one, split)
+    got = [r["valid"] for r in wgl3.assemble_batch_results(
+        wgl3.unpack_np(split), steps, cfg)]
+    want = [check_events_oracle(e, MODEL).valid for e in encs]
+    assert got == want and False in want

@@ -56,7 +56,7 @@ def main() -> int:
         for p in problems:
             print(f"  - {p}", file=sys.stderr)
         print("Fix the 'KernelLimits reference' table in doc/perf.md: "
-              "every field needs a row with its [worker]/[arch]/[tunable] "
+              "every field needs a row with its [envelope]/[arch]/[tunable] "
               "tag and its lo..hi safe range.", file=sys.stderr)
         return 1
     print(f"ok: all {len(field_metadata())} KernelLimits fields "
