@@ -575,6 +575,17 @@ def _extract_metrics(facts: FlowFacts, mod: ModuleSource) -> None:
             prefix = _const_str(lead)
             if prefix:
                 family = prefix.rstrip("._")
+        if name is None and isinstance(arg, ast.Subscript) \
+                and isinstance(arg.value, ast.Name) \
+                and arg.value.id in consts:
+            # `m.counter(NAMES[i])` over a module tuple of names writes
+            # some member of it: count each member as written.
+            for member in _resolve_fields(mod, consts,
+                                          consts[arg.value.id]) or ():
+                facts.metric_writes.append(MetricWrite(
+                    mod=mod, line=node.lineno, method=node.func.attr,
+                    name=member, family=None))
+            continue
         if name is not None or family is not None:
             facts.metric_writes.append(MetricWrite(
                 mod=mod, line=node.lineno, method=node.func.attr,

@@ -65,10 +65,11 @@ class IndependentChecker(Checker):
 
     def check(self, test: dict, history: Sequence[Op],
               opts: dict | None = None) -> dict[str, Any]:
-        keyed = split_by_key(history)
+        with obs.phase("check.split"):
+            keyed = split_by_key(history)
+            keys = sorted(keyed, key=str)
         if not keyed:
             return {"valid": True, "key_count": 0}
-        keys = sorted(keyed, key=str)
 
         # Which checkers can ride the batched kernel? Only direct entries:
         # either the sub-checker itself, or first-level values of a Compose.
@@ -98,10 +99,11 @@ class IndependentChecker(Checker):
             settled = settled_for(lin)
             return {k: h for k, h in keyed.items() if k not in settled}
 
+        # The call's intermediates (encodings), freed in check.release.
+        held: list = []
         batched: dict[str | None, dict[Any, dict]] = {
-            name: (_batched_linearizable(lin, sub_keyed,
-                                         (opts or {}).get("store_dir"))
-                   if (sub_keyed := batch_keys(lin)) else {})
+            name: _batched_linearizable(lin, batch_keys(lin),
+                                        (opts or {}).get("store_dir"), held)
             for name, lin in batchable.items()
         }
 
@@ -109,8 +111,13 @@ class IndependentChecker(Checker):
         for k in keys:
             results[k] = self._check_key(test, keyed[k], opts, batched, k)
         valid = merge_valid([r.get("valid") for r in results.values()])
-        return {"valid": valid, "key_count": len(keyed),
-                "results": {str(k): v for k, v in results.items()}}
+        out = {"valid": valid, "key_count": len(keyed),
+               "results": {str(k): v for k, v in results.items()}}
+        # Free the keyed sub-histories and encodings here, inside a
+        # phase, rather than after the call's last one.
+        with obs.phase("check.release"):
+            del keyed, batched, results, held
+        return out
 
     def _check_key(self, test, sub_history, opts, batched, key):
         opts = dict(opts or {})
@@ -141,16 +148,19 @@ class IndependentChecker(Checker):
 
 
 def _batched_linearizable(lin: Linearizable, keyed: dict[Any, list[Op]],
-                          store_dir=None) -> dict[Any, dict]:
+                          store_dir, held: list) -> dict[Any, dict]:
     """Encode every key's history into the return-major form, pad to one
     step count, run one vmapped kernel launch over the key batch.
 
     Prefers the dense lattice kernel (wgl3) — exact, no overflow — whenever
-    the shared config table is feasible; falls back to the sort kernel."""
+    the shared config table is feasible; falls back to the sort kernel.
+    The encodings are appended to `held`, for the caller to free."""
+    if not keyed:
+        return {}
     with obs.get_tracer().span("check.linearizable.batched",
                                model=lin.model.name,
                                keys=len(keyed)) as sp:
-        out = _batched_linearizable_traced(lin, keyed, store_dir)
+        out = _batched_linearizable_traced(lin, keyed, store_dir, held)
         sp.set(settled=sum(1 for r in out.values()
                            if r.get("valid") is True))
         return out
@@ -158,25 +168,27 @@ def _batched_linearizable(lin: Linearizable, keyed: dict[Any, list[Op]],
 
 def _batched_linearizable_traced(lin: Linearizable,
                                  keyed: dict[Any, list[Op]],
-                                 store_dir=None) -> dict[Any, dict]:
+                                 store_dir, held: list) -> dict[Any, dict]:
     from ..ops import wgl3
 
-    event_encs = {k: lin.encode(h) for k, h in keyed.items()}
+    with obs.phase("check.encode"):
+        event_encs = {k: lin.encode(h) for k, h in keyed.items()}
+        held.append(event_encs)
+        max_value = max(e.max_value for e in event_encs.values())
+        tight = max(wgl3.tight_k_slots(e) for e in event_encs.values())
+        cfg3 = wgl3.dense_config(lin.model, tight, max_value)
     if store_dir:
         from ..store.store import write_encoded_tensor
 
         for k, e in event_encs.items():
             # Empty encodings included (corpus tensor-coverage contract).
             write_encoded_tensor(store_dir, k, e, lin.model.name)
-    max_value = max(e.max_value for e in event_encs.values())
 
     # Dense path: one table geometry serves the whole batch — mask width =
     # the largest key's real concurrency. Launches go through the corpus
     # scheduler (sched/engine.py): per-key histories land in padded-length
     # buckets instead of all padding to the longest key, so a run with one
     # long-lived key no longer taxes every other key's launch.
-    tight = max(wgl3.tight_k_slots(e) for e in event_encs.values())
-    cfg3 = wgl3.dense_config(lin.model, tight, max_value)
     if cfg3 is not None:
         from .. import sched
 

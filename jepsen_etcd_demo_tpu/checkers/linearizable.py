@@ -145,11 +145,13 @@ class Linearizable(Checker):
         # drop them like knossos does [dep]. Workloads under the
         # independent wrapper never see them (split_by_key filters), but a
         # bare whole-history checker (multiregister workload) does.
-        history = [op for op in history if op.process != "nemesis"]
-        # Translate ONCE (e.g. mutex acquire/release -> cas) so the
-        # witness replay below sees the same op language the encoder did.
-        history = self.model.prepare_history(history)
-        enc = self._encode_translated(history)
+        with obs.phase("check.encode"):
+            history = [op for op in history if op.process != "nemesis"]
+            # Translate ONCE (e.g. mutex acquire/release -> cas) so the
+            # witness replay below sees the same op language the encoder
+            # did.
+            history = self.model.prepare_history(history)
+            enc = self._encode_translated(history)
         store_dir = (opts or {}).get("store_dir")
         if store_dir:
             # Empty encodings included: the artifact records the checker's
@@ -181,7 +183,7 @@ class Linearizable(Checker):
                backend=res.get("backend", self.backend),
                op_count=res.get("op_count"))
         if res.get("valid") is False:
-            with obs.get_tracer().span("check.witness"):
+            with obs.phase("check.witness"):
                 self._explain(res, enc, history, opts)
         return res
 

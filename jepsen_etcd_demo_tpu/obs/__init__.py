@@ -11,7 +11,9 @@ idle:
   * obs/metrics.py — counters/gauges/histograms -> `metrics.json`
   * this module    — the capture stack wiring instrumentation points to
                      the active run, kernel compile/execute attribution,
-                     and the env-gated jax.profiler trace.
+                     the host phases of a check (`phase`), garbage-
+                     collection and compile counters, and the env-gated
+                     jax.profiler trace.
 
 Usage pattern: layers call `get_tracer()` / `get_metrics()` at the
 point of instrumentation; both return no-op singletons unless a
@@ -26,10 +28,8 @@ Env vars:
                            become no-ops; no artifacts are written)
   JEPSEN_TPU_JAX_TRACE=1   additionally capture a jax.profiler trace of
                            the check phase into <run_dir>/jax_trace/
-                           (view with tensorboard/xprof)
-  JEPSEN_TPU_KERNEL_COST=0 disable the per-kernel XLA cost_analysis /
-                           device-memory capture on first calls
-                           (kernel_phases flops/bytes stay zero)
+                           (view with tensorboard/xprof); the host
+                           phases show on its host plane
 
 Live export (obs/export.py): `obs.subscribe()` streams span/event/
 metric records as they are appended (the web layer's /live SSE feed),
@@ -49,11 +49,21 @@ are never absent from metrics.json or the bench's kernel_phases):
   encode.encode_s    host-side history->tensor encoding seconds
   wgl.frontier_peak  gauge; max over checks of the search's live-config
                      high-water mark (kernel_phases reports its max)
+  <phase>_s          host seconds of each phase in PHASES, counted only
+                     while it is the outermost open phase (disjoint)
+  gc.collect_s       seconds of garbage collections that started inside
+                     a phase (inside a check)
+  compile.*          jax trace / lower / backend-compile seconds and
+                     persistent-cache misses (COMPILE_COUNTERS)
 """
 
 from __future__ import annotations
 
+import collections
+import contextvars
+import gc
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -70,7 +80,6 @@ from .ledger import Ledger                         # noqa: F401
 
 TELEMETRY_FILE = "telemetry.jsonl"
 METRICS_FILE = "metrics.json"
-KERNEL_COST_ENV = "JEPSEN_TPU_KERNEL_COST"
 
 # The bench/e2e contract keys: pre-registered at zero on every capture.
 # The jtflow "metrics preregistered" hooks below declare the
@@ -156,16 +165,38 @@ SERVE_GAUGES = ("serve.queue_depth", "serve.batch_fill")
 # tenant; the exporter renders p50/p95/p99 quantile lines.
 # jtflow: metrics preregistered
 SERVE_HISTOGRAM = "serve.request_latency_s"
-# Deep kernel attribution (ISSUE 8): XLA cost_analysis totals captured
-# by instrument_kernel at lower time, plus the device-memory high-water
-# mark — behind kernel_phases' flops / bytes / device_mem_peak fields.
-# Tracer truncation (trace.dropped_records) rides along so a truncated
-# telemetry.jsonl is visible in metrics too, not only the footer.
+# Tracer truncation: a truncated telemetry.jsonl is visible in metrics
+# too, not only the footer.
 # jtflow: metrics preregistered
-COST_COUNTERS = ("wgl.flops", "wgl.bytes_accessed",
-                 "trace.dropped_records")
+TRACE_COUNTERS = ("trace.dropped_records",)
+# The host phases of a check, each opened once per block of a call,
+# never per key or per op (`phase`). PHASE_SECONDS[i] is PHASES[i]'s
+# counter: seconds while that phase is the outermost open one, so the
+# counters are disjoint and their sum never exceeds the wall.
+PHASES = ("check.split", "check.encode", "sched.pack", "sched.dispatch",
+          "sched.fetch_wait", "sched.unpack", "check.witness",
+          "check.release")
 # jtflow: metrics preregistered
-COST_GAUGE = "wgl.device_mem_peak"
+PHASE_SECONDS = ("check.split_s", "check.encode_s", "sched.pack_s",
+                 "sched.dispatch_s", "sched.fetch_wait_s",
+                 "sched.unpack_s", "check.witness_s", "check.release_s")
+_PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
+# Garbage-collection seconds (a gc.callbacks hook while a capture is
+# open) of collections that start inside a phase. They overlap the
+# phases: never part of their sum.
+# jtflow: metrics preregistered
+GC_COUNTER = "gc.collect_s"
+# jax's compile events (one jax.monitoring listener per process):
+# tracing, lowering to MLIR, and the backend compile — which includes
+# loads from the persistent compilation cache — plus the persistent
+# cache's misses (jax records one per entry it writes).
+# jtflow: metrics preregistered
+COMPILE_COUNTERS = ("compile.trace_s", "compile.lower_s",
+                    "compile.backend_s", "compile.cache_misses")
+_COMPILE_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": 0,
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration": 1,
+                   "/jax/core/compile/backend_compile_duration": 2}
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 # Backend health supervisor (obs/health.py): 0 healthy / 1 degraded /
 # 2 wedged, set on every transition.
 # jtflow: metrics preregistered
@@ -270,13 +301,19 @@ class Capture:
         # dir. `with_ledger=False` is the bench's overhead-control arm.
         self.ledger = Ledger(out_dir=self.out_dir, metrics=self.metrics,
                              enabled=enabled and with_ledger)
+        # Seconds of each collection the gc hook saw, not yet added to
+        # gc.collect_s: the hook may not take the registry lock (a
+        # collection can start inside any allocation, including one
+        # made while this thread holds it), so fold_gc moves them.
+        self.gc_pending: collections.deque = collections.deque()
         if enabled:
             for name in PHASE_COUNTERS + SCHED_COUNTERS + SWEEP_COUNTERS \
-                    + COST_COUNTERS + ELLE_COUNTERS + SERVE_COUNTERS \
+                    + TRACE_COUNTERS + ELLE_COUNTERS + SERVE_COUNTERS \
                     + SYNC_COUNTERS + CAMPAIGN_COUNTERS \
                     + LEDGER_COUNTERS + FLEET_COUNTERS \
-                    + SPILL_COUNTERS:
+                    + SPILL_COUNTERS + PHASE_SECONDS + COMPILE_COUNTERS:
                 self.metrics.counter(name)
+            self.metrics.counter(GC_COUNTER)
             for name in ELLE_GAUGES + SERVE_GAUGES + CAMPAIGN_GAUGES \
                     + LEDGER_GAUGES + SLO_GAUGES + FLEET_GAUGES \
                     + SPILL_GAUGES:
@@ -285,7 +322,6 @@ class Capture:
             self.metrics.gauge(PHASE_GAUGE)
             self.metrics.gauge(SWEEP_GAUGE)
             self.metrics.gauge(DEDUP_GAUGE)
-            self.metrics.gauge(COST_GAUGE)
             self.metrics.gauge(HEALTH_GAUGE)
             for name in STREAM_GAUGES:
                 self.metrics.gauge(name)
@@ -297,6 +333,17 @@ class Capture:
             self.tracer.listener = export.bus_publish
             self.tracer.drop_counter = \
                 self.metrics.counter("trace.dropped_records")
+
+    def fold_gc(self) -> None:
+        """Add the gc hook's pending seconds to gc.collect_s."""
+        s = 0.0
+        try:
+            while True:
+                s += self.gc_pending.popleft()
+        except IndexError:
+            pass
+        if s:
+            self.metrics.counter(GC_COUNTER).add(s)
 
     def write(self) -> None:
         # Join the ledger writer thread first (idempotent) so
@@ -364,60 +411,139 @@ def capture(out_dir: Optional[str | Path] = None, *,
     if not cap.enabled:
         yield cap
         return
+    if "jax" in sys.modules:
+        _jax()
     with _lock:
         _stack.append(cap)
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
     try:
         yield cap
     finally:
         with _lock:
             if cap in _stack:
                 _stack.remove(cap)
+            if not _stack and _on_gc in gc.callbacks:
+                gc.callbacks.remove(_on_gc)
+        cap.fold_gc()
         cap.write()
+
+
+# -- host phases, garbage collection, compiles -----------------------------
+
+_JAX = None             # the jax module, once a capture has needed it
+_phase_open: contextvars.ContextVar[bool] = contextvars.ContextVar(
+    "jepsen_tpu_phase", default=False)
+
+
+def _jax():
+    """jax, imported at the first capture that needs it, with the
+    process's one compile listener registered then."""
+    global _JAX
+    if _JAX is None:
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        jax.monitoring.register_event_listener(_on_compile_event)
+        _JAX = jax
+    return _JAX
+
+
+def _on_compile_duration(event: str, duration: float, **_) -> None:
+    i = _COMPILE_EVENTS.get(event)
+    stack = _stack
+    if i is not None and stack:
+        stack[-1].metrics.counter(COMPILE_COUNTERS[i]).add(duration)
+
+
+def _on_compile_event(event: str, **_) -> None:
+    stack = _stack
+    if event == _CACHE_MISS_EVENT and stack:
+        stack[-1].metrics.counter("compile.cache_misses").add(1)
+
+
+_gc_t0: Optional[float] = None   # start of the collection being counted
+_gc_note = None         # the open gc.collect annotation, if any
+
+
+def _on_gc(stage: str, info: dict) -> None:
+    """gc.callbacks hook, installed while a capture is open. A
+    collection that starts inside a phase — inside a check, not in the
+    caller's own work between checks — adds its seconds to gc.collect_s
+    (Capture.gc_pending, folded in at the end of that outermost phase)
+    and, for generations 1 and 2, opens a `gc.collect` annotation on
+    the profiler's host plane (generation 0 collects far too often to
+    annotate)."""
+    global _gc_t0, _gc_note
+    if stage == "start":
+        if not _phase_open.get():
+            _gc_t0 = None
+            return
+        _gc_t0 = time.perf_counter()
+        if _JAX is not None and info.get("generation", 0) >= 1:
+            _gc_note = _JAX.profiler.TraceAnnotation("gc.collect")
+            _gc_note.__enter__()
+        return
+    if _gc_t0 is None:
+        return
+    dt = time.perf_counter() - _gc_t0
+    if _gc_note is not None:
+        _gc_note.__exit__(None, None, None)
+        _gc_note = None
+    stack = _stack
+    if stack:
+        stack[-1].gc_pending.append(dt)
+
+
+@contextmanager
+def phase(name: str) -> Iterator[None]:
+    """One host phase of a check (`name` from PHASES), opened once per
+    block of a call. Under a capture: a telemetry.jsonl span, a
+    jax.profiler annotation on the host plane (the device trace's
+    clock), and — when no other phase is open in this context — its
+    seconds into the phase's counter, so phase counters never overlap.
+    With no capture open it records nothing and imports nothing."""
+    i = _PHASE_INDEX.get(name)
+    if i is None:
+        raise ValueError(f"unknown phase {name!r}; phases: {PHASES}")
+    stack = _stack
+    if not stack:
+        yield
+        return
+    cap = stack[-1]
+    outer = not _phase_open.get()
+    token = _phase_open.set(True)
+    t0 = time.perf_counter()
+    try:
+        with cap.tracer.span(name), _jax().profiler.TraceAnnotation(name):
+            yield
+    finally:
+        if outer:
+            cap.metrics.counter(PHASE_SECONDS[i]).add(
+                time.perf_counter() - t0)
+            if cap.gc_pending:
+                cap.fold_gc()
+        _phase_open.reset(token)
 
 
 # -- kernel phase attribution ----------------------------------------------
 
-def kernel_cost_enabled() -> bool:
-    return os.environ.get(KERNEL_COST_ENV, "1").lower() \
-        not in ("0", "false", "no", "off")
-
-
-def _capture_kernel_cost(name: str, fn: Callable, args, kwargs,
-                         m: MetricsRegistry) -> None:
-    """Deep attribution for one about-to-compile kernel geometry: lower
-    the jitted callable (tracing only — no XLA compile, no execution,
-    donation-safe because nothing runs) and fold its
-    ``cost_analysis()`` flops / bytes-accessed estimates into the
-    registry, then note the backend's device-memory high-water mark.
-    Pure observability: ANY failure (a non-jit callable, a backend
-    without cost analysis, a CPU without memory_stats) is swallowed and
-    the pre-registered zeros stand."""
+def _lower_ahead(fn: Callable, args, kwargs) -> None:
+    """Lower a jitted kernel before its first call; the call then reuses
+    this lowering instead of lowering on its own path. Kept for set-up
+    time: on a TPU v5e, under one measuring script, the warm-up call of
+    a 1024-key independent check took 23.0-24.5 s without it and
+    16.6-17.3 s with it (the call's own lowering of each of its six
+    pallas launchers took 1.2-2.5 s, this one 0.8-1.0 s; why is not
+    known). Nothing runs, so donated operands stay alive. A callable
+    that cannot be lowered this way (not jitted, traced arguments) is
+    left to its call, which raises what is wrong."""
+    lower = getattr(fn, "lower", None)
+    if lower is None:
+        return
     try:
-        lowered = fn.lower(*args, **kwargs)
-        ca = lowered.cost_analysis() or {}
-        flops = float(ca.get("flops", 0.0) or 0.0)
-        nbytes = float(ca.get("bytes accessed", 0.0) or 0.0)
-        if flops > 0:
-            m.counter("wgl.flops").add(flops)
-            # jtlint: disable=JTL107 -- bounded family: kernel names are
-            # the fixed static set of instrument_kernel call sites; the
-            # exporter folds them into one labeled Prometheus family.
-            m.gauge(f"wgl.kernel_flops.{name}").set(flops)
-        if nbytes > 0:
-            m.counter("wgl.bytes_accessed").add(nbytes)
-            # jtlint: disable=JTL107 -- bounded family: kernel names are
-            # a fixed static set (same argument as wgl.kernel_flops).
-            m.gauge(f"wgl.kernel_bytes.{name}").set(nbytes)
-    except Exception:
-        pass
-    try:
-        import jax
-
-        stats = jax.local_devices()[0].memory_stats() or {}
-        peak = float(stats.get("peak_bytes_in_use",
-                               stats.get("bytes_in_use", 0)) or 0)
-        if peak > 0:
-            m.gauge(COST_GAUGE).set(peak)
+        lower(*args, **kwargs)
     except Exception:
         pass
 
@@ -436,18 +562,17 @@ def instrument_kernel(name: str, fn: Callable) -> Callable:
     Steady-state times are dispatch wall, NOT device time: kernels
     dispatch asynchronously and callers rely on that (the chunked
     sweeps pipeline windows), so the wrapper never blocks on results.
-    Device-true timings are the env-gated jax.profiler trace's job."""
+    Device-true timings are the env-gated jax.profiler trace's job.
+
+    Under a capture, the first call lowers the jitted callable ahead of
+    the call, outside the timed region (`_lower_ahead`)."""
     state = {"first": True}
 
     def wrapped(*args, **kwargs):
         first = state["first"]
         m = get_metrics()
-        if first and m.enabled and kernel_cost_enabled():
-            # Deep attribution BEFORE the call (donated operands are
-            # still alive): XLA cost_analysis flops/bytes + device
-            # memory peak, outside the timed region so compile_s keeps
-            # meaning "the first call's wall".
-            _capture_kernel_cost(name, fn, args, kwargs, m)
+        if first and m.enabled:
+            _lower_ahead(fn, args, kwargs)
         t0_ns = time.monotonic_ns()
         out = fn(*args, **kwargs)
         t1_ns = time.monotonic_ns()
@@ -574,15 +699,9 @@ def kernel_phases(metrics: Optional[MetricsRegistry] = None) -> dict:
     timing field is zero — the contract is "zeros permitted, never
     absent". `profile_hash` identifies the tuning profile the process
     resolved (ISSUE 4: every bench record names its profile, the
-    degraded path included — "default" when none applies). ISSUE 8
-    grew the deep-attribution fields: `flops` / `bytes` (summed XLA
-    cost_analysis estimates over every kernel geometry compiled under
-    the capture) and `device_mem_peak` (the backend allocator's
-    peak-bytes-in-use high-water mark) — zeros on backends that report
-    neither, never absent."""
+    degraded path included — "default" when none applies)."""
     out = {"compile_s": 0.0, "execute_s": 0.0, "encode_s": 0.0,
-           "frontier_peak": 0, "flops": 0.0, "bytes": 0.0,
-           "device_mem_peak": 0, "profile_hash": active_profile_hash()}
+           "frontier_peak": 0, "profile_hash": active_profile_hash()}
     if metrics is None or not metrics.enabled:
         return out
     snap = metrics.snapshot()
@@ -595,14 +714,9 @@ def kernel_phases(metrics: Optional[MetricsRegistry] = None) -> dict:
     out["compile_s"] = counter_value("wgl.compile_s")
     out["execute_s"] = counter_value("wgl.execute_s")
     out["encode_s"] = counter_value("encode.encode_s")
-    out["flops"] = counter_value("wgl.flops")
-    out["bytes"] = counter_value("wgl.bytes_accessed")
     fp = snap.get(PHASE_GAUGE)
     if fp and fp.get("max") is not None:
         out["frontier_peak"] = int(fp["max"])
-    mem = snap.get(COST_GAUGE)
-    if mem and mem.get("max") is not None:
-        out["device_mem_peak"] = int(mem["max"])
     return out
 
 

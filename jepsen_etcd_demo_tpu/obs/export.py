@@ -53,8 +53,6 @@ PROM_CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"
 LABELED_FAMILIES = {
     "wgl.compile_s": "kernel",
     "wgl.execute_s": "kernel",
-    "wgl.kernel_flops": "kernel",
-    "wgl.kernel_bytes": "kernel",
     "tune.probe_s": "knob",
     "tune.chosen": "knob",
     # Scaling-ledger per-bucket cumulative seconds (obs/ledger.py

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.base import Model
-from ..obs import (get_ledger, get_metrics, instrument_kernel,
+from ..obs import (get_ledger, get_metrics, instrument_kernel, phase,
                    record_check_result)
 from .encode import EncodedHistory, ReturnSteps, encode_return_steps
 from .limits import limits
@@ -662,15 +662,19 @@ def check_steps3_long(rs: ReturnSteps, model: Model, cfg: DenseConfig,
         done = 0
         for staged in double_buffer(range(start_c, n_pad // chunk),
                                     stage):
-            carry, part = run(carry, *staged)
+            with phase("sched.dispatch"):
+                carry, part = run(carry, *staged)
             cfgs_dev = part if cfgs_dev is None else cfgs_dev + part
             done += 1
             if done % poll == 0:
-                # jtlint: disable=JTL103 -- bounded death poll: one
-                # fetch per sched_poll_chunks chunks (the [tunable]
-                # knob), not per iteration — the doc/perf.md early-exit
-                # contract; the seam spill rides the same sync.
-                if bool(np.asarray(carry.dead)):
+                with phase("sched.fetch_wait"):
+                    # jtlint: disable=JTL103 -- bounded death poll: one
+                    # fetch per sched_poll_chunks chunks (the [tunable]
+                    # knob), not per iteration — the doc/perf.md
+                    # early-exit contract; the seam spill rides the same
+                    # sync.
+                    dead = bool(np.asarray(carry.dead))
+                if dead:
                     break
                 if do_spill:
                     seam_spill(start_c + done)
@@ -690,14 +694,17 @@ def check_steps3_long(rs: ReturnSteps, model: Model, cfg: DenseConfig,
                     jnp.asarray(rs.targets[sl]))
             if pairs is not None:
                 args = args + (jnp.asarray(pairs[sl]),)
-            carry, part = run(carry, *args, jnp.int32(c * chunk))
+            with phase("sched.dispatch"):
+                carry, part = run(carry, *args, jnp.int32(c * chunk))
             cfgs_dev = part if cfgs_dev is None else cfgs_dev + part
             # Early exit on death: one 1-byte fetch per chunk vs
             # minutes of dead chunks on wide tables.
-            # jtlint: disable=JTL103 -- budgeted lane is synchronous BY
-            # CONTRACT: the budget check must see device time, so the
-            # per-chunk fetch is the bound on overshoot.
-            if bool(np.asarray(carry.dead)):
+            with phase("sched.fetch_wait"):
+                # jtlint: disable=JTL103 -- budgeted lane is synchronous
+                # BY CONTRACT: the budget check must see device time, so
+                # the per-chunk fetch is the bound on overshoot.
+                dead = bool(np.asarray(carry.dead))
+            if dead:
                 break
             if do_spill:
                 seam_spill(c + 1)
@@ -708,12 +715,13 @@ def check_steps3_long(rs: ReturnSteps, model: Model, cfg: DenseConfig,
         cfgs_dev = jnp.zeros((n_parts,), jnp.float32)
     # One packed fetch at the end (chunks chain device-side): 3 verdict
     # fields + the chunk fn's declared partial row.
-    # jtflow: partials-from wgl3._chunk_fn
-    # jtflow: partials-from wgl3._chunk_fn_dedup
-    packed = np.asarray(jnp.concatenate([
-        jnp.stack([jnp.where(carry.dead, 0, 1),
-                   carry.dead_step, carry.max_frontier]),
-        jnp.clip(cfgs_dev, 0, 2**31 - 1).astype(jnp.int32)]))
+    with phase("sched.fetch_wait"):
+        # jtflow: partials-from wgl3._chunk_fn
+        # jtflow: partials-from wgl3._chunk_fn_dedup
+        packed = np.asarray(jnp.concatenate([
+            jnp.stack([jnp.where(carry.dead, 0, 1),
+                       carry.dead_step, carry.max_frontier]),
+            jnp.clip(cfgs_dev, 0, 2**31 - 1).astype(jnp.int32)]))
     out = {
         "survived": bool(packed[0]),
         "overflow": False,

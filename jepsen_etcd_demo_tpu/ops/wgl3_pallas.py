@@ -80,7 +80,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..models.base import Model
-from ..obs import instrument_kernel, record_check_result
+from ..obs import instrument_kernel, phase, record_check_result
 from .encode import EncodedHistory
 from .limits import limits
 from .wgl3 import DenseConfig, _LO_MASK, batch_arrays3, dense_config
@@ -394,7 +394,7 @@ def local_pallas_launcher_resumable(model: Model, cfg: DenseConfig,
                 grid_spec=grid_spec,
                 out_shape=[jax.ShapeDtypeStruct((5,), jnp.int32),
                            jax.ShapeDtypeStruct((1, Sp, W), jnp.uint32)],
-                interpret=interpret,
+                interpret=interpret, name="wgl3_resumable",
             )(ln, mt, tg, cm, Tin)
             # The NEXT window's metadata, chained device-side INSIDE the
             # jit. `end` (the global step offset after this window) is an
@@ -483,17 +483,21 @@ def check_steps3_long_pallas(rs, model: Model, cfg: DenseConfig,
         wn = min(window, n - w0)
         sl = slice(w0, w0 + wn)
         pad = ((0, window - wn),)
-        tg = np.pad(rs.targets[sl], pad, constant_values=-1)[None]
-        tabs = np.pad(rs.slot_tabs[sl],
-                      pad + ((0, 0), (0, 0)))[None]
-        act = np.pad(rs.slot_active[sl], pad + ((0, 0),))[None]
-        cm, tgd, ln = prep(jnp.asarray(tabs), jnp.asarray(act),
-                           jnp.asarray(tg))
-        out, Tin, meta = launch(window)(
-            ln, meta, tgd, cm, Tin, jnp.asarray(w0 + wn, jnp.int32))
+        with phase("sched.pack"):
+            tg = np.pad(rs.targets[sl], pad, constant_values=-1)[None]
+            tabs = np.pad(rs.slot_tabs[sl],
+                          pad + ((0, 0), (0, 0)))[None]
+            act = np.pad(rs.slot_active[sl], pad + ((0, 0),))[None]
+        with phase("sched.dispatch"):
+            cm, tgd, ln = prep(jnp.asarray(tabs), jnp.asarray(act),
+                               jnp.asarray(tg))
+            out, Tin, meta = launch(window)(
+                ln, meta, tgd, cm, Tin, jnp.asarray(w0 + wn, jnp.int32))
         if time_budget_s is not None:
-            np.asarray(out)   # sync: bound overshoot by one window
-    out_np = np.asarray(out)
+            with phase("sched.fetch_wait"):
+                np.asarray(out)   # sync: bound overshoot by one window
+    with phase("sched.fetch_wait"):
+        out_np = np.asarray(out)
     cfgs = int(out_np[4])
     if cfgs < 0:
         # The i32 accumulator wrapped across windows: saturate, matching
@@ -861,7 +865,7 @@ def local_pallas_launcher_sparse_resumable(model: Model, cfg: DenseConfig,
                 grid_spec=grid_spec,
                 out_shape=[jax.ShapeDtypeStruct((8,), jnp.int32),
                            jax.ShapeDtypeStruct((1, Sp, W), jnp.uint32)],
-                interpret=interpret,
+                interpret=interpret, name="wgl3_sparse_resumable",
             )(ln, mt, tg, cm, Tin)
             mt_next = jnp.stack([1 - out[0], out[2], out[3], out[4], end,
                                  out[5], out[6], out[7]])[None]
@@ -930,16 +934,20 @@ def check_steps3_long_pallas_sparse(rs, model: Model, cfg: DenseConfig,
         wn = min(window, n - w0)
         sl = slice(w0, w0 + wn)
         pad = ((0, window - wn),)
-        tg = np.pad(rs.targets[sl], pad, constant_values=-1)[None]
-        tabs = np.pad(rs.slot_tabs[sl], pad + ((0, 0), (0, 0)))[None]
-        act = np.pad(rs.slot_active[sl], pad + ((0, 0),))[None]
-        cm, tgd, ln = prep(jnp.asarray(tabs), jnp.asarray(act),
-                           jnp.asarray(tg))
-        out, Tin, meta = launch(window)(
-            ln, meta, tgd, cm, Tin, jnp.asarray(w0 + wn, jnp.int32))
+        with phase("sched.pack"):
+            tg = np.pad(rs.targets[sl], pad, constant_values=-1)[None]
+            tabs = np.pad(rs.slot_tabs[sl], pad + ((0, 0), (0, 0)))[None]
+            act = np.pad(rs.slot_active[sl], pad + ((0, 0),))[None]
+        with phase("sched.dispatch"):
+            cm, tgd, ln = prep(jnp.asarray(tabs), jnp.asarray(act),
+                               jnp.asarray(tg))
+            out, Tin, meta = launch(window)(
+                ln, meta, tgd, cm, Tin, jnp.asarray(w0 + wn, jnp.int32))
         if time_budget_s is not None:
-            np.asarray(out)
-    out_np = np.asarray(out)
+            with phase("sched.fetch_wait"):
+                np.asarray(out)
+    with phase("sched.fetch_wait"):
+        out_np = np.asarray(out)
     cfgs = int(out_np[4])
     if cfgs < 0:
         cfgs = 2**31 - 1
@@ -1050,7 +1058,7 @@ def local_pallas_launcher(model: Model, cfg: DenseConfig,
             return pl.pallas_call(
                 kernel, grid_spec=grid_spec,
                 out_shape=[jax.ShapeDtypeStruct((5 * size,), jnp.int32)],
-                interpret=interpret)
+                interpret=interpret, name="wgl3_per_history")
 
         parts = launch_parts(B, R_pad, 1)
 
@@ -1354,7 +1362,7 @@ def local_pallas_launcher_grouped(model: Model, cfg: DenseConfig, G: int,
             return pl.pallas_call(
                 kernel, grid_spec=grid_spec,
                 out_shape=[jax.ShapeDtypeStruct((5 * size,), jnp.int32)],
-                interpret=interpret)
+                interpret=interpret, name="wgl3_grouped")
 
         parts = launch_parts(B, R_pad, G)
 
@@ -1762,7 +1770,8 @@ def check_batch_encoded_auto(encs: Sequence[EncodedHistory],
     if dense_idx:
         sub = [encs[i] for i in dense_idx]
         try:
-            cfg, steps, r_cap = wgl3.batch_steps3(sub, model)
+            with phase("sched.pack"):
+                cfg, steps, r_cap = wgl3.batch_steps3(sub, model)
         except ValueError:
             # Individually feasible but not under one SHARED geometry
             # (e.g. one history's k with another's value range): ladder
@@ -1793,13 +1802,19 @@ def check_batch_encoded_auto(encs: Sequence[EncodedHistory],
                     results[i] = one
                 kernels.add(name)
             else:
-                arrays = wgl3.stack_steps3(steps, r_cap)
-                check, name = packed_batch_checker(
-                    model, cfg, n_steps=r_cap, batch=len(sub))
-                batch_out = wgl3.assemble_batch_results(
-                    wgl3.unpack_np(check(*arrays)), steps, cfg)
-                for i, one in zip(dense_idx, batch_out):
-                    results[i] = one
+                with phase("sched.pack"):
+                    arrays = wgl3.stack_steps3(steps, r_cap)
+                    check, name = packed_batch_checker(
+                        model, cfg, n_steps=r_cap, batch=len(sub))
+                with phase("sched.dispatch"):
+                    dev = check(*arrays)
+                with phase("sched.fetch_wait"):
+                    fetched = np.asarray(dev)
+                with phase("sched.unpack"):
+                    batch_out = wgl3.assemble_batch_results(
+                        wgl3.unpack_np(fetched), steps, cfg)
+                    for i, one in zip(dense_idx, batch_out):
+                        results[i] = one
                 kernels.add(name)
     if general_idx:
         overflowed, too_long, top = _batch_general(encs, general_idx, model,
@@ -2007,17 +2022,21 @@ def _batch_general(encs, idxs, model, results, kernels, f_cap: int = 256
             b_cap = min(wgl3.step_bucket(len(part), floor=8), chunk)
             if sharded:
                 b_cap = (b_cap + n_dev - 1) // n_dev * n_dev
-            padded = [s.padded_to(r_cap) for _, s in part]
-            tabs = np.zeros((b_cap,) + padded[0].slot_tabs.shape, np.int32)
-            act = np.zeros((b_cap,) + padded[0].slot_active.shape, bool)
-            tgt = np.full((b_cap, r_cap), -1, np.int32)
-            for j, p in enumerate(padded):
-                tabs[j] = p.slot_tabs
-                act[j] = p.slot_active
-                tgt[j] = p.targets
-            out = {name: np.asarray(v) for name, v in check(
-                jnp.asarray(tabs), jnp.asarray(act),
-                jnp.asarray(tgt)).items()}
+            with phase("sched.pack"):
+                padded = [s.padded_to(r_cap) for _, s in part]
+                tabs = np.zeros((b_cap,) + padded[0].slot_tabs.shape,
+                                np.int32)
+                act = np.zeros((b_cap,) + padded[0].slot_active.shape, bool)
+                tgt = np.full((b_cap, r_cap), -1, np.int32)
+                for j, p in enumerate(padded):
+                    tabs[j] = p.slot_tabs
+                    act[j] = p.slot_active
+                    tgt[j] = p.targets
+            with phase("sched.dispatch"):
+                dev = check(jnp.asarray(tabs), jnp.asarray(act),
+                            jnp.asarray(tgt))
+            with phase("sched.fetch_wait"):
+                out = {name: np.asarray(v) for name, v in dev.items()}
             for j, (i, s) in enumerate(part):
                 one = {name: out[name][j].item() for name in out}
                 v = wgl.verdict(one)

@@ -264,20 +264,20 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
         results: list[Any] = [None] * len(encs)
         kernels: set[str] = set()
 
-        dense_idx, general_idx = wgl3_pallas.partition_dense(encs, model)
-        cfg = None
-        if dense_idx:
-            k = max(wgl3.tight_k_slots(encs[i]) for i in dense_idx)
-            cfg = wgl3.dense_config(
-                model, k, max(encs[i].max_value for i in dense_idx))
-            if cfg is None:
-                # Individually feasible but not under one shared geometry:
-                # ladder each (rare extreme — same policy as the auto
-                # router).
-                general_idx = sorted(general_idx + dense_idx)
-                dense_idx = []
-
-        if dense_idx:
+        with obs.phase("sched.pack"):
+            dense_idx, general_idx = wgl3_pallas.partition_dense(encs,
+                                                                 model)
+            cfg = None
+            if dense_idx:
+                k = max(wgl3.tight_k_slots(encs[i]) for i in dense_idx)
+                cfg = wgl3.dense_config(
+                    model, k, max(encs[i].max_value for i in dense_idx))
+                if cfg is None:
+                    # Individually feasible but not under one shared
+                    # geometry: ladder each (rare extreme — same policy
+                    # as the auto router).
+                    general_idx = sorted(general_idx + dense_idx)
+                    dense_idx = []
             lim = limits()
             steps_of: dict[int, Any] = {}
             long_idx, short_idx = [], []
@@ -288,7 +288,14 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                 steps_of[i] = rs
                 (long_idx if rs.n_steps > lim.long_scan_max
                  else short_idx).append(i)
+            # The bucketed batched lanes: group by padded step length.
+            buckets: dict[int, list[int]] = {}
+            for i, r in zip(short_idx,
+                            assign_step_buckets(
+                                [steps_of[i].n_steps for i in short_idx])):
+                buckets.setdefault(r, []).append(i)
 
+        if dense_idx:
             # Long histories: host-chunked (now pipelined) sweeps, one at
             # a time — arrays are never stacked. Eligible geometries ride
             # the sparse active-tile engine automatically
@@ -300,18 +307,14 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                 kernels.add(one["kernel"])
                 stats.record_sweep(one)
 
-            # The bucketed batched lanes: group by padded step length,
-            # dispatch every launch async, fetch once at drain.
-            buckets: dict[int, list[int]] = {}
-            for i, r in zip(short_idx,
-                            assign_step_buckets(
-                                [steps_of[i].n_steps for i in short_idx])):
-                buckets.setdefault(r, []).append(i)
+            # Every bucket's launches dispatch async and are fetched at
+            # drain.
             def _fetch_launch(entry):
                 part, part_steps, dev, lctx, perm = entry
                 t0f = time.monotonic_ns()
                 try:
-                    fetched = np.asarray(dev)
+                    with obs.phase("sched.fetch_wait"):
+                        fetched = np.asarray(dev)
                 except Exception as e:
                     # The drain fetch is where a dead backend finally
                     # surfaces for async launches — tell the supervisor
@@ -324,20 +327,21 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                 # padding/straggler decomposition sees the real wait.
                 obs.get_ledger().record_fetch(t0f, time.monotonic_ns(),
                                               ctx=lctx)
-                if perm is None:
-                    rows = fetched[:len(part)]
-                else:
-                    # Shard packing permuted the batch: row j holds
-                    # original lane perm[j]; invert to read the real
-                    # histories back in part order.
-                    inv = [0] * len(perm)
-                    for j, p in enumerate(perm):
-                        inv[p] = j
-                    rows = fetched[[inv[p] for p in range(len(part))]]
-                out = wgl3.unpack_np(rows)
-                for i, one in zip(part, wgl3.assemble_batch_results(
-                        out, part_steps, cfg)):
-                    results[i] = one
+                with obs.phase("sched.unpack"):
+                    if perm is None:
+                        rows = fetched[:len(part)]
+                    else:
+                        # Shard packing permuted the batch: row j holds
+                        # original lane perm[j]; invert to read the real
+                        # histories back in part order.
+                        inv = [0] * len(perm)
+                        for j, p in enumerate(perm):
+                            inv[p] = j
+                        rows = fetched[[inv[p] for p in range(len(part))]]
+                    out = wgl3.unpack_np(rows)
+                    for i, one in zip(part, wgl3.assemble_batch_results(
+                            out, part_steps, cfg)):
+                        results[i] = one
 
             # In-flight launch window (plan/dispatch.py LaunchPipeline,
             # depth = limits().pod_pipeline_depth): bucket N+1's host
@@ -354,50 +358,53 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                 per_hist = max(1, r * (cfg.k_slots * 5 + 1))
                 chunk = max(1, lim.stack_element_budget // per_hist)
                 for c0 in range(0, len(idxs), chunk):
-                    part = idxs[c0:c0 + chunk]
-                    part_steps = [steps_of[i] for i in part]
-                    # Bucket FIRST, then derive the sharding multiple
-                    # from the bucketed size: the launcher picks the
-                    # sharded kernel by the PADDED batch, so a part the
-                    # bucket inflates past 1 must pad to the device
-                    # multiple even though the raw part would have run
-                    # single-history (a batch_bucket_floor that is not a
-                    # multiple of the device count — any tuned floor on a
-                    # pod — crashed here otherwise).
-                    b0 = _batch_bucket(len(part), chunk)
-                    mult = _launch_multiple(model, cfg, b0, r)
-                    b = (b0 + mult - 1) // mult * mult
-                    run, plan_obj = _dense_bucket_launcher(model, cfg,
-                                                           b, r)
-                    padded = part_steps + [_pad_rs(k)] * (b - len(part))
-                    # Scaling ledger launch context: plan identity +
-                    # the bucket economics (real vs padded steps/batch,
-                    # per-shard real steps for straggler attribution) —
-                    # the instrumented kernel call and the H2D staging
-                    # inside the block inherit it.
-                    real = sum(s.n_steps for s in part_steps)
-                    lctx = obs.ledger.plan_context(plan_obj)
-                    lctx.update(batch_real=len(part), batch_padded=b,
-                                steps_real=real, steps_padded=b * r)
-                    perm = None
-                    n_shards = lctx.get("n_shards", 1)
-                    if n_shards > 1:
-                        if lim.shard_bucket_mode:
-                            # Shard-aware packing: permute the padded
-                            # batch so contiguous per-shard blocks carry
-                            # balanced REAL steps (pads interleave
-                            # instead of stacking on the tail shards).
-                            perm = lpt_shard_order(
+                    with obs.phase("sched.pack"):
+                        part = idxs[c0:c0 + chunk]
+                        part_steps = [steps_of[i] for i in part]
+                        # Bucket FIRST, then derive the sharding multiple
+                        # from the bucketed size: the launcher picks the
+                        # sharded kernel by the PADDED batch, so a part the
+                        # bucket inflates past 1 must pad to the device
+                        # multiple even though the raw part would have run
+                        # single-history (a batch_bucket_floor that is not a
+                        # multiple of the device count — any tuned floor on a
+                        # pod — crashed here otherwise).
+                        b0 = _batch_bucket(len(part), chunk)
+                        mult = _launch_multiple(model, cfg, b0, r)
+                        b = (b0 + mult - 1) // mult * mult
+                        run, plan_obj = _dense_bucket_launcher(model, cfg,
+                                                               b, r)
+                        padded = part_steps + [_pad_rs(k)] * (b - len(part))
+                        # Scaling ledger launch context: plan identity +
+                        # the bucket economics (real vs padded steps/batch,
+                        # per-shard real steps for straggler attribution) —
+                        # the instrumented kernel call and the H2D staging
+                        # inside the block inherit it.
+                        real = sum(s.n_steps for s in part_steps)
+                        lctx = obs.ledger.plan_context(plan_obj)
+                        lctx.update(batch_real=len(part), batch_padded=b,
+                                    steps_real=real, steps_padded=b * r)
+                        perm = None
+                        n_shards = lctx.get("n_shards", 1)
+                        if n_shards > 1:
+                            if lim.shard_bucket_mode:
+                                # Shard-aware packing: permute the padded
+                                # batch so contiguous per-shard blocks carry
+                                # balanced REAL steps (pads interleave
+                                # instead of stacking on the tail shards).
+                                perm = lpt_shard_order(
+                                    [s.n_steps for s in padded], n_shards)
+                                if perm == list(range(len(padded))):
+                                    perm = None
+                                else:
+                                    padded = [padded[j] for j in perm]
+                                    lctx["shard_packed"] = True
+                            lctx["shard_real"] = obs.ledger.shard_real_steps(
                                 [s.n_steps for s in padded], n_shards)
-                            if perm == list(range(len(padded))):
-                                perm = None
-                            else:
-                                padded = [padded[j] for j in perm]
-                                lctx["shard_packed"] = True
-                        lctx["shard_real"] = obs.ledger.shard_real_steps(
-                            [s.n_steps for s in padded], n_shards)
-                    with obs.ledger.launch_context(**lctx):
-                        arrays = wgl3.stack_steps3(padded, r)
+                        with obs.ledger.launch_context(**lctx):
+                            arrays = wgl3.stack_steps3(padded, r)
+                    with obs.phase("sched.dispatch"), \
+                            obs.ledger.launch_context(**lctx):
                         dev = run(*arrays)
                     pipe.submit((part, part_steps, dev, lctx, perm))
                     stats.record_launch(real, b, r)

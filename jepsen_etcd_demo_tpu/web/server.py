@@ -410,12 +410,10 @@ def _span_tree_html(records: list[dict]) -> str:
 
 
 def _kernel_attribution_html(metrics: dict) -> str:
-    """Per-kernel deep-attribution table (ISSUE 8): every kernel
-    geometry the run compiled, with its compile/execute wall (the
-    wgl.compile_s.<k>/wgl.execute_s.<k> histograms) and the XLA
-    cost_analysis estimates captured at lower time
-    (wgl.kernel_flops/kernel_bytes gauges). Empty string when the run
-    recorded no per-kernel series (pre-ISSUE-8 artifacts)."""
+    """Per-kernel attribution table: every kernel geometry the run
+    compiled, with its compile/execute wall (the
+    wgl.compile_s.<k>/wgl.execute_s.<k> histograms). Empty string when
+    the run recorded no per-kernel series (older artifacts)."""
     kernels: dict[str, dict] = {}
 
     def fold(prefix: str, field: str, value_of):
@@ -429,38 +427,25 @@ def _kernel_attribution_html(metrics: dict) -> str:
     fold("wgl.execute_s", "calls", lambda r: r.get("count", 0))
     fold("wgl.execute_s", "execute_s", lambda r: r.get("sum", 0.0))
     fold("wgl.execute_s", "p95_s", lambda r: r.get("p95"))
-    fold("wgl.kernel_flops", "flops", lambda r: r.get("last"))
-    fold("wgl.kernel_bytes", "bytes", lambda r: r.get("last"))
     if not kernels:
         return ""
-
-    def num(v, unit="") -> str:
-        if not isinstance(v, (int, float)):
-            return ""
-        if v >= 1e9:
-            return f"{v / 1e9:,.2f}G{unit}"
-        if v >= 1e6:
-            return f"{v / 1e6:,.2f}M{unit}"
-        if v >= 1e3:
-            return f"{v / 1e3:,.2f}k{unit}"
-        return f"{v:,.4g}{unit}"
 
     rows = []
     for k in sorted(kernels):
         r = kernels[k]
+        p95 = r.get("p95_s")
+        p95_cell = f"{p95:,.4g}s" if isinstance(p95, (int, float)) else ""
         rows.append(
             f"<tr><td><code>{html.escape(k)}</code></td>"
             f"<td>{r.get('compiles', 0)}</td>"
             f"<td>{r.get('compile_s', 0.0):.3f}</td>"
             f"<td>{r.get('calls', 0)}</td>"
             f"<td>{r.get('execute_s', 0.0):.3f}</td>"
-            f"<td>{num(r.get('p95_s'), 's')}</td>"
-            f"<td>{num(r.get('flops'))}</td>"
-            f"<td>{num(r.get('bytes'), 'B')}</td></tr>")
+            f"<td>{p95_cell}</td></tr>")
     return ("<h3>kernel attribution</h3>"
             "<table><tr><th>kernel</th><th>compiles</th>"
             "<th>compile s</th><th>calls</th><th>execute s</th>"
-            "<th>p95 call</th><th>flops/call</th><th>bytes/call</th></tr>"
+            "<th>p95 call</th></tr>"
             f"{''.join(rows)}</table>")
 
 

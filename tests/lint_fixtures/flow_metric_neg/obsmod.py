@@ -5,6 +5,9 @@ instead of colliding with the plain counter."""
 
 # jtflow: metrics preregistered
 PHASE_COUNTERS = ("wgl.compile_s", "wgl.execute_s")
+# Written through an index into the tuple: each member counts as written.
+# jtflow: metrics preregistered
+STAGE_SECONDS = ("wgl.stage_a_s", "wgl.stage_b_s")
 
 LABELED_FAMILIES = {
     "wgl.compile_s": "kernel",
@@ -23,6 +26,10 @@ def record_compile(m, dt, first):
         m.counter("wgl.compile_s").add(dt)
     else:
         m.counter("wgl.execute_s").add(dt)
+
+
+def record_stage(m, i, dt):
+    m.counter(STAGE_SECONDS[i]).add(dt)
 
 
 def instrument(m, kernel, dt):

@@ -15,7 +15,7 @@ import bench_compare  # noqa: E402
 
 
 def _record(eps: float, sched_eps: float = 5000.0,
-            stream_speedup: float = 1.4, flops: float = 2.0e9,
+            stream_speedup: float = 1.4,
             health: str = "healthy") -> dict:
     return {
         "metric": "wgl_check_throughput", "value": eps,
@@ -23,8 +23,6 @@ def _record(eps: float, sched_eps: float = 5000.0,
         "cache_hit_rate": 1.0,
         "kernel_phases": {"compile_s": 1.0, "execute_s": 2.0,
                           "encode_s": 0.5, "frontier_peak": 64,
-                          "flops": flops, "bytes": 4.0e8,
-                          "device_mem_peak": 0,
                           "profile_hash": "default"},
         "health": {"state": health, "last_transition": None},
         "degraded": False, "backend": "cpu",
@@ -142,31 +140,35 @@ def test_long_history_lane_dropped_also_fails(tmp_path):
 
 
 def test_flops_bytes_lanes_are_informational_only():
-    """ISSUE 8 satellite: the kernel_phases deep-attribution fields
-    compare as INFORMATIONAL lanes — deltas reported, never gated. A
-    50% flops drop alone must exit 0."""
-    res = bench_compare.compare(_record(1000.0),
-                                _record(1000.0, flops=1.0e9),
-                                threshold_pct=10.0)
+    """Older records still carry kernel_phases flops/bytes; they are no
+    lane any more. Informational lanes report deltas and never gate: a
+    50% drop in one alone must exit 0."""
+    old, new = _record(1000.0), _record(1000.0)
+    old["kernel_phases"].update(flops=2.0e9, bytes=4.0e8,
+                                device_mem_peak=0)
+    old["detail"]["dedup"] = {"raw_configs_per_sec": 2.0e6}
+    new["detail"]["dedup"] = {"raw_configs_per_sec": 1.0e6}
+    res = bench_compare.compare(old, new, threshold_pct=10.0)
     assert res["comparable"] is True and res["regressions"] == []
     by_lane = {r["lane"]: r for r in res["lanes"]}
-    assert by_lane["kernel_flops"]["informational"] is True
-    assert by_lane["kernel_flops"]["delta_pct"] == -50.0
-    assert by_lane["kernel_flops"]["regression"] is False
-    assert by_lane["kernel_bytes"]["delta_pct"] == 0.0
-    # device_mem_peak is 0 on CPU records: skipped, not divided by.
-    assert by_lane["device_mem_peak"].get("skipped") is True
+    assert not {"kernel_flops", "kernel_bytes",
+                "device_mem_peak"} & set(by_lane)
+    assert by_lane["dedup_raw_configs"]["informational"] is True
+    assert by_lane["dedup_raw_configs"]["delta_pct"] == -50.0
+    assert by_lane["dedup_raw_configs"]["regression"] is False
 
 
 def test_flops_absent_in_old_record_skips_silently():
-    """Pre-ISSUE-8 records have no flops field — the informational lane
-    skips without joining `missing` (it is not a measured perf lane)."""
-    old = _record(1000.0)
+    """A record without an informational field (an older round, or
+    kernel_phases missing) skips that lane without joining `missing`:
+    it is not a measured perf lane."""
+    old, new = _record(1000.0), _record(1000.0)
     del old["kernel_phases"]
-    res = bench_compare.compare(old, _record(1000.0))
+    new["detail"]["dedup"] = {"raw_configs_per_sec": 1.0e6}
+    res = bench_compare.compare(old, new)
     by_lane = {r["lane"]: r for r in res["lanes"]}
-    assert by_lane["kernel_flops"].get("skipped") is True
-    assert "kernel_flops" not in res["missing"]
+    assert by_lane["dedup_raw_configs"].get("skipped") is True
+    assert "dedup_raw_configs" not in res["missing"]
 
 
 def test_health_state_difference_not_comparable(tmp_path, capsys):

@@ -36,11 +36,11 @@ def test_sched_corpus_lane_contract():
     assert lane["cache_hit_rate"] == 1.0
     assert set(lane["kernel_phases"]) == {
         "compile_s", "execute_s", "encode_s", "frontier_peak",
-        "flops", "bytes", "device_mem_peak", "profile_hash"}
+        "profile_hash"}
     # ISSUE 16: the lane carries its windowed ledger attribution — the
     # loss buckets must explain >= 95% of the measured warm wall (the
     # lane itself asserts this; re-check the emitted object) — and the
-    # measured ledger overhead, asserted < 2% inside the lane.
+    # measured ledger overhead, reported (not asserted) by the lane.
     att = lane["ledger"]
     assert att["coverage"] >= 0.95, att
     assert set(att["buckets"]) == {

@@ -332,51 +332,20 @@ class TestWebEndpoints:
 
 
 class TestKernelCostContract:
-    def test_kernel_phases_flops_bytes_on_cpu(self):
-        """The CPU-path contract: a fresh jitted kernel's first call
-        under a capture lands nonzero flops/bytes in kernel_phases and
-        a per-kernel gauge pair; every field JSON-serializable."""
-        jax = pytest.importorskip("jax")
-        import jax.numpy as jnp
-
-        fn = obs.instrument_kernel(
-            "obs-export-cost-test",
-            jax.jit(lambda a, b: (a @ b).sum() + a.shape[0]))
-        with obs.capture() as cap:
-            x = jnp.ones((37, 41), jnp.float32)
-            fn(x, x.T)
-            fn(x, x.T)
-        phases = obs.kernel_phases(cap.metrics)
-        json.dumps(phases)
-        assert phases["flops"] > 0
-        assert phases["bytes"] > 0
-        assert phases["device_mem_peak"] >= 0   # CPU may not report one
-        snap = cap.metrics.snapshot()
-        assert snap["wgl.kernel_flops.obs-export-cost-test"]["last"] > 0
-        assert snap["wgl.kernel_bytes.obs-export-cost-test"]["last"] > 0
-        # Compile/execute attribution is unchanged by the cost capture.
-        assert snap["wgl.compile_calls"]["value"] == 1
-        assert snap["wgl.execute_calls"]["value"] == 1
-
-    def test_cost_capture_env_gate(self, monkeypatch):
-        jax = pytest.importorskip("jax")
-        import jax.numpy as jnp
-
-        monkeypatch.setenv("JEPSEN_TPU_KERNEL_COST", "0")
-        fn = obs.instrument_kernel(
-            "obs-export-cost-gated", jax.jit(lambda a: a * 2))
-        with obs.capture() as cap:
-            fn(jnp.ones((8,)))
-        phases = obs.kernel_phases(cap.metrics)
-        assert phases["flops"] == 0.0 and phases["bytes"] == 0.0
-        assert "wgl.kernel_flops.obs-export-cost-gated" \
-            not in cap.metrics.snapshot()
-
     def test_non_jit_callable_is_harmless(self):
+        """instrument_kernel wraps any callable: the result passes
+        through, and the first call counts as the compile, the rest as
+        executes."""
         fn = obs.instrument_kernel("obs-export-plain", lambda x: x + 1)
         with obs.capture() as cap:
             assert fn(1) == 2
-        assert obs.kernel_phases(cap.metrics)["flops"] == 0.0
+            assert fn(2) == 3
+        snap = cap.metrics.snapshot()
+        assert snap["wgl.compile_calls"]["value"] == 1
+        assert snap["wgl.execute_calls"]["value"] == 1
+        assert set(obs.kernel_phases(cap.metrics)) == {
+            "compile_s", "execute_s", "encode_s", "frontier_peak",
+            "profile_hash"}
 
 
 class TestTraceTruncationSurfacing:

@@ -29,9 +29,9 @@ and exits 0 — a round that measured nothing must not read as a 100%
 regression.
 Likewise two records whose backend-health states differ (`healthy` vs
 `degraded`/`wedged`, the ISSUE 8 supervisor stamp): not-comparable,
-with both states named. The kernel_phases deep-attribution fields
-(flops / bytes / device_mem_peak) are compared as INFORMATIONAL lanes
-— deltas printed, never regression-gated (gating stays on events/s).
+with both states named. INFO_LANES are compared as INFORMATIONAL
+lanes — deltas printed, never regression-gated (gating stays on
+events/s).
 
 Importable: `load_record(path)`, `compare(old, new, threshold_pct)` —
 `tests/test_bench_compare.py` smokes both plus the exit-code contract.
@@ -115,14 +115,10 @@ SCALING_LANES: list[tuple[str, tuple]] = [
 SCALING_MESH_PATH = ("scaling", "mesh_shape")
 # Long-history lanes: seconds, LOWER is better — handled via inversion.
 LONG_LANES_PATH = ("detail", "long_history")
-# Deep-attribution lanes (ISSUE 8): the kernel_phases cost_analysis
-# totals. INFORMATIONAL — a flops delta explains a throughput move
-# (did the work change, or the speed?) but is not itself a regression;
-# gating stays on events/s exactly as before.
+# INFORMATIONAL lanes: a delta here explains a throughput move (did
+# the work change, or the speed?) but is not itself a regression;
+# gating stays on events/s.
 INFO_LANES: list[tuple[str, tuple]] = [
-    ("kernel_flops", ("kernel_phases", "flops")),
-    ("kernel_bytes", ("kernel_phases", "bytes")),
-    ("device_mem_peak", ("kernel_phases", "device_mem_peak")),
     # Dedup-lane configs rates (ISSUE 10): raw (dedup-off) and unique
     # (canonical) configs/s are reported but NEVER gated — pruning
     # legitimately moves them, and the lane's gate is events/s above.
@@ -516,9 +512,9 @@ def compare(old: dict, new: dict,
         out["lanes"].append(row)
         if reg:
             out["regressions"].append(lane)
-    # Informational lanes: deltas reported, never gated (a flops move
+    # Informational lanes: deltas reported, never gated (a context move
     # explains a throughput move; it is not itself one). Absent fields
-    # (pre-ISSUE-8 records) skip silently in either direction.
+    # (records that predate a lane) skip silently in either direction.
     for lane, path in INFO_LANES:
         o, n = _dig(old, path), _dig(new, path)
         if o is None or n is None or o == 0:
