@@ -12,6 +12,11 @@ kernel launch; per-key histories are embarrassingly parallel, so the key axis
 is the batch axis (BASELINE.json configs[2]). Each distinct Linearizable
 entry gets its own batched launch under its own result name; every other
 composed checker still runs per key, unbatched.
+
+The split routes records to keys without copying them: the batched encode
+reads each key's records through their (key, v) values, and only a key
+that reaches a single-history check (or a model that translates
+histories) gets unwrapped Op copies.
 """
 
 from __future__ import annotations
@@ -24,16 +29,18 @@ from .base import Checker, merge_valid
 from .compose import Compose
 from .linearizable import Linearizable
 from .. import obs
-from ..ops.op import Op, INVOKE
+from ..ops.op import Op, INVOKE, keyed_value
 
 
-def split_by_key(history: Sequence[Op]) -> dict[Any, list[Op]]:
-    """Split a tuple-valued history into per-key sub-histories.
+def route_by_key(history: Sequence[Op]) -> dict[Any, list[Op]]:
+    """Route a tuple-valued history's records to their keys, uncopied.
 
     Invocations carry (key, v) tuples; completions may or may not (e.g. a
     :write completion keeps the tuple, a timeout :info has whatever the invoke
     had). Like jepsen.independent, the key is taken from the op's tuple value;
-    completions are routed to the key of their pending invocation.
+    completions are routed to the key of their pending invocation, and
+    orphan completions and nemesis records are dropped. Each key's list
+    holds the original records in history order, values still keyed.
     """
     keyed: dict[Any, list[Op]] = {}
     key_of_process: dict[Any, Any] = {}
@@ -44,18 +51,44 @@ def split_by_key(history: Sequence[Op]) -> dict[Any, list[Op]]:
             if not (isinstance(op.value, tuple) and len(op.value) == 2):
                 raise ValueError(
                     f"independent history op without (key, value) tuple: {op}")
-            k, v = op.value
+            k = op.value[0]
             key_of_process[op.process] = k
         else:
             k = key_of_process.pop(op.process, None)
             if k is None:
                 continue
-            v = op.value[1] if (isinstance(op.value, tuple)
-                                and len(op.value) == 2) else op.value
-        sub = Op(type=op.type, f=op.f, value=v, process=op.process,
-                 time=op.time, index=op.index, error=op.error, seq=op.seq)
-        keyed.setdefault(k, []).append(sub)
+        keyed.setdefault(k, []).append(op)
+    obs.get_metrics().counter("check.split.records_routed").add(
+        sum(map(len, keyed.values())))
     return keyed
+
+
+def _unwrap(op: Op) -> Op:
+    """A copy of a routed record with its value unwrapped (keyed_value)."""
+    return Op(type=op.type, f=op.f, value=keyed_value(op),
+              process=op.process, time=op.time, index=op.index,
+              error=op.error, seq=op.seq)
+
+
+class _Unwrapped(dict):
+    """Each key's unwrapped sub-history, copied on first use and shared
+    by every reader of that key."""
+
+    def __init__(self, routed: dict[Any, list[Op]]):
+        super().__init__()
+        self.routed = routed
+
+    def __missing__(self, key) -> list[Op]:
+        sub = self[key] = [_unwrap(op) for op in self.routed[key]]
+        obs.get_metrics().counter("check.split.records_copied").add(len(sub))
+        return sub
+
+
+def split_by_key(history: Sequence[Op]) -> dict[Any, list[Op]]:
+    """Split a tuple-valued history into per-key sub-histories of
+    unwrapped copies (route_by_key's routing, keyed_value's values)."""
+    subs = _Unwrapped(route_by_key(history))
+    return {k: subs[k] for k in subs.routed}
 
 
 class IndependentChecker(Checker):
@@ -66,15 +99,19 @@ class IndependentChecker(Checker):
     def check(self, test: dict, history: Sequence[Op],
               opts: dict | None = None) -> dict[str, Any]:
         with obs.phase("check.split"):
-            keyed = split_by_key(history)
-            keys = sorted(keyed, key=str)
-        if not keyed:
+            routed = route_by_key(history)
+            keys = sorted(routed, key=str)
+        if not routed:
             return {"valid": True, "key_count": 0}
+        # Unwrapped copies only for what reads Op values: a key that
+        # reaches a sub-checker's single-history path, and the batched
+        # encode of a model that translates histories.
+        subs = _Unwrapped(routed)
 
         # Which checkers can ride the batched kernel? Only direct entries:
         # either the sub-checker itself, or first-level values of a Compose.
         batchable: dict[str | None, Linearizable] = {}
-        if self.batch_jax and len(keyed) > 1:
+        if self.batch_jax and len(routed) > 1:
             if (isinstance(self.sub_checker, Linearizable)
                     and self.sub_checker.backend == "jax"):
                 batchable[None] = self.sub_checker
@@ -97,7 +134,11 @@ class IndependentChecker(Checker):
 
         def batch_keys(lin: Linearizable) -> dict[Any, list[Op]]:
             settled = settled_for(lin)
-            return {k: h for k, h in keyed.items() if k not in settled}
+            todo = [k for k in routed if k not in settled]
+            if not lin.model.translates():
+                return {k: routed[k] for k in todo}
+            with obs.phase("check.split"):
+                return {k: subs[k] for k in todo}
 
         # The call's intermediates (encodings), freed in check.release.
         held: list = []
@@ -109,17 +150,17 @@ class IndependentChecker(Checker):
 
         results: dict[Any, dict] = {}
         for k in keys:
-            results[k] = self._check_key(test, keyed[k], opts, batched, k)
+            results[k] = self._check_key(test, subs, opts, batched, k)
         valid = merge_valid([r.get("valid") for r in results.values()])
-        out = {"valid": valid, "key_count": len(keyed),
+        out = {"valid": valid, "key_count": len(routed),
                "results": {str(k): v for k, v in results.items()}}
-        # Free the keyed sub-histories and encodings here, inside a
+        # Free the routed lists, copies and encodings here, inside a
         # phase, rather than after the call's last one.
         with obs.phase("check.release"):
-            del keyed, batched, results, held
+            del routed, subs, batched, results, held
         return out
 
-    def _check_key(self, test, sub_history, opts, batched, key):
+    def _check_key(self, test, subs, opts, batched, key):
         opts = dict(opts or {})
         opts["key"] = key  # sub-checkers emit per-key artifacts (timeline)
 
@@ -136,7 +177,7 @@ class IndependentChecker(Checker):
             if pre and pre.get("f_cap_floor"):
                 sub_opts = dict(opts)
                 sub_opts["f_cap_floor"] = pre["f_cap_floor"]
-            return checker.check(test, sub_history, sub_opts)
+            return checker.check(test, subs[key], sub_opts)
 
         if not isinstance(self.sub_checker, Compose):
             return pick(None, self.sub_checker)
@@ -172,7 +213,11 @@ def _batched_linearizable_traced(lin: Linearizable,
     from ..ops import wgl3
 
     with obs.phase("check.encode"):
-        event_encs = {k: lin.encode(h) for k, h in keyed.items()}
+        # Routed records in place; a model that translates histories
+        # was given unwrapped copies (IndependentChecker.check).
+        in_place = not lin.model.translates()
+        event_encs = {k: lin.encode(h, keyed=in_place)
+                      for k, h in keyed.items()}
         held.append(event_encs)
         max_value = max(e.max_value for e in event_encs.values())
         tight = max(wgl3.tight_k_slots(e) for e in event_encs.values())

@@ -62,29 +62,42 @@ class Linearizable(Checker):
         self.time_budget_s = time_budget_s
 
     # -- encoding ---------------------------------------------------------
-    def encode(self, history: Sequence[Op]) -> EncodedHistory:
-        return self._encode_translated(self.model.prepare_history(history))
+    def encode(self, history: Sequence[Op],
+               keyed: bool = False) -> EncodedHistory:
+        """`keyed`: one key's records of an independent-key history, read
+        in place (ops/encode.pair_history); the encoding equals that of
+        their unwrapped copies. A model that translates histories reads
+        only unwrapped copies."""
+        if keyed and self.model.translates():
+            raise ValueError(f"model {self.model.name!r} translates "
+                             f"histories: encode unwrapped copies")
+        return self._encode_translated(self.model.prepare_history(history),
+                                       keyed)
 
-    def _encode_translated(self, history: Sequence[Op]) -> EncodedHistory:
+    def _encode_translated(self, history: Sequence[Op],
+                           keyed: bool = False) -> EncodedHistory:
         # Encoded-tensor cache (store/encode_cache.py): replays of an
         # unchanged history skip the pair/encode pass entirely. Inactive
         # unless the CLI (analyze/corpus) switched it on; the key covers
         # exactly the encoder's input, so a hit is bit-identical.
         from ..store import encode_cache
 
-        cached = encode_cache.lookup(history, self.model.name, self.k_slots)
+        cached = encode_cache.lookup(history, self.model.name, self.k_slots,
+                                     keyed)
         if cached is not None:
             return cached
         k = self.k_slots
         while True:
             try:
-                enc = encode_history(history, self.model, k_slots=k)
+                enc = encode_history(history, self.model, k_slots=k,
+                                     keyed=keyed)
                 break
             except SlotOverflow:
                 if k >= 4096:
                     raise
                 k *= 2
-        encode_cache.store(history, self.model.name, self.k_slots, enc)
+        encode_cache.store(history, self.model.name, self.k_slots, enc,
+                           keyed)
         return enc
 
     # -- checking ---------------------------------------------------------
@@ -143,7 +156,7 @@ class Linearizable(Checker):
             return res
         # Fault-plane ops (nemesis start/stop) are not client operations —
         # drop them like knossos does [dep]. Workloads under the
-        # independent wrapper never see them (split_by_key filters), but a
+        # independent wrapper never see them (route_by_key filters), but a
         # bare whole-history checker (multiregister workload) does.
         with obs.phase("check.encode"):
             history = [op for op in history if op.process != "nemesis"]

@@ -92,6 +92,11 @@ class Model(abc.ABC):
             return f"cas({a1} -> {a2})"
         return f"op({f}, {a1}, {a2}, {rv})"
 
+    def translates(self) -> bool:
+        """Whether prepare_history rewrites histories (the mutex and
+        queue models): their ops are then read only after it has run."""
+        return type(self).prepare_history is not Model.prepare_history
+
     @abc.abstractmethod
     def init_state(self) -> int:
         ...

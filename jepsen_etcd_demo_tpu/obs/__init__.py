@@ -55,6 +55,8 @@ are never absent from metrics.json or the bench's kernel_phases):
                      a phase (inside a check)
   compile.*          jax trace / lower / backend-compile seconds and
                      persistent-cache misses (COMPILE_COUNTERS)
+  check.split.*      records routed by key and records copied as
+                     unwrapped Ops (SPLIT_COUNTERS)
 """
 
 from __future__ import annotations
@@ -181,6 +183,12 @@ PHASE_SECONDS = ("check.split_s", "check.encode_s", "sched.pack_s",
                  "sched.dispatch_s", "sched.fetch_wait_s",
                  "sched.unpack_s", "check.witness_s", "check.release_s")
 _PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
+# The per-key split (checkers/independent.py): records routed to a key,
+# and records copied as unwrapped Ops (split_by_key, or a key that
+# reaches a single-history check). Their ratio is the copy share.
+# jtflow: metrics preregistered
+SPLIT_COUNTERS = ("check.split.records_routed",
+                  "check.split.records_copied")
 # Garbage-collection seconds (a gc.callbacks hook while a capture is
 # open) of collections that start inside a phase. They overlap the
 # phases: never part of their sum.
@@ -311,7 +319,8 @@ class Capture:
                     + TRACE_COUNTERS + ELLE_COUNTERS + SERVE_COUNTERS \
                     + SYNC_COUNTERS + CAMPAIGN_COUNTERS \
                     + LEDGER_COUNTERS + FLEET_COUNTERS \
-                    + SPILL_COUNTERS + PHASE_SECONDS + COMPILE_COUNTERS:
+                    + SPILL_COUNTERS + PHASE_SECONDS + COMPILE_COUNTERS \
+                    + SPLIT_COUNTERS:
                 self.metrics.counter(name)
             self.metrics.counter(GC_COUNTER)
             for name in ELLE_GAUGES + SERVE_GAUGES + CAMPAIGN_GAUGES \

@@ -43,7 +43,7 @@ from typing import Any, Optional, Sequence
 import numpy as np
 
 from ..obs import get_ledger, get_metrics
-from .op import Op, INVOKE, OK, FAIL, INFO
+from .op import Op, INVOKE, OK, FAIL, INFO, keyed_value
 
 # Value encoding. The reference register draws values from (rand-int 5), i.e.
 # 0..4 (src/jepsen/etcdemo.clj:68-69); NIL encodes "key missing" observed by a
@@ -175,7 +175,8 @@ def register_fields(f_name: str, invoke_value: Any, ok_value: Any,
     return f, a1, a2, rv
 
 
-def pair_history(history: Sequence[Op], model=None) -> list[Invocation]:
+def pair_history(history: Sequence[Op], model=None,
+                 keyed: bool = False) -> list[Invocation]:
     """Pair invoke entries with their completions by process id.
 
     Mirrors the framework recorder's pairing [dep]; a process has at most one
@@ -186,6 +187,10 @@ def pair_history(history: Sequence[Op], model=None) -> list[Invocation]:
     `model` supplies the op-language codec (Model.encode_invocation); None
     uses the register conventions — the language of the reference demo and
     of every model whose prepare_history translates into it.
+
+    `keyed`: the history is one key's records of an independent-key
+    history, values still `(key, v)`; the codec sees them through
+    op.keyed_value, as it would see split_by_key's unwrapped copies.
     """
     pending: dict[Any, tuple[int, Op]] = {}
     out: list[Invocation] = []
@@ -204,28 +209,31 @@ def pair_history(history: Sequence[Op], model=None) -> list[Invocation]:
                     f"{idx} has no pending invocation"
                 )
             inv_idx, inv = pending.pop(op.process)
-            out.append(_make_invocation(inv, op, inv_idx, idx, model))
+            out.append(_make_invocation(inv, op, inv_idx, idx, model, keyed))
         else:
             raise EncodeError(f"unknown op type {op.type!r} at index {idx}")
     # Unfinished invocations: open forever.
     for proc, (inv_idx, inv) in pending.items():
-        out.append(_make_invocation(inv, None, inv_idx, -1, model))
+        out.append(_make_invocation(inv, None, inv_idx, -1, model, keyed))
     out.sort(key=lambda i: i.invoke_index)
     return out
 
 
 def _make_invocation(inv: Op, comp: Optional[Op], inv_idx: int,
-                     comp_idx: int, model=None) -> Invocation:
+                     comp_idx: int, model=None,
+                     keyed: bool = False) -> Invocation:
     status = comp.type if comp is not None else INFO
     # The completion value reaches the codec for OK *and* INFO: an
     # indeterminate op may still carry the value it tried to take (e.g. a
     # dequeue whose compare-and-delete response was lost after claiming a
     # known element — clients/etcd.py IndeterminateDequeue), which is what
     # makes it encodable as a pending op.
-    comp_value = (comp.value if comp is not None
-                  and comp.type in (OK, INFO) else None)
+    comp_value = None
+    if comp is not None and comp.type in (OK, INFO):
+        comp_value = keyed_value(comp) if keyed else comp.value
+    inv_value = keyed_value(inv) if keyed else inv.value
     codec = register_fields if model is None else model.encode_invocation
-    f, a1, a2, rv = codec(inv.f, inv.value, comp_value, status)
+    f, a1, a2, rv = codec(inv.f, inv_value, comp_value, status)
     return Invocation(f=f, a1=a1, a2=a2, rv=rv, status=status,
                       invoke_index=inv_idx, complete_index=comp_idx,
                       process=inv.process)
@@ -497,14 +505,15 @@ def encode_register_history(history: Sequence[Op], k_slots: int = 32
     return encode_events(pair_history(history), k_slots=k_slots)
 
 
-def encode_history(history: Sequence[Op], model, k_slots: int = 32
-                   ) -> EncodedHistory:
+def encode_history(history: Sequence[Op], model, k_slots: int = 32,
+                   keyed: bool = False) -> EncodedHistory:
     """History in `model`'s op language -> padded event tensor.
 
     Does NOT apply model.prepare_history — the checker seam translates once
     (checkers/linearizable.py) so witness reconstruction sees the same op
-    language the encoder did."""
-    return encode_events(pair_history(history, model), k_slots=k_slots)
+    language the encoder did. `keyed`: as in pair_history."""
+    return encode_events(pair_history(history, model, keyed),
+                         k_slots=k_slots)
 
 
 def reslot_events(enc: EncodedHistory, k_slots: int) -> EncodedHistory:

@@ -73,6 +73,15 @@ class Op:
         return Op(**d)
 
 
+def keyed_value(op: Op) -> Any:
+    """The value of a record of an independent-key history, without its
+    key: `v` of a `(key, v)` value, else the value as recorded (a
+    completion may carry a bare value, e.g. a timeout's :info). An invoke
+    always carries the tuple (checkers/independent.route_by_key checks)."""
+    v = op.value
+    return v[1] if isinstance(v, tuple) and len(v) == 2 else v
+
+
 def _jsonable(x):
     if isinstance(x, (set, frozenset)):
         return sorted(x)

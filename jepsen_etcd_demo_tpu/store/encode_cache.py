@@ -31,6 +31,7 @@ import numpy as np
 from ..obs import get_metrics
 from ..ops.encode import ENCODING_VERSION, EncodedHistory
 from ..ops.limits import limits
+from ..ops.op import keyed_value
 
 CACHE_DIRNAME = ".encode-cache"
 
@@ -72,16 +73,18 @@ def activated(root: str | os.PathLike | None,
 
 
 def history_fingerprint(history: Sequence, model_name: str,
-                        k_slots: int) -> str:
+                        k_slots: int, keyed: bool = False) -> str:
     """sha256 over exactly the fields the encoder consumes (encode.py
     pair_history: type, f, value, process — time/index never reach the
     tensors), plus the codec (model), requested slot width, and the
-    encoder version (an encoder fix invalidates every entry)."""
+    encoder version (an encoder fix invalidates every entry). `keyed`
+    reads each value as the encoder does (op.keyed_value), so one key's
+    records hash as their unwrapped copies (split_by_key) do."""
     h = hashlib.sha256()
     h.update(f"v{ENCODING_VERSION}|{model_name}|{k_slots}".encode())
     for op in history:
-        h.update(
-            f"\n{op.type}|{op.f}|{op.value!r}|{op.process!r}".encode())
+        v = keyed_value(op) if keyed else op.value
+        h.update(f"\n{op.type}|{op.f}|{v!r}|{op.process!r}".encode())
     return h.hexdigest()
 
 
@@ -91,15 +94,15 @@ def _entry_path(fingerprint: str) -> Optional[Path]:
     return _active_root / f"{fingerprint}.npz"
 
 
-def lookup(history: Sequence, model_name: str,
-           k_slots: int) -> Optional[EncodedHistory]:
+def lookup(history: Sequence, model_name: str, k_slots: int,
+           keyed: bool = False) -> Optional[EncodedHistory]:
     """Cached EncodedHistory for this (history, model, k_slots), or None
-    (cache inactive, refresh mode, miss, or unreadable entry)."""
-    if _refresh:
+    (cache inactive, refresh mode, miss, or unreadable entry). An
+    inactive cache hashes nothing."""
+    if _refresh or _active_root is None:
         return None
-    path = _entry_path(history_fingerprint(history, model_name, k_slots))
-    if path is None:
-        return None
+    path = _entry_path(
+        history_fingerprint(history, model_name, k_slots, keyed))
     m = get_metrics()
     try:
         with np.load(path) as z:
@@ -118,12 +121,13 @@ def lookup(history: Sequence, model_name: str,
 
 
 def store(history: Sequence, model_name: str, k_slots: int,
-          enc: EncodedHistory) -> None:
+          enc: EncodedHistory, keyed: bool = False) -> None:
     """Persist an encoding under its input fingerprint (atomic replace:
     concurrent replays of the same store race benignly)."""
-    path = _entry_path(history_fingerprint(history, model_name, k_slots))
-    if path is None:
+    if _active_root is None:
         return
+    path = _entry_path(
+        history_fingerprint(history, model_name, k_slots, keyed))
     try:
         path.parent.mkdir(parents=True, exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".npz")

@@ -662,7 +662,7 @@ def session_for_test(test: dict):
             return ElleStreamSession(elle)
         return None
     lin, keyed = found
-    if type(lin.model).prepare_history is not Model.prepare_history:
+    if lin.model.translates():
         log.info("check-mode stream: model %r translates histories; "
                  "falling back to post-hoc", lin.model.name)
         return None
