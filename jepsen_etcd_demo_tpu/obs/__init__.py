@@ -57,6 +57,8 @@ are never absent from metrics.json or the bench's kernel_phases):
                      persistent-cache misses (COMPILE_COUNTERS)
   check.split.*      records routed by key and records copied as
                      unwrapped Ops (SPLIT_COUNTERS)
+  sched.shard_*      batch-sharded launches and their largest shard's
+                     real steps x the shard count (SHARD_COUNTERS)
 """
 
 from __future__ import annotations
@@ -189,6 +191,13 @@ _PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
 # jtflow: metrics preregistered
 SPLIT_COUNTERS = ("check.split.records_routed",
                   "check.split.records_copied")
+# The batch-sharded launches of the corpus scheduler (sched/engine.py):
+# for each, the shard count x the largest shard's real steps (every shard
+# waits for that one), and the launches themselves. Over
+# sched.steps_real the first is the shard waste: 1.0 when every shard
+# sweeps equal real work.
+# jtflow: metrics preregistered
+SHARD_COUNTERS = ("sched.shard_steps_max", "sched.shard_launches")
 # Garbage-collection seconds (a gc.callbacks hook while a capture is
 # open) of collections that start inside a phase. They overlap the
 # phases: never part of their sum.
@@ -320,7 +329,7 @@ class Capture:
                     + SYNC_COUNTERS + CAMPAIGN_COUNTERS \
                     + LEDGER_COUNTERS + FLEET_COUNTERS \
                     + SPILL_COUNTERS + PHASE_SECONDS + COMPILE_COUNTERS \
-                    + SPLIT_COUNTERS:
+                    + SPLIT_COUNTERS + SHARD_COUNTERS:
                 self.metrics.counter(name)
             self.metrics.counter(GC_COUNTER)
             for name in ELLE_GAUGES + SERVE_GAUGES + CAMPAIGN_GAUGES \

@@ -183,7 +183,11 @@ class _Stats:
             if isinstance(dedup, dict):
                 self.configs_pruned += int(dedup.get("configs_pruned", 0))
 
-    def record_launch(self, real: int, b: int, r: int) -> None:
+    def record_launch(self, real: int, b: int, r: int,
+                      shard_real: Sequence[int] | None = None) -> None:
+        """One launch's steps; `shard_real` (the real steps of each of
+        the mesh's shards) on the sharded route, where every shard waits
+        for the one with the most real steps."""
         padded = b * r
         with self._lock:
             self.steps_real += real
@@ -194,6 +198,10 @@ class _Stats:
         m.counter("sched.steps_real").add(real)
         m.counter("sched.steps_padded").add(padded)
         m.counter("sched.launches").add(1)
+        if shard_real:
+            m.counter("sched.shard_steps_max").add(
+                len(shard_real) * max(shard_real))
+            m.counter("sched.shard_launches").add(1)
         if real:
             m.gauge("sched.padding_waste_ratio").set(padded / real)
 
@@ -407,7 +415,7 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                             obs.ledger.launch_context(**lctx):
                         dev = run(*arrays)
                     pipe.submit((part, part_steps, dev, lctx, perm))
-                    stats.record_launch(real, b, r)
+                    stats.record_launch(real, b, r, lctx.get("shard_real"))
                     kernels.add(plan_obj.label)
             pipe.drain()
             if pipe.dispatched:

@@ -187,3 +187,36 @@ def test_grouped_kernel_sharded_on_four_chips(topo):
                    _sds((CORPUS_B, CORPUS_R, sp, 128), jnp.uint32,
                         sh(None, None, None)))
     assert "tpu_custom_call" in hlo
+
+
+# The register-indep-4chip cell: one 4096-key test history (k_slots 16)
+# runs as three step buckets, each padded to the mesh multiple and
+# LPT-packed; (global batch, steps) of each launch (benchmark pool
+# 2022, CPU reckoning of sched/engine.py's bucketing).
+FOUR_CHIP_K = 16
+FOUR_CHIP_LAUNCHES = [(3072, 64), (1536, 96), (16, 48)]
+
+
+@pytest.mark.parametrize("B,R", FOUR_CHIP_LAUNCHES)
+def test_grouped_kernel_sharded_at_four_chip_cell_shapes(topo, B, R):
+    """The launches of a 4096-key check on a 2x2 v5e: each chip runs the
+    grouped kernel over its quarter of the batch (768, 384 and 4
+    histories) under shard_map, whole groups per chip."""
+    from jax.sharding import Mesh
+
+    from jepsen_etcd_demo_tpu.parallel.dense import (
+        sharded_batch_checker_pallas)
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("batch",))
+    cfg = _cfg(FOUR_CHIP_K)
+    G = wgl3_pallas.pallas_group_size(cfg, R, B // 4)
+    assert G > 1 and B % (4 * G) == 0
+    assert wgl3_pallas.pallas_feasible(cfg, R, B // 4, group=G)
+    check = sharded_batch_checker_pallas(MODEL, cfg, mesh, group=G)
+    sp = max(8, (cfg.n_states + 7) // 8 * 8)
+    sh = lambda *spec: NamedSharding(mesh, P("batch", *spec))  # noqa: E731
+    hlo = _compile(check.sharded_launch(B // 4, R),
+                   _sds((B,), jnp.int32, sh()),
+                   _sds((B, R), jnp.int32, sh(None)),
+                   _sds((B, R, sp, 128), jnp.uint32, sh(None, None, None)))
+    assert "tpu_custom_call" in hlo
