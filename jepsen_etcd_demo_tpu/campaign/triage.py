@@ -84,7 +84,7 @@ def failing_op(history: Sequence[Op], model, dead_step: int
     """The concrete completion op whose return step killed the
     frontier. Return steps are exactly the ok completions of the
     model-translated history in completion order (ops/encode.py
-    _timeline_points: fail ops and info reads never emit EV_RETURN), so
+    _event_points: fail ops and info reads never emit EV_RETURN), so
     dead_step indexes that list directly."""
     prepared = model.prepare_history(
         [op for op in history if op.process != "nemesis"])

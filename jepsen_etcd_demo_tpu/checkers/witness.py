@@ -28,8 +28,7 @@ import numpy as np
 
 from ..models.base import Model
 from ..ops.encode import (EncodedHistory, EV_INVOKE, EV_RETURN, F_READ,
-                          F_WRITE, F_CAS, NIL, Invocation, event_sources,
-                          pair_history)
+                          F_WRITE, F_CAS, NIL, Invocation, event_sources)
 from ..ops.op import Op
 
 # Reconstruction is exponential-ish like the search itself; witnesses are
@@ -75,7 +74,7 @@ def _inv_info(inv: Optional[Invocation]) -> dict[str, Any]:
 def _sources_fn(history: Sequence[Op] | None, model):
     sources: list[Optional[Invocation]] = []
     if history is not None:
-        sources = list(event_sources(pair_history(history, model)))
+        sources = event_sources(history, model)
 
     def src(i: int) -> Optional[Invocation]:
         return sources[i] if i < len(sources) else None

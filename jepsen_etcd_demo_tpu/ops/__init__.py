@@ -11,7 +11,6 @@ from .encode import (  # noqa: F401
     EV_PAD,
     Invocation,
     pair_history,
-    encode_events,
     encode_register_history,
     EncodedHistory,
 )

@@ -141,6 +141,8 @@ class EncodedHistory:
 
 
 def _encode_value(v: Any) -> int:
+    if type(v) is int and v >= 0:
+        return v                     # the common case, checked first
     if v is None:
         return NIL
     v = int(v)
@@ -239,86 +241,101 @@ def _make_invocation(inv: Op, comp: Optional[Op], inv_idx: int,
                       process=inv.process)
 
 
-def _timeline_points(invocations: Sequence[Invocation]
-                     ) -> list[tuple[int, int, Invocation]]:
-    """(history_index, is_return, invocation) per event, in event order.
+def _codec_of(model):
+    """`model`'s op-language codec, bound once per history:
+    register_fields itself when the model keeps the default (None
+    included), so the pass makes no per-op call through the Model."""
+    from ..models.base import Model
 
-    Single source of the event-ordering rule shared by encode_events and
-    event_sources: each included invocation contributes an invoke point and,
-    when status == ok, a return point; `fail` ops and `info` reads are
-    excluded (see module docstring)."""
-    points: list[tuple[int, int, Invocation]] = []
-    for inv in invocations:
-        if inv.status == FAIL:
+    if model is None or \
+            type(model).encode_invocation is Model.encode_invocation:
+        return register_fields
+    return model.encode_invocation
+
+
+def _event_points(history: Sequence[Op], codec, keyed: bool = False,
+                  complete: Optional[dict[int, int]] = None) -> list:
+    """The event each record gives, in record order: the (f, a1, a2, rv)
+    fields of an included invoke, the invoke's record index at the ok
+    completion of an included op, None for every other record.
+
+    The one copy of the inclusion and ordering rule: `fail` ops and `info`
+    reads give no event (module docstring). Each record gives at most one
+    event, at its own position, so record order is event order. Pairs by
+    process as pair_history does and raises the same EncodeError on the
+    shapes it rejects; `codec` (_codec_of) sees every paired op, `fail`
+    ops too, in completion order, then the never-completed invokes as
+    `info`, its values read as _make_invocation reads them. `complete`,
+    when given, maps each included invoke's index to its completion's."""
+    points: list = [None] * len(history)
+    pending: dict[Any, int] = {}     # process -> invoke index
+    for idx, op in enumerate(history):
+        t = op.type
+        if t == INVOKE:
+            if op.process in pending:
+                raise EncodeError(
+                    f"process {op.process} invoked twice without completing "
+                    f"(history indices {pending[op.process]} and {idx})")
+            pending[op.process] = idx
             continue
-        if inv.status == INFO and inv.f == F_READ:
-            continue  # an indeterminate read imposes no constraint
-        points.append((inv.invoke_index, 0, inv))
-        if inv.status == OK:
-            points.append((inv.complete_index, 1, inv))
-    points.sort(key=lambda p: (p[0], p[1]))
+        if t != OK and t != FAIL and t != INFO:
+            raise EncodeError(f"unknown op type {t!r} at index {idx}")
+        inv_idx = pending.pop(op.process, -1)
+        if inv_idx < 0:
+            raise EncodeError(
+                f"completion for process {op.process} at history index "
+                f"{idx} has no pending invocation")
+        inv = history[inv_idx]
+        if keyed:
+            fields = codec(inv.f, keyed_value(inv),
+                           None if t == FAIL else keyed_value(op), t)
+        else:
+            fields = codec(inv.f, inv.value,
+                           None if t == FAIL else op.value, t)
+        if t == FAIL or (t == INFO and fields[0] == F_READ):
+            continue
+        points[inv_idx] = fields
+        if t == OK:
+            points[idx] = inv_idx
+        if complete is not None:
+            complete[inv_idx] = idx
+    # Never completed: info, pending forever.
+    for inv_idx in pending.values():
+        inv = history[inv_idx]
+        fields = codec(inv.f, keyed_value(inv) if keyed else inv.value,
+                       None, INFO)
+        if fields[0] != F_READ:
+            points[inv_idx] = fields
     return points
 
 
-def event_sources(invocations: Sequence[Invocation]) -> list[Invocation]:
+def event_sources(history: Sequence[Op], model=None) -> list[Invocation]:
     """The invocation behind each encoded event row, in event order —
-    row i of encode_events(invocations).events describes event_sources[i].
-    Used by the witness reconstructor to map kernel/oracle event indices
-    back to concrete history operations."""
-    return [inv for _, _, inv in _timeline_points(invocations)]
-
-
-def encode_events(invocations: Sequence[Invocation], k_slots: int = 32
-                  ) -> EncodedHistory:
-    """Build the (kind, slot, f, a1, a2, rv) event stream with slot assignment.
-
-    Events are emitted in history order: each included invocation contributes
-    an EV_INVOKE at its invoke position and, when status == ok, an EV_RETURN at
-    its completion position. `fail` ops and `info` reads are excluded (see
-    module docstring).
-    """
-    t_enc = time.monotonic()
-    points = _timeline_points(invocations)
-
-    free = list(range(k_slots - 1, -1, -1))  # pop() yields lowest slot first
-    slot_of: dict[int, int] = {}             # invoke_index -> slot
-    rows: list[list[int]] = []
-    max_pending = 0
-    for hist_idx, is_return, inv in points:
-        if not is_return:
-            if not free:
-                raise SlotOverflow(
-                    f"more than {k_slots} simultaneously pending ops at "
-                    f"history index {hist_idx}; raise k_slots"
-                )
-            slot = free.pop()
-            slot_of[inv.invoke_index] = slot
-            rows.append([EV_INVOKE, slot, inv.f, inv.a1, inv.a2, inv.rv])
-            max_pending = max(max_pending, k_slots - len(free))
+    row i of encode_history(history, model).events describes
+    event_sources(history, model)[i]. Used by the witness reconstructor
+    to map kernel/oracle event indices back to concrete history
+    operations."""
+    complete: dict[int, int] = {}
+    points = _event_points(history, _codec_of(model), complete=complete)
+    out: list[Invocation] = []
+    made: dict[int, Invocation] = {}  # invoke index -> its invocation
+    for idx, p in enumerate(points):
+        if p is None:
+            continue
+        if type(p) is tuple:
+            comp_idx = complete.get(idx, -1)
+            made[idx] = Invocation(
+                *p, status=history[comp_idx].type if comp_idx >= 0 else INFO,
+                invoke_index=idx, complete_index=comp_idx,
+                process=history[idx].process)
+            out.append(made[idx])
         else:
-            slot = slot_of.pop(inv.invoke_index)
-            rows.append([EV_RETURN, slot, inv.f, inv.a1, inv.a2, inv.rv])
-            free.append(slot)
-
-    events = np.asarray(rows, dtype=np.int32).reshape(-1, EVENT_WIDTH)
-    n_ops = sum(1 for _, r, _i in points if not r)
-    max_value = int(events[:, 3:6].max()) if len(rows) else 0
-    # Telemetry (obs/): host-side encode cost and the event-tensor bytes
-    # that will cross the host->device boundary (SURVEY §5.1 — the
-    # harness's own hot loop needs a breakdown, not just the op history).
-    m = get_metrics()
-    dt_enc = time.monotonic() - t_enc
-    m.counter("encode.encode_s").add(dt_enc)
-    get_ledger().record_encode(dt_enc)
-    m.counter("encode.histories").add(1)
-    m.counter("encode.event_bytes").add(int(events.nbytes))
-    return EncodedHistory(events=events, n_events=len(rows), n_ops=n_ops,
-                          k_slots=k_slots, max_pending=max_pending,
-                          max_value=max_value)
+            out.append(made[p])
+    return out
 
 
 class IncrementalEncoder:
-    """Streaming counterpart of pair_history + encode_events (stream/).
+    """Streaming counterpart of encode_history (stream/).
 
     Consumes history entries ONE AT A TIME (in recorded order) and emits
     event rows exactly when they become STABLE — when no future history
@@ -343,9 +360,9 @@ class IncrementalEncoder:
     :meth:`finalize`, exactly like pair_history's end-of-run rule.
 
     The emitted rows are BIT-IDENTICAL to the corresponding prefix of
-    ``encode_events(pair_history(history, model))``: same point order
-    (invoke/return points sorted by (position, kind)), same slot
-    assignment — encode_events pops fresh slots in increasing order and
+    ``encode_history(history, model)``: same event order (record order,
+    one event per record at most), same slot
+    assignment — encode_history pops fresh slots in increasing order and
     reuses freed slots LIFO, which depends only on the event
     interleaving, never on the slot-table capacity, so the unbounded
     stack here reproduces any non-overflowing capacity's ids — and the
@@ -372,7 +389,7 @@ class IncrementalEncoder:
 
     @property
     def max_value(self) -> int:
-        # Exactly encode_events' bookkeeping: max over the emitted rows'
+        # Exactly encode_history's bookkeeping: max over the emitted rows'
         # (a1, a2, rv) fields, 0 when no rows were emitted.
         return 0 if self._row_max is None else self._row_max
 
@@ -456,7 +473,7 @@ class IncrementalEncoder:
                  comp_idx: int) -> None:
         invocation = _make_invocation(inv, comp, inv_idx, comp_idx,
                                       self.model)
-        # The _timeline_points exclusions, applied at resolution time.
+        # The _event_points exclusions, applied at resolution time.
         if invocation.status == FAIL:
             return
         if invocation.status == INFO and invocation.f == F_READ:
@@ -473,7 +490,7 @@ class IncrementalEncoder:
         while self._heap and self._heap[0][0] < wm:
             _pos, is_return, _t, inv = heapq.heappop(self._heap)
             if not is_return:
-                # encode_events' exact policy: its free list is a stack
+                # encode_history's exact policy: its free list is a stack
                 # seeded [k-1..0], so fresh slots come out in increasing
                 # order and FREED slots are reused most-recent-first.
                 if self._free:
@@ -502,24 +519,74 @@ class IncrementalEncoder:
 def encode_register_history(history: Sequence[Op], k_slots: int = 32
                             ) -> EncodedHistory:
     """History of register ops (read/write/cas) -> padded event tensor."""
-    return encode_events(pair_history(history), k_slots=k_slots)
+    return encode_history(history, None, k_slots=k_slots)
 
 
 def encode_history(history: Sequence[Op], model, k_slots: int = 32,
                    keyed: bool = False) -> EncodedHistory:
-    """History in `model`'s op language -> padded event tensor.
+    """History in `model`'s op language -> (kind, slot, f, a1, a2, rv)
+    event rows, with slot assignment; `model` None is the register
+    language.
+
+    _event_points gives the events in record order; slots come from a
+    stack seeded [k_slots-1..0], so fresh slots are taken in increasing
+    order and a freed slot is reused first; SlotOverflow past k_slots.
 
     Does NOT apply model.prepare_history — the checker seam translates once
     (checkers/linearizable.py) so witness reconstruction sees the same op
     language the encoder did. `keyed`: as in pair_history."""
-    return encode_events(pair_history(history, model, keyed),
-                         k_slots=k_slots)
+    t_enc = time.monotonic()
+    codec = _codec_of(model)
+    points = _event_points(history, codec, keyed)
+    free = list(range(k_slots - 1, -1, -1))  # pop() yields lowest slot first
+    slot_of: dict[int, int] = {}             # invoke index -> slot
+    flat: list[int] = []                     # the rows, one after another
+    n_ops = pending = max_pending = 0
+    for idx, p in enumerate(points):
+        if p is None:
+            continue
+        if type(p) is tuple:
+            if not free:
+                raise SlotOverflow(
+                    f"more than {k_slots} simultaneously pending ops at "
+                    f"history index {idx}; raise k_slots")
+            slot = free.pop()
+            slot_of[idx] = slot
+            flat += (EV_INVOKE, slot) + p
+            n_ops += 1
+            pending += 1
+            if pending > max_pending:
+                max_pending = pending
+        else:
+            slot = slot_of.pop(p)
+            flat += (EV_RETURN, slot) + points[p]
+            free.append(slot)
+            pending -= 1
+
+    events = np.fromiter(flat, np.int32, len(flat)).reshape(-1, EVENT_WIDTH)
+    n_events = events.shape[0]
+    max_value = int(events[:, 3:6].max()) if n_events else 0
+    # Telemetry (obs/): host-side encode cost, pairing included, and the
+    # event-tensor bytes that will cross the host->device boundary
+    # (SURVEY §5.1 — the harness's own hot loop needs a breakdown, not
+    # just the op history).
+    m = get_metrics()
+    dt_enc = time.monotonic() - t_enc
+    m.counter("encode.encode_s").add(dt_enc)
+    get_ledger().record_encode(dt_enc)
+    m.counter("encode.histories").add(1)
+    if codec is register_fields:
+        m.counter("encode.inline_codec_histories").add(1)
+    m.counter("encode.event_bytes").add(int(events.nbytes))
+    return EncodedHistory(events=events, n_events=n_events, n_ops=n_ops,
+                          k_slots=k_slots, max_pending=max_pending,
+                          max_value=max_value)
 
 
 def reslot_events(enc: EncodedHistory, k_slots: int) -> EncodedHistory:
     """Remap slot ids into a smaller slot table (k_slots >= max_pending).
 
-    Uses the same greedy lowest-free assignment as encode_events over the
+    Uses the same greedy lowest-free assignment as encode_history over the
     same event order, so the result is exactly what encoding with the
     smaller k_slots would have produced. Lets the dense lattice kernel
     (wgl3) shrink its 2^K mask axis to the history's REAL concurrency after

@@ -504,8 +504,10 @@ class CoalescingScheduler:
         for req in batch:
             req.done.set()
             if req.webhook and self._webhook_sink is not None:
-                self._webhook_sink(req)
+                # Counted before the sink starts its delivery thread, so
+                # the count never trails a delivery the callee has seen.
                 m.counter("serve.webhooks").add(1)
+                self._webhook_sink(req)
         if self._artifact_sink is not None:
             try:
                 self._artifact_sink(batch, batch_tracer)

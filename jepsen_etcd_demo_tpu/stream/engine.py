@@ -594,7 +594,7 @@ class StreamSession:
                         # encode.event_bytes). Only VALID verdicts
                         # settle (checkers/linearizable._stream_result);
                         # invalid keys re-run post-hoc, whose
-                        # encode_events counts the same history itself.
+                        # encode_history counts the same history itself.
                         metrics.counter("encode.event_bytes").add(
                             int(enc.events[: enc.n_events].nbytes))
                         metrics.counter("encode.histories").add(1)
