@@ -12,7 +12,7 @@ points a user calls, at the reference's sizes (BASELINE.json):
               --stale-read-prob 0.8 --no-nemesis: exit 1, valid false, a
               dead_step.
   2. corpus   1024 register histories x 150 ops, 10 processes each
-              (bench.build_corpus, one in eight mutated) through the
+              (corpus_encs, one in eight mutated) through the
               production router and the corpus scheduler: compiled
               pallas kernels, every verdict equal to the CPU WGL oracle.
      split    1024 histories x 170 ops: one launch would overflow SMEM,
@@ -47,6 +47,9 @@ import threading
 import time
 
 N_KEYS_OPS = 100          # ops per key (reference default)
+CORPUS = 1024             # histories in the corpus phases
+N_OPS = 150               # ops per corpus history (BASELINE.json configs[0])
+N_PROCS = 10              # concurrency: the reference's 10 threads per key
 CORPUS_MUTATE_EVERY = 8   # one history in eight is made non-linearizable
 SPLIT_OPS = 170           # 1024 x 170 ops encode to 192 steps: 2 launches
 LONG_OPS = 10_000         # BASELINE.json configs[3]
@@ -140,19 +143,18 @@ def phase_cli(store: str, time_limit: float = 30.0, rate: float = 100.0,
 
 
 def corpus_encs(n: int | None = None, n_ops: int | None = None):
-    """The bench corpus (same generator, same seed), one history in
-    CORPUS_MUTATE_EVERY mutated so the comparison covers both verdicts."""
-    import bench
+    """The seeded corpus, one history in CORPUS_MUTATE_EVERY mutated so
+    the comparison covers both verdicts."""
     from jepsen_etcd_demo_tpu.ops.encode import encode_register_history
     from jepsen_etcd_demo_tpu.utils.fuzz import (gen_register_history,
                                                  mutate_history)
 
-    n = bench.CORPUS if n is None else n
+    n = CORPUS if n is None else n
     rng, mrng = random.Random(0xBE7C), random.Random(0x5A0E)
     encs, hists = [], []
     for i in range(n):
-        h = gen_register_history(rng, n_ops=n_ops or bench.N_OPS,
-                                 n_procs=bench.N_PROCS, p_info=0.002)
+        h = gen_register_history(rng, n_ops=n_ops or N_OPS,
+                                 n_procs=N_PROCS, p_info=0.002)
         if i % CORPUS_MUTATE_EVERY == CORPUS_MUTATE_EVERY - 1:
             h = mutate_history(mrng, h)
         hists.append(h)
@@ -227,15 +229,13 @@ def phase_split(encs, model) -> dict:
 
 
 def long_encs(n_ops: int = LONG_OPS):
-    """One long single-register history (bench_long's generator) and a
-    mutated copy."""
-    import bench
+    """One long single-register history and a mutated copy."""
     from jepsen_etcd_demo_tpu.ops.encode import encode_register_history
     from jepsen_etcd_demo_tpu.utils.fuzz import (gen_register_history,
                                                  mutate_history)
 
     h = gen_register_history(random.Random(0x10C0 + n_ops), n_ops=n_ops,
-                             n_procs=bench.N_PROCS, p_info=0.0005)
+                             n_procs=N_PROCS, p_info=0.0005)
     bad = mutate_history(random.Random(0xBAD), h)
     return [encode_register_history(x, k_slots=64) for x in (h, bad)]
 

@@ -891,7 +891,7 @@ def cmd_warmup(args) -> int:
     """`jepsen-tpu warmup`: replay the plan-family corpus through the
     persistent XLA cache (sched/warmup.py) so the fleet's first real
     launches are disk-cache hits. Prints one WARMUP JSON line — the
-    ledger-armed warmup record (check_ledger_record-clean)."""
+    warmup record."""
     from ..sched import enable_persistent_cache, warmup_plans
 
     enable_persistent_cache()

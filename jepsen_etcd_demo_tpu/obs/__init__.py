@@ -19,9 +19,9 @@ Usage pattern: layers call `get_tracer()` / `get_metrics()` at the
 point of instrumentation; both return no-op singletons unless a
 `capture()` is active, so library use (imports, ad-hoc checker calls)
 records nothing and pays one list-index per call. The runner
-(runner/core.py run_test) and the bench (bench.py) open captures; the
-runner's capture writes `telemetry.jsonl` + `metrics.json` into the run
-dir next to history.jsonl/results.json.
+(runner/core.py run_test) and the benchmark (benchmark/run.py) open
+captures; the runner's capture writes `telemetry.jsonl` +
+`metrics.json` into the run dir next to history.jsonl/results.json.
 
 Env vars:
   JEPSEN_TPU_TELEMETRY=0   disable capture entirely (spans/metrics
@@ -78,9 +78,7 @@ from .metrics import MetricsRegistry, read_metrics
 from .trace import Tracer, read_jsonl
 from . import export                               # noqa: E402
 from . import health                               # noqa: E402
-from . import ledger                               # noqa: E402
 from .export import render_prometheus, subscribe   # noqa: F401
-from .ledger import Ledger                         # noqa: F401
 
 TELEMETRY_FILE = "telemetry.jsonl"
 METRICS_FILE = "metrics.json"
@@ -101,6 +99,7 @@ PHASE_GAUGE = "wgl.frontier_peak"
 # zeros, never absences, even for runs that never launch a batch.
 # jtflow: metrics preregistered
 SCHED_COUNTERS = ("sched.steps_real", "sched.steps_padded",
+                  "sched.launches",
                   "sched.cache_hits", "sched.cache_misses",
                   "encode.cache_hits", "encode.cache_misses")
 # Sparse active-tile sweep engine (ops/wgl3_sparse.py) accounting:
@@ -244,27 +243,9 @@ CAMPAIGN_COUNTERS = ("campaign.specs", "campaign.aborted_runs",
 # jtflow: metrics preregistered
 CAMPAIGN_GAUGES = ("campaign.unique_signatures", "campaign.shrink_ratio",
                    "campaign.specs_per_sec")
-# Scaling ledger (obs/ledger.py, ISSUE 16): launch-level time
-# attribution folded live into the capture's registry — launches,
-# per-bucket seconds (encode / H2D / compile / useful execute / bucket
-# padding / straggler wait / host dispatch gap) and H2D bytes — behind
-# obs.ledger_stats(), the bench record's `ledger` object and the
-# /metrics jepsen_tpu_ledger_* families. Pre-registered so the
-# artifacts carry zeros, never absences, even for runs that never
-# launch (the degraded bench paths included).
-# jtflow: metrics preregistered
-LEDGER_COUNTERS = ("ledger.launches", "ledger.encode_s", "ledger.h2d_s",
-                   "ledger.h2d_bytes", "ledger.compile_s",
-                   "ledger.execute_s", "ledger.padding_s",
-                   "ledger.straggler_s", "ledger.dispatch_gap_s",
-                   "ledger.spill_read_s", "ledger.spill_write_s")
-# Last-launch occupancy: real/padded step fill and real/padded batch
-# fill of the most recent decomposed launch.
-# jtflow: metrics preregistered
-LEDGER_GAUGES = ("ledger.step_fill", "ledger.batch_fill")
-# Serve SLO gauges (obs/ledger.py RollingWindow): rolling-window
+# Serve SLO gauges (serve/scheduler.py RollingWindow): rolling-window
 # p50/p99 request latency and the burn rate (breach fraction over the
-# error budget) — the /live SLO cells and ledger_stats' slo_* fields.
+# error budget) — the /live SLO cells.
 # jtflow: metrics preregistered
 SLO_GAUGES = ("serve.slo_p50_s", "serve.slo_p99_s",
               "serve.slo_burn_rate")
@@ -299,7 +280,6 @@ SPILL_GAUGES = ("spill.compress_ratio", "spill.peak_rss_mb")
 
 _NULL_TRACER = Tracer(enabled=False)
 _NULL_METRICS = MetricsRegistry(enabled=False)
-_NULL_LEDGER = Ledger(enabled=False)
 
 
 class Capture:
@@ -307,17 +287,11 @@ class Capture:
     bound to an output directory the artifacts land in on exit."""
 
     def __init__(self, out_dir: Optional[str | Path] = None,
-                 enabled: bool = True, with_ledger: bool = True):
+                 enabled: bool = True):
         self.enabled = enabled
         self.out_dir = Path(out_dir) if out_dir is not None else None
         self.tracer = Tracer(enabled=enabled)
         self.metrics = MetricsRegistry(enabled=enabled)
-        # The scaling ledger (obs/ledger.py): in-memory always; file-
-        # backed (ledger-<proc>.jsonl next to telemetry.jsonl, via a
-        # writer thread joined on write()) when the capture has a run
-        # dir. `with_ledger=False` is the bench's overhead-control arm.
-        self.ledger = Ledger(out_dir=self.out_dir, metrics=self.metrics,
-                             enabled=enabled and with_ledger)
         # Seconds of each collection the gc hook saw, not yet added to
         # gc.collect_s: the hook may not take the registry lock (a
         # collection can start inside any allocation, including one
@@ -326,14 +300,13 @@ class Capture:
         if enabled:
             for name in PHASE_COUNTERS + SCHED_COUNTERS + SWEEP_COUNTERS \
                     + TRACE_COUNTERS + ELLE_COUNTERS + SERVE_COUNTERS \
-                    + SYNC_COUNTERS + CAMPAIGN_COUNTERS \
-                    + LEDGER_COUNTERS + FLEET_COUNTERS \
+                    + SYNC_COUNTERS + CAMPAIGN_COUNTERS + FLEET_COUNTERS \
                     + SPILL_COUNTERS + PHASE_SECONDS + COMPILE_COUNTERS \
                     + SPLIT_COUNTERS + SHARD_COUNTERS:
                 self.metrics.counter(name)
             self.metrics.counter(GC_COUNTER)
             for name in ELLE_GAUGES + SERVE_GAUGES + CAMPAIGN_GAUGES \
-                    + LEDGER_GAUGES + SLO_GAUGES + FLEET_GAUGES \
+                    + SLO_GAUGES + FLEET_GAUGES \
                     + SPILL_GAUGES:
                 self.metrics.gauge(name)
             self.metrics.histogram(SERVE_HISTOGRAM)
@@ -364,9 +337,6 @@ class Capture:
             self.metrics.counter(GC_COUNTER).add(s)
 
     def write(self) -> None:
-        # Join the ledger writer thread first (idempotent) so
-        # ledger-<proc>.jsonl is complete before anyone merges it.
-        self.ledger.close()
         if not self.enabled or self.out_dir is None:
             return
         try:
@@ -402,13 +372,6 @@ def get_metrics() -> MetricsRegistry:
     return stack[-1].metrics if stack else _NULL_METRICS
 
 
-# jtsan: returns=Ledger
-def get_ledger() -> Ledger:
-    """The active capture's scaling ledger, or a no-op singleton."""
-    stack = _stack
-    return stack[-1].ledger if stack else _NULL_LEDGER
-
-
 def capture_active() -> bool:
     """True while some capture is installed (a run is in flight) — the
     /healthz `run_in_flight` field."""
@@ -416,16 +379,12 @@ def capture_active() -> bool:
 
 
 @contextmanager
-def capture(out_dir: Optional[str | Path] = None, *,
-            with_ledger: bool = True) -> Iterator[Capture]:
+def capture(out_dir: Optional[str | Path] = None) -> Iterator[Capture]:
     """Install a fresh tracer+registry as the active telemetry sinks;
     on exit, restore the previous ones and (when `out_dir` is given)
-    write telemetry.jsonl + metrics.json + ledger-<proc>.jsonl there.
-    Nesting shadows: the innermost capture receives the records (one
-    capture per run). `with_ledger=False` disables only the scaling
-    ledger — the bench's ledger-overhead control arm."""
-    cap = Capture(out_dir, enabled=telemetry_enabled(),
-                  with_ledger=with_ledger)
+    write telemetry.jsonl + metrics.json there. Nesting shadows: the
+    innermost capture receives the records (one capture per run)."""
+    cap = Capture(out_dir, enabled=telemetry_enabled())
     if not cap.enabled:
         yield cap
         return
@@ -591,10 +550,9 @@ def instrument_kernel(name: str, fn: Callable) -> Callable:
         m = get_metrics()
         if first and m.enabled:
             _lower_ahead(fn, args, kwargs)
-        t0_ns = time.monotonic_ns()
+        t0 = time.monotonic()
         out = fn(*args, **kwargs)
-        t1_ns = time.monotonic_ns()
-        dt = (t1_ns - t0_ns) / 1e9
+        dt = time.monotonic() - t0
         if first:
             state["first"] = False
             m.counter("wgl.compile_s").add(dt)
@@ -610,12 +568,6 @@ def instrument_kernel(name: str, fn: Callable) -> Callable:
             # jtlint: disable=JTL107 -- bounded family: kernel names are
             # the fixed static set of instrument_kernel call sites.
             m.histogram(f"wgl.execute_s.{name}").observe(dt)
-        # Scaling ledger (obs/ledger.py): the launch record, enriched
-        # by whatever launch_context the call site opened (plan
-        # identity, bucket shape, padding, shard layout).
-        get_ledger().record_launch(name,
-                                   "compile" if first else "execute",
-                                   t0_ns, t1_ns)
         return out
 
     wrapped.__name__ = f"instrumented_{name}"
@@ -931,51 +883,6 @@ def longhaul_stats(metrics: Optional[MetricsRegistry] = None) -> dict:
             out[key] = int(rec["value"])
     for key, name in (("compress_ratio", "spill.compress_ratio"),
                       ("peak_rss_mb", "spill.peak_rss_mb")):
-        g = snap.get(name)
-        if g and g.get("last") is not None:
-            out[key] = round(float(g["last"]), 6)
-    return out
-
-
-def ledger_stats(metrics: Optional[MetricsRegistry] = None) -> dict:
-    """The scaling ledger's bench/web contract fields (obs/ledger.py,
-    ISSUE 16), from a registry snapshot: launch count, the per-bucket
-    second totals (useful execute vs padding/straggler waste, encode,
-    H2D, compile, host dispatch gap), H2D bytes, the last launch's
-    fill gauges, and the serve daemon's rolling-window SLO gauges.
-    Zeros when no registry / no launches — like every reader here, the
-    contract is "zeros permitted, never absent"."""
-    out = {"launches": 0, "encode_s": 0.0, "h2d_s": 0.0, "h2d_bytes": 0,
-           "compile_s": 0.0, "execute_s": 0.0, "padding_s": 0.0,
-           "straggler_s": 0.0, "dispatch_gap_s": 0.0,
-           "spill_read_s": 0.0, "spill_write_s": 0.0,
-           "step_fill": 0.0, "batch_fill": 0.0,
-           "slo_p50_s": 0.0, "slo_p99_s": 0.0, "slo_burn_rate": 0.0}
-    if metrics is None or not metrics.enabled:
-        return out
-    snap = metrics.snapshot()
-
-    def counter_value(key: str) -> float:
-        rec = snap.get(key)
-        return round(rec["value"], 6) if rec \
-            and rec.get("type") == "counter" else 0.0
-
-    out["launches"] = int(counter_value("ledger.launches"))
-    out["encode_s"] = counter_value("ledger.encode_s")
-    out["h2d_s"] = counter_value("ledger.h2d_s")
-    out["h2d_bytes"] = int(counter_value("ledger.h2d_bytes"))
-    out["compile_s"] = counter_value("ledger.compile_s")
-    out["execute_s"] = counter_value("ledger.execute_s")
-    out["padding_s"] = counter_value("ledger.padding_s")
-    out["straggler_s"] = counter_value("ledger.straggler_s")
-    out["dispatch_gap_s"] = counter_value("ledger.dispatch_gap_s")
-    out["spill_read_s"] = counter_value("ledger.spill_read_s")
-    out["spill_write_s"] = counter_value("ledger.spill_write_s")
-    for key, name in (("step_fill", "ledger.step_fill"),
-                      ("batch_fill", "ledger.batch_fill"),
-                      ("slo_p50_s", "serve.slo_p50_s"),
-                      ("slo_p99_s", "serve.slo_p99_s"),
-                      ("slo_burn_rate", "serve.slo_burn_rate")):
         g = snap.get(name)
         if g and g.get("last") is not None:
             out[key] = round(float(g["last"]), 6)

@@ -55,10 +55,6 @@ LABELED_FAMILIES = {
     "wgl.execute_s": "kernel",
     "tune.probe_s": "knob",
     "tune.chosen": "knob",
-    # Scaling-ledger per-bucket cumulative seconds (obs/ledger.py
-    # BUCKETS — a closed 8-member set): `ledger.bucket_s.padding_s` ->
-    # `jepsen_tpu_ledger_bucket_s_by_bucket{bucket="padding_s"}`.
-    "ledger.bucket_s": "bucket",
 }
 
 _NAME_SUB = re.compile(r"[^a-zA-Z0-9_:]")

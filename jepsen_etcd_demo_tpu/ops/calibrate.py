@@ -29,8 +29,7 @@ assumed:
 The router consumes this via `limits().oracle_crossover_events == -1`
 (auto, the default); a fixed positive value or the
 `JEPSEN_TPU_LIMIT_ORACLE_CROSSOVER_EVENTS` env override bypasses
-measurement entirely, and 0 disables oracle routing (bench.py pins 0 for
-its kernel lanes). Persistence is keyed by the JAX backend + device kind,
+measurement entirely, and 0 disables oracle routing. Persistence is keyed by the JAX backend + device kind,
 so one cache file serves a laptop CPU run and a TPU pod worker without
 cross-talk.
 
@@ -116,8 +115,7 @@ def measure_dispatch_floor(repeats: int = 5) -> float:
     """Round trip of an already-compiled trivial launch: dispatch one
     jitted add on a [8,128] i32 tile and fetch one word back. np.asarray
     (not block_until_ready) forces the fetch, so the round trip
-    includes the result reaching the host (bench.py measures the same
-    way)."""
+    includes the result reaching the host."""
     import jax
     import jax.numpy as jnp
     import numpy as np

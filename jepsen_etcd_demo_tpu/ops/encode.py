@@ -42,7 +42,7 @@ from typing import Any, Optional, Sequence
 
 import numpy as np
 
-from ..obs import get_ledger, get_metrics
+from ..obs import get_metrics
 from .op import Op, INVOKE, OK, FAIL, INFO, keyed_value
 
 # Value encoding. The reference register draws values from (rand-int 5), i.e.
@@ -571,9 +571,7 @@ def encode_history(history: Sequence[Op], model, k_slots: int = 32,
     # (SURVEY §5.1 — the harness's own hot loop needs a breakdown, not
     # just the op history).
     m = get_metrics()
-    dt_enc = time.monotonic() - t_enc
-    m.counter("encode.encode_s").add(dt_enc)
-    get_ledger().record_encode(dt_enc)
+    m.counter("encode.encode_s").add(time.monotonic() - t_enc)
     m.counter("encode.histories").add(1)
     if codec is register_fields:
         m.counter("encode.inline_codec_histories").add(1)
@@ -705,9 +703,7 @@ def _encode_return_steps_host(enc: EncodedHistory) -> ReturnSteps:
     last = last_inv[ret_pos]                   # [R, K]
     tabs = np.where(last[:, :, None] >= 0,
                     ev[np.maximum(last, 0)][:, :, 2:6], 0).astype(np.int32)
-    dt_enc = time.monotonic() - t_enc
-    get_metrics().counter("encode.encode_s").add(dt_enc)
-    get_ledger().record_encode(dt_enc)
+    get_metrics().counter("encode.encode_s").add(time.monotonic() - t_enc)
     return ReturnSteps(
         slot_tabs=tabs,
         slot_active=active,

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs import get_ledger, get_metrics, instrument_kernel
+from ..obs import get_metrics, instrument_kernel
 from .encode import (EV_INVOKE, EV_RETURN, EVENT_WIDTH, EncodedHistory,
                      ReturnSteps)
 
@@ -118,8 +118,7 @@ def _encode_fn(k_slots: int, e_cap: int, r_cap: int):
 def cached_device_encoder(k_slots: int, e_cap: int, r_cap: int):
     """Jitted single-history device encoder for one (K, E, R) geometry,
     instrumented for compile/execute attribution like every production
-    kernel (the encoder must not be a telemetry blind spot — its whole
-    point is moving seconds between ledger buckets)."""
+    kernel (the encoder must not be a telemetry blind spot)."""
     key = ("encode", k_slots, e_cap, r_cap)
     if key not in _CACHE:
         _CACHE[key] = instrument_kernel(
@@ -135,10 +134,7 @@ def stack_events(encs: Sequence[EncodedHistory], e_cap: int):
     ev = np.stack([e.padded_to(e_cap).events for e in encs])
     nbytes = int(ev.nbytes)
     get_metrics().counter("wgl.h2d_bytes").add(nbytes)
-    t0_ns = time.monotonic_ns()
-    out = jnp.asarray(ev)
-    get_ledger().record_h2d(nbytes, t0_ns, time.monotonic_ns())
-    return out
+    return jnp.asarray(ev)
 
 
 def encode_return_steps_device(enc: EncodedHistory,
@@ -168,9 +164,7 @@ def encode_return_steps_device(enc: EncodedHistory,
     fn = cached_device_encoder(k, e_cap, r_cap)
     ev_dev = stack_events([enc], e_cap)[0]
     tabs, act, tgt = (np.asarray(x) for x in fn(ev_dev))
-    dt_enc = time.monotonic() - t_enc
-    get_metrics().counter("encode.encode_s").add(dt_enc)
-    get_ledger().record_encode(dt_enc)
+    get_metrics().counter("encode.encode_s").add(time.monotonic() - t_enc)
     return ReturnSteps(
         slot_tabs=tabs[:n_ret], slot_active=act[:n_ret],
         targets=tgt[:n_ret].astype(np.int32),

@@ -114,8 +114,8 @@ class KernelLimits:
     # device launch: the dispatch+fetch round trip exceeds the oracle's
     # whole runtime at tutorial scale. -1 (default) = MEASURED per
     # platform at first use (ops/calibrate.py: dispatch floor x oracle
-    # events/s, persisted in the tuned profile); 0 = never route
-    # (bench.py pins 0 for its kernel lanes); >0 = fixed crossover.
+    # events/s, persisted in the tuned profile); 0 = never route;
+    # >0 = fixed crossover.
     # Batches are never routed regardless.
     oracle_crossover_events: int = _f(-1, "tunable", -1, 1 << 16)
     # [arch] Concurrency ceiling for the oracle route: the frontier can
@@ -181,7 +181,7 @@ class KernelLimits:
     # dense.py): 1 = split sharded launches into per-step-length buckets
     # and LPT-pack histories into contiguous per-shard blocks balanced
     # by REAL step count, so one ragged straggler no longer pads the
-    # whole mesh (the MULTICHIP_r06 smoking gun); 0 = legacy one-bucket
+    # whole mesh; 0 = legacy one-bucket
     # corpus padding. Verdicts are bit-identical either way — packing
     # permutes launch order only, never the per-history scan.
     shard_bucket_mode: int = _f(1, "tunable", 0, 1, group="pod")
@@ -237,8 +237,8 @@ class KernelLimits:
     # is fixed in the table size, so the pass is pure overhead there
     # unless measured otherwise.
     # 1 = off (every kernel byte-identical to the pre-dedup build).
-    # 2 = force — the table sweeps canonicalize too (the bench/test
-    # lane, or a tuned profile on a machine where the `dedup` probe
+    # 2 = force — the table sweeps canonicalize too (the test lane, or
+    # a tuned profile on a machine where the `dedup` probe
     # measured it faster). Exact in every mode: canonicalization is a
     # verdict-preserving quotient (doc/perf.md "Frontier dedup"), so
     # the tuner may search it freely.
@@ -260,9 +260,9 @@ class KernelLimits:
     # (ops/cycles.py): 0 = auto (dense squaring below
     # elle_dense_max_nodes; component decomposition + bucketed batch +
     # tiled work-list kernel above), 1 = dense-only (the seed [N, N]
-    # matrix-squaring path regardless of size — the bench's baseline
-    # arm), 2 = prefer-tiled (the blocked work-list kernel even for
-    # small graphs — the bench/test lane for exercising the tiled path
+    # matrix-squaring path regardless of size — the baseline arm),
+    # 2 = prefer-tiled (the blocked work-list kernel even for small
+    # graphs — the test lane for exercising the tiled path
     # deterministically). Exact in every mode: the closure fixpoint is
     # unique, so anomaly verdicts never depend on the route.
     elle_mode: int = _f(0, "arch", 0, 2)
@@ -306,7 +306,7 @@ class KernelLimits:
     # verdicts, no device allocation). 2^28 cells = 16384^2 = 1 GiB
     # f32. The floor sits BELOW the smallest padded graph (128^2 =
     # 2^14) so the oracle route can be force-pinned for certification
-    # (the bench elle lane's "tarjan" arm).
+    # (the elle route tests' "tarjan" arm).
     elle_cell_budget: int = _f(1 << 28, "envelope", 1 << 12, 1 << 34,
                                conservative="down")
     # [tunable] Completed txns per incremental dependency-graph
@@ -363,7 +363,7 @@ class KernelLimits:
     # the rendezvous preference order past unhealthy/failed replicas —
     # the default), 1 = strict affinity (no spillover; 503 when the
     # owning replica cannot take the request), 2 = random routing
-    # (shape affinity off — the bench's comparison arm and the
+    # (shape affinity off — the fleet tests' comparison arm and the
     # locality-off escape hatch).
     fleet_spillover_mode: int = _f(0, "arch", 0, 2)
     # [arch] Salt mixed into the router's rendezvous hash
@@ -378,13 +378,13 @@ class KernelLimits:
     # checkpoints to disk only when the estimated working set exceeds
     # host_rss_budget_mb), 1 = off (everything stays in RAM — the seed
     # behaviour), 2 = force (every checkpoint/chunk goes through the
-    # spill tier — the bench/test lane). Verdicts are bit-identical in
+    # spill tier — the test lane). Verdicts are bit-identical in
     # every mode: the spill tier moves bytes, never meaning.
     host_spill_mode: int = _f(0, "tunable", 0, 2, group="spill")
     # [tunable] Host-RAM working-set budget (MiB) of the out-of-core
     # tier: the bounded in-RAM window of spilled encoded chunks and
     # frontier checkpoints evicts to disk past this budget, and the
-    # long-haul bench lane pins its RSS-growth ceiling to it.
+    # long-haul lane (stream/longhaul.py) holds its RSS growth to it.
     host_rss_budget_mb: int = _f(4096, "tunable", 64, 1 << 20,
                                  group="spill")
     # [tunable] Spilled frontier-checkpoint compression (store/spill.py
@@ -520,7 +520,7 @@ def limits_provenance() -> dict[str, str]:
     JEPSEN_TPU_LIMIT_* override), "set" (set_limits() installed a value
     differing from the default), "tuned" (the persisted tuned profile),
     or "default" (the dataclass default). Surfaced by
-    tools/print_profile.py, the bench records, and run telemetry."""
+    tools/print_profile.py and run telemetry."""
     lim = limits()
     out = {}
     for f in fields(KernelLimits):

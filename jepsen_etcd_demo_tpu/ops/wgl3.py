@@ -42,7 +42,6 @@ drops into the same scan/vmap/shard harness.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Any, NamedTuple, Sequence
 
@@ -51,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.base import Model
-from ..obs import (get_ledger, get_metrics, instrument_kernel, phase,
+from ..obs import (get_metrics, instrument_kernel, phase,
                    record_check_result)
 from .encode import EncodedHistory, ReturnSteps, encode_return_steps
 from .limits import limits
@@ -1009,12 +1008,7 @@ def stack_steps3(steps, r_cap: int):
     _record_padding(steps, r_cap)
     nbytes = int(tabs.nbytes + act.nbytes + tgt.nbytes)
     get_metrics().counter("wgl.h2d_bytes").add(nbytes)
-    # Scaling ledger: the host->device staging enqueue wall + bytes (a
-    # lower bound on transfer time — async backends overlap the copy).
-    t0_ns = time.monotonic_ns()
-    out = jnp.asarray(tabs), jnp.asarray(act), jnp.asarray(tgt)
-    get_ledger().record_h2d(nbytes, t0_ns, time.monotonic_ns())
-    return out
+    return jnp.asarray(tabs), jnp.asarray(act), jnp.asarray(tgt)
 
 
 def batch_arrays3(encs: Sequence[EncodedHistory], model: Model,

@@ -59,7 +59,7 @@ ms to 45 ms of device time:
     a flat K-way switch crashes the compiler at K >= 12).
   * Calibration: an independent 8-chain int32 ALU loop sustains ~4.0 G
     vreg-ops/s (~4.1 T word-ops/s) on a v5e core, against the 6.1 T
-    spec-sheet figure bench.py's roofline also reports; one serial
+    spec-sheet figure (benchmark/peaks.json); one serial
     dependent chain sustains ~0.7 G vreg-ops/s, so ILP shape (not op
     count) dominates kernel cost here.
   * Chip limits (v5e compiles, jax 0.9): SMEM is 1 MiB per core and a
@@ -1828,8 +1828,7 @@ def check_batch_encoded_auto(encs: Sequence[EncodedHistory],
 
 def _oracle_crossover() -> int:
     """Active oracle-route crossover: a non-negative limits() value is
-    authoritative (0 = route off — bench.py pins this for kernel lanes;
-    >0 = fixed); -1 (the default) defers to the per-platform measurement
+    authoritative (0 = route off; >0 = fixed); -1 (the default) defers to the per-platform measurement
     (ops/calibrate.py — dispatch floor x oracle throughput, persisted)."""
     fixed = limits().oracle_crossover_events
     if fixed >= 0:

@@ -23,7 +23,6 @@ are stripped back.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, Sequence
 
 import jax
@@ -34,7 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from ..models.base import Model
-from .. import obs
 from ..obs import instrument_kernel
 from ..ops import wgl3
 from ..ops.limits import limits
@@ -110,7 +108,7 @@ def sharded_device_encoder(k_slots: int, e_cap: int, r_cap: int,
     shardings match the sharded checker's input shardings exactly, so
     the encoded tables NEVER visit the host: the compact event tensor
     crosses once and each device expands its own shard in place —
-    killing the packed-table H2D that dominated the r06 pod waterfall.
+    killing the packed-table H2D of the host-encoded route.
     B must be a multiple of the total device count."""
     from ..ops import encode_device
 
@@ -320,7 +318,6 @@ def _check_steps_one_launch(model: Model, cfg: DenseConfig, steps,
     {2^k, 1.5*2^k} bucket (then the sharding multiple), launch ONCE at
     the corpus-wide r_cap, strip pads. Pad histories are all-pad scans
     (targets=-1, zero work)."""
-    from ..obs import ledger as obs_ledger
     from ..plan import plan_dense_batch, resolve
 
     mult = batch_multiple(model, cfg, mesh, n_steps=r_cap,
@@ -333,24 +330,7 @@ def _check_steps_one_launch(model: Model, cfg: DenseConfig, steps,
     p = plan_dense_batch(model, cfg, n_steps=r_cap, batch=b_pad,
                          mesh=mesh)
     check = resolve(p)
-    # Scaling ledger launch context: the bucket economics of this one
-    # sharded launch — per-shard real steps make straggler wait (the
-    # mesh idling behind its slowest shard on a ragged corpus)
-    # attributable, not folklore.
-    step_counts = [s.n_steps for s in steps] + [0] * (b_pad - b)
-    lctx = obs_ledger.plan_context(p)
-    lctx.update(batch_real=b, batch_padded=b_pad,
-                steps_real=sum(step_counts),
-                steps_padded=b_pad * r_cap)
-    if lctx.get("n_shards", 1) > 1:
-        lctx["shard_real"] = obs_ledger.shard_real_steps(
-            step_counts, lctx["n_shards"])
-    with obs_ledger.launch_context(**lctx):
-        dev = check(*(jnp.asarray(a) for a in arrays))
-        t0f = time.monotonic_ns()
-        fetched = np.asarray(dev)
-        obs.get_ledger().record_fetch(t0f, time.monotonic_ns(),
-                                      ctx=lctx)
+    fetched = np.asarray(check(*(jnp.asarray(a) for a in arrays)))
     out = wgl3.unpack_np(fetched[:b])
     return wgl3.assemble_batch_results(out, steps, cfg), p.label
 
@@ -419,7 +399,6 @@ def _check_steps_bucketed(model: Model, cfg: DenseConfig, steps,
     """The shard-aware discipline: per-length step buckets, LPT shard
     packing inside each launch, pipelined launches, optional device-side
     encoding. See check_steps_sharded."""
-    from ..obs import ledger as obs_ledger
     from ..ops import encode_device
     from ..ops.encode import reslot_events
     from ..plan import LaunchPipeline, plan_dense_batch, resolve
@@ -441,18 +420,12 @@ def _check_steps_bucketed(model: Model, cfg: DenseConfig, steps,
     results: list = [None] * len(steps)
 
     def _fetch_launch(entry):
-        part, part_steps, dev, lctx, perm = entry
-        t0f = time.monotonic_ns()
+        part, part_steps, dev, perm = entry
         fetched = np.asarray(dev)
-        obs.get_ledger().record_fetch(t0f, time.monotonic_ns(),
-                                      ctx=lctx)
         if perm is None:
             rows = fetched[:len(part)]
         else:
-            inv = [0] * len(perm)
-            for j, p in enumerate(perm):
-                inv[p] = j
-            rows = fetched[[inv[p] for p in range(len(part))]]
+            rows = fetched[np.argsort(perm)[:len(part)]]
         out = wgl3.unpack_np(rows)
         for i, one in zip(part, wgl3.assemble_batch_results(
                 out, part_steps, cfg)):
@@ -500,22 +473,14 @@ def _check_steps_bucketed(model: Model, cfg: DenseConfig, steps,
         p = plan_dense_batch(model, cfg, n_steps=r, batch=b_pad,
                              mesh=mesh)
         check = resolve(p)
-        lctx = obs_ledger.plan_context(p)
-        lctx.update(batch_real=len(part), batch_padded=b_pad,
-                    steps_real=sum(s.n_steps for s in part_steps),
-                    steps_padded=b_pad * r)
         perm = None
-        n_shards = lctx.get("n_shards", 1)
-        if n_shards > 1:
+        if p.n_shards > 1:
             perm = lpt_shard_order([s.n_steps for s in padded],
-                                   n_shards)
+                                   p.n_shards)
             if perm == list(range(len(padded))):
                 perm = None
             else:
                 padded = [padded[j] for j in perm]
-                lctx["shard_packed"] = True
-            lctx["shard_real"] = obs_ledger.shard_real_steps(
-                [s.n_steps for s in padded], n_shards)
         # Device-encode geometry check is per bucket: the one-hot
         # expansion must fit the launch element budget at this bucket's
         # event capacity.
@@ -525,21 +490,17 @@ def _check_steps_bucketed(model: Model, cfg: DenseConfig, steps,
                 max((encs[i].n_events for i in part), default=1))
             if e_cap * max(1, cfg.k_slots) > lim.stack_element_budget:
                 e_cap = 0
-        with obs_ledger.launch_context(**lctx):
-            if e_cap:
-                bucket_encs = ([encs[i] for i in part]
-                               + [_pad_enc(cfg.k_slots)]
-                               * (b_pad - len(part)))
-                if perm is not None:
-                    bucket_encs = [bucket_encs[j] for j in perm]
-                ev = encode_device.stack_events(bucket_encs, e_cap)
-                enc_fn = sharded_device_encoder(cfg.k_slots, e_cap, r,
-                                                mesh)
-                dev = check(*enc_fn(ev))
-            else:
-                arrays = wgl3.stack_steps3(padded, r)
-                dev = check(*arrays)
-        pipe.submit((part, part_steps, dev, lctx, perm))
+        if e_cap:
+            bucket_encs = ([encs[i] for i in part]
+                           + [_pad_enc(cfg.k_slots)] * (b_pad - len(part)))
+            if perm is not None:
+                bucket_encs = [bucket_encs[j] for j in perm]
+            ev = encode_device.stack_events(bucket_encs, e_cap)
+            enc_fn = sharded_device_encoder(cfg.k_slots, e_cap, r, mesh)
+            dev = check(*enc_fn(ev))
+        else:
+            dev = check(*wgl3.stack_steps3(padded, r))
+        pipe.submit((part, part_steps, dev, perm))
         label = p.label
     pipe.drain()
     return results, label

@@ -194,6 +194,12 @@ class KernelPlan:
                 self.mesh.key() if self.mesh is not None else None,
                 self.sparse, self.dedup, self.extra)
 
+    @property
+    def n_shards(self) -> int:
+        """How many contiguous blocks the launch splits its [B] axis
+        into: the mesh's device count, 1 without a mesh."""
+        return self.mesh.total if self.mesh is not None else 1
+
     def dispatch(self, *args, **kwargs):
         """Resolve this plan's backend kernel and launch it — THE entry
         every rerouted caller funnels through (plan.dispatch module)."""

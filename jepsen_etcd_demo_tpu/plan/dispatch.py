@@ -65,15 +65,8 @@ def resolve(plan: KernelPlan):
 
 
 def dispatch(plan: KernelPlan, *args, **kwargs):
-    """Resolve + launch: the single choke point (KernelPlan.dispatch).
-    Launches carry the plan's identity into the scaling ledger
-    (obs/ledger.py) — callers that know the padding economics open a
-    richer launch_context themselves; the merge keeps their fields."""
-    from ..obs import ledger as obs_ledger
-
-    fn = resolve(plan)
-    with obs_ledger.launch_context(**obs_ledger.plan_context(plan)):
-        return fn(*args, **kwargs)
+    """Resolve + launch: the single choke point (KernelPlan.dispatch)."""
+    return resolve(plan)(*args, **kwargs)
 
 
 def _extra(plan: KernelPlan) -> dict:

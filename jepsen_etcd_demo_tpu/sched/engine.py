@@ -29,7 +29,6 @@ and batch pads are all-pad histories stripped before assembly
 from __future__ import annotations
 
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Sequence
 
@@ -58,20 +57,20 @@ def lpt_shard_order(step_counts: Sequence[int], n_shards: int
     bucketed scheduler (limits().shard_bucket_mode).
 
     The sharded routes split the [B] axis into n_shards equal
-    CONTIGUOUS blocks (obs.ledger.shard_real_steps is the accounting
-    twin), so batch ORDER is the packing degree of freedom: the legacy
+    CONTIGUOUS blocks (_shard_real_steps is the accounting twin), so
+    batch ORDER is the packing degree of freedom: the legacy
     append-pads-at-the-end order loads the leading shards with every
-    real step while the trailing shards sweep all-pad lanes — the
-    MULTICHIP_r06 straggler_table smoking gun (shards
-    [3913, ..., 2305, 0, 0]). This permutation assigns histories
-    longest-first to the least-loaded shard with remaining capacity
-    (ties -> lowest shard id), then restores ascending original order
-    within each shard, so equal-work shards retire their bucket
-    together and the mesh stops idling behind one straggler.
+    real step while the trailing shards sweep all-pad lanes (on one
+    8-device run, real steps per shard [3913, ..., 2305, 0, 0]). This
+    permutation assigns histories longest-first to the least-loaded
+    shard with remaining capacity (ties -> lowest shard id), then
+    restores ascending original order within each shard, so equal-work
+    shards retire their bucket together and the mesh stops idling
+    behind one straggler.
 
     Returns the permutation `perm` such that position j of the packed
     launch holds original entry perm[j]; identity when the batch does
-    not split evenly (mirroring shard_real_steps' degraded contract) or
+    not split evenly (mirroring _shard_real_steps' degraded contract) or
     there is nothing to balance. Pure and deterministic — same counts,
     same shard count, same order — so verdicts and compiled shapes are
     independent of packing (tests/test_pod_scaling.py pins determinism
@@ -91,6 +90,19 @@ def lpt_shard_order(step_counts: Sequence[int], n_shards: int
     for block in fill:
         block.sort()
     return [i for block in fill for i in block]
+
+
+def _shard_real_steps(step_counts: Sequence[int], n_shards: int
+                      ) -> list[int]:
+    """Per-shard real step totals of a padded launch whose [B] axis
+    splits into n_shards equal contiguous blocks; one total when the
+    batch does not split evenly."""
+    b = len(step_counts)
+    if n_shards <= 1 or b % n_shards:
+        return [int(sum(step_counts))]
+    per = b // n_shards
+    return [int(sum(step_counts[i * per:(i + 1) * per]))
+            for i in range(n_shards)]
 
 
 def _batch_bucket(n: int, cap: int) -> int:
@@ -131,7 +143,7 @@ def _dense_bucket_launcher(model, cfg, b: int, r: int):
     mesh identity, so an elastic re-shard between runs can only MISS
     the kernel LRU, never serve a stale compiled launch.
     Returns (run, plan) — the plan's label is the kernel name and its
-    identity feeds the scaling ledger's launch context."""
+    mesh gives the launch's shard count."""
     from .. import plan as kplan
 
     p = kplan.plan_dense_batch(model, cfg, n_steps=r, batch=b)
@@ -318,8 +330,7 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
             # Every bucket's launches dispatch async and are fetched at
             # drain.
             def _fetch_launch(entry):
-                part, part_steps, dev, lctx, perm = entry
-                t0f = time.monotonic_ns()
+                part, part_steps, dev, perm = entry
                 try:
                     with obs.phase("sched.fetch_wait"):
                         fetched = np.asarray(dev)
@@ -330,11 +341,6 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                     supervisor.note_failure(f"{type(e).__name__}: {e}",
                                             source="sched.dispatch")
                     raise
-                # The drain fetch is where async device time surfaces
-                # on the host — ledger it under the launch's context so
-                # padding/straggler decomposition sees the real wait.
-                obs.get_ledger().record_fetch(t0f, time.monotonic_ns(),
-                                              ctx=lctx)
                 with obs.phase("sched.unpack"):
                     if perm is None:
                         rows = fetched[:len(part)]
@@ -342,10 +348,7 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                         # Shard packing permuted the batch: row j holds
                         # original lane perm[j]; invert to read the real
                         # histories back in part order.
-                        inv = [0] * len(perm)
-                        for j, p in enumerate(perm):
-                            inv[p] = j
-                        rows = fetched[[inv[p] for p in range(len(part))]]
+                        rows = fetched[np.argsort(perm)[:len(part)]]
                     out = wgl3.unpack_np(rows)
                     for i, one in zip(part, wgl3.assemble_batch_results(
                             out, part_steps, cfg)):
@@ -383,17 +386,10 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                         run, plan_obj = _dense_bucket_launcher(model, cfg,
                                                                b, r)
                         padded = part_steps + [_pad_rs(k)] * (b - len(part))
-                        # Scaling ledger launch context: plan identity +
-                        # the bucket economics (real vs padded steps/batch,
-                        # per-shard real steps for straggler attribution) —
-                        # the instrumented kernel call and the H2D staging
-                        # inside the block inherit it.
                         real = sum(s.n_steps for s in part_steps)
-                        lctx = obs.ledger.plan_context(plan_obj)
-                        lctx.update(batch_real=len(part), batch_padded=b,
-                                    steps_real=real, steps_padded=b * r)
                         perm = None
-                        n_shards = lctx.get("n_shards", 1)
+                        shard_real = None
+                        n_shards = plan_obj.n_shards
                         if n_shards > 1:
                             if lim.shard_bucket_mode:
                                 # Shard-aware packing: permute the padded
@@ -406,16 +402,13 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                                     perm = None
                                 else:
                                     padded = [padded[j] for j in perm]
-                                    lctx["shard_packed"] = True
-                            lctx["shard_real"] = obs.ledger.shard_real_steps(
+                            shard_real = _shard_real_steps(
                                 [s.n_steps for s in padded], n_shards)
-                        with obs.ledger.launch_context(**lctx):
-                            arrays = wgl3.stack_steps3(padded, r)
-                    with obs.phase("sched.dispatch"), \
-                            obs.ledger.launch_context(**lctx):
+                        arrays = wgl3.stack_steps3(padded, r)
+                    with obs.phase("sched.dispatch"):
                         dev = run(*arrays)
-                    pipe.submit((part, part_steps, dev, lctx, perm))
-                    stats.record_launch(real, b, r, lctx.get("shard_real"))
+                    pipe.submit((part, part_steps, dev, perm))
+                    stats.record_launch(real, b, r, shard_real)
                     kernels.add(plan_obj.label)
             pipe.drain()
             if pipe.dispatched:

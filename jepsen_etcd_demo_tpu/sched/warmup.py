@@ -18,10 +18,8 @@ a disk-cache hit; the serve daemon and campaign runner call the same
 function at startup (one cheap rung) so a cold store never puts a
 compile on a request's critical path.
 
-The report is ledger-armed: every warmup launch runs under an obs
-capture, and the returned record carries the zeros-never-absent
-``ledger`` object the bench contract requires
-(tools/bench_compare.py check_ledger_record — smoked by tier-1).
+Every warmup launch runs under an obs capture, and the returned record
+carries the capture's ``wgl.compile_s`` / ``wgl.execute_s`` seconds.
 """
 
 from __future__ import annotations
@@ -63,7 +61,7 @@ def warmup_plans(model=None, mesh=None, *, k_slots: int = 16,
                  encoder: bool = True) -> dict[str, Any]:
     """Pre-compile the plan-family corpus for this platform into the
     persistent XLA cache. Returns the warmup record: per-family launch
-    labels, compile/execute seconds (the ledger object), wall, and the
+    labels, compile/execute seconds (the wgl.* counters), wall, and the
     active cache directory (None when the cache is disabled)."""
     import jax
     import jax.numpy as jnp
@@ -71,7 +69,6 @@ def warmup_plans(model=None, mesh=None, *, k_slots: int = 16,
 
     from .. import obs
     from ..models import CASRegister
-    from ..obs import ledger as obs_ledger
     from ..ops import wgl3
     from ..ops.encode import EVENT_WIDTH
     from ..ops.limits import limits
@@ -101,21 +98,16 @@ def warmup_plans(model=None, mesh=None, *, k_slots: int = 16,
             p = plan_dense_batch(model, cfg, n_steps=r, batch=b,
                                  mesh=mesh)
             check = resolve(p)
-            lctx = obs_ledger.plan_context(p)
-            lctx.update(batch_real=0, batch_padded=b, steps_real=0,
-                        steps_padded=b * r)
             # All-pad inputs: targets=-1 rows are zero search work, so
-            # the launch is almost pure compile — exactly what a warmup
-            # wants on the ledger.
+            # the launch is almost pure compile.
             tabs = np.zeros((b, r, cfg.k_slots, 4), np.int32)
             act = np.zeros((b, r, cfg.k_slots), bool)
             tgt = np.full((b, r), -1, np.int32)
-            with obs_ledger.launch_context(**lctx):
-                # jtlint: disable=JTL103 -- warmup wants the block: each
-                # rung's fetch materializes its compile into the
-                # persistent cache before the next rung is measured
-                np.asarray(check(jnp.asarray(tabs), jnp.asarray(act),
-                                 jnp.asarray(tgt)))
+            # jtlint: disable=JTL103 -- warmup wants the block: each
+            # rung's fetch materializes its compile into the
+            # persistent cache before the next rung is measured
+            np.asarray(check(jnp.asarray(tabs), jnp.asarray(act),
+                             jnp.asarray(tgt)))
             families.append(p.label)
             launches += 1
             if encoder and lim.encode_mode != 1:
@@ -127,12 +119,11 @@ def warmup_plans(model=None, mesh=None, *, k_slots: int = 16,
                     ev[:, :, 0] = 2          # EV_PAD
                     enc_fn = pdense.sharded_device_encoder(
                         cfg.k_slots, e_cap, r, mesh)
-                    with obs_ledger.launch_context(**lctx):
-                        for a in enc_fn(jnp.asarray(ev)):
-                            np.asarray(a)
+                    for a in enc_fn(jnp.asarray(ev)):
+                        np.asarray(a)
                     families.append("wgl3-encode-sharded")
                     launches += 1
-        led = obs.ledger_stats(cap.metrics)
+        phases = obs.kernel_phases(cap.metrics)
     return {
         "value": launches,
         "backend": jax.default_backend(),
@@ -141,7 +132,8 @@ def warmup_plans(model=None, mesh=None, *, k_slots: int = 16,
         "families": sorted(set(families)),
         "launches": launches,
         "wall_s": round(time.monotonic() - t0, 4),
-        "ledger": led,
+        "compile_s": phases["compile_s"],
+        "execute_s": phases["execute_s"],
     }
 
 

@@ -48,6 +48,7 @@ equivalence), and the oracle shed runs the same WGL algorithm on host
 from __future__ import annotations
 
 import itertools
+import os
 import threading
 import time
 import uuid
@@ -85,6 +86,80 @@ TENANT_LATENCY_TENANTS = 256
 # tenant, so a million-scenario campaign cannot starve an interactive
 # tenant, which is the whole point of submitting it AS a tenant.
 CAMPAIGN_TENANT = "campaign"
+
+
+# -- rolling-window SLO gauges ----------------------------------------------
+
+def slo_target_s() -> float:
+    """The serve SLO latency target (p99 threshold) in seconds."""
+    try:
+        return float(os.environ.get("JEPSEN_TPU_SERVE_SLO_P99_S", "1.0"))
+    except ValueError:
+        return 1.0
+
+
+def slo_budget() -> float:
+    """The SLO error budget: the tolerated breach fraction (burn rate
+    1.0 means breaches exactly consume the budget)."""
+    try:
+        return float(os.environ.get("JEPSEN_TPU_SERVE_SLO_BUDGET",
+                                    "0.01"))
+    except ValueError:
+        return 0.01
+
+
+class RollingWindow:
+    """A time-bounded latency window for the serve daemon's live SLO
+    gauges: p50/p99 over the last window_s seconds (the cumulative
+    request histogram can't forget, so a recovered daemon would wear
+    its worst minute forever) plus the burn rate — the breach fraction
+    over the error budget."""
+
+    def __init__(self, window_s: float = 60.0, maxlen: int = 4096):
+        self.window_s = window_s
+        self.maxlen = maxlen
+        self._items: list[tuple[float, float]] = []
+
+    def observe(self, value: float, now: Optional[float] = None) -> None:
+        now = time.monotonic() if now is None else now
+        self._items.append((now, float(value)))
+        self._prune(now)
+
+    def _prune(self, now: float) -> None:
+        cut = now - self.window_s
+        i = 0
+        n = len(self._items)
+        while i < n and self._items[i][0] < cut:
+            i += 1
+        if i or n > self.maxlen:
+            self._items = self._items[max(i, n - self.maxlen):]
+
+    def values(self, now: Optional[float] = None) -> list[float]:
+        self._prune(time.monotonic() if now is None else now)
+        return [v for _, v in self._items]
+
+    def quantiles(self, now: Optional[float] = None) \
+            -> tuple[float, float]:
+        vals = sorted(self.values(now))
+        if not vals:
+            return 0.0, 0.0
+
+        def q(p: float) -> float:
+            return vals[min(len(vals) - 1, int(p * len(vals)))]
+
+        return q(0.50), q(0.99)
+
+    def burn_rate(self, slo_s: Optional[float] = None,
+                  budget: Optional[float] = None,
+                  now: Optional[float] = None) -> float:
+        vals = self.values(now)
+        if not vals:
+            return 0.0
+        slo = slo_target_s() if slo_s is None else slo_s
+        bud = slo_budget() if budget is None else budget
+        breach = sum(1 for v in vals if v > slo) / len(vals)
+        return round(breach / bud, 4) if bud > 0 else 0.0
+
 
 
 class Rejected(Exception):
@@ -171,13 +246,13 @@ class CoalescingScheduler:
         self._shed_cpu = 0
         self._fill_sum = 0.0
         self._tenant_latency: dict[str, deque] = {}
-        # Rolling-window SLO gauges (obs/ledger.py RollingWindow,
-        # ISSUE 16): p50/p99 over the last minute + burn rate, updated
-        # by the dispatch thread only; stats() reads the copied dict.
-        self._slo_window = obs.ledger.RollingWindow()
+        # Rolling-window SLO gauges: p50/p99 over the last minute +
+        # burn rate, updated by the dispatch thread only; stats() reads
+        # the copied dict.
+        self._slo_window = RollingWindow()
         self._slo = {"slo_p50_s": 0.0, "slo_p99_s": 0.0,
                      "slo_burn_rate": 0.0,
-                     "slo_target_s": obs.ledger.slo_target_s()}
+                     "slo_target_s": slo_target_s()}
         self._thread = threading.Thread(target=self._run,
                                         name="serve-dispatch", daemon=True)
         self._thread.start()
@@ -458,7 +533,7 @@ class CoalescingScheduler:
         if shed:
             m.counter("serve.shed_cpu").add(len(batch))
         m.gauge("serve.batch_fill").set(fill)
-        # The live SLO cells (/live, ledger_stats): rolling-window
+        # The live SLO cells (/live): rolling-window
         # quantiles, not the cumulative histogram — a recovered daemon
         # must not wear its worst minute forever.
         p50, p99 = self._slo_window.quantiles(now=now)

@@ -5,10 +5,8 @@ watermarks, the incremental ElleGraph) bounds DEVICE memory per chunk;
 this module extends the same discipline to the HOST. Three pieces:
 
   * :class:`SpillDir` — an atomic, digest-framed blob store next to the
-    content-addressed encode cache. Every read/write is timed into the
-    ledger's first-class ``spill_read``/``spill_write`` buckets
-    (obs/ledger.py) and counted on the ``spill.*`` registry families,
-    so ``scaling_report`` shows where the disk-seconds go.
+    content-addressed encode cache. Every read/write is counted on the
+    ``spill.*`` registry families.
   * :class:`FrontierCodec` framing (:func:`encode_frontier` /
     :func:`decode_frontier`) — spilled wgl2/wgl3 frontier checkpoints,
     compressed with the PR 10 canon quotient: a CANONICAL frontier
@@ -44,14 +42,13 @@ import os
 import resource
 import sys
 import tempfile
-import time
 from contextlib import contextmanager
 from pathlib import Path
 from typing import Iterator, Optional
 
 import numpy as np
 
-from ..obs import get_ledger, get_metrics
+from ..obs import get_metrics
 from ..ops.limits import limits
 
 SPILL_DIRNAME = ".spill"
@@ -270,8 +267,8 @@ class SpillDir:
     """Digest-framed blob store for the out-of-core tier. Writes are
     atomic (mkstemp + os.replace — a crash mid-spill leaves either the
     previous entry or a tmp file, never a torn named entry; the codec
-    digest catches everything else). Every transfer is timed into the
-    ledger's spill buckets and counted on the spill.* families."""
+    digest catches everything else). Every transfer is counted on the
+    spill.* families."""
 
     def __init__(self, root: str | os.PathLike):
         self.root = Path(root)
@@ -281,7 +278,6 @@ class SpillDir:
         return self.root / name
 
     def write(self, name: str, blob: bytes) -> Optional[Path]:
-        t0 = time.monotonic_ns()
         path = self.path(name)
         try:
             fd, tmp = tempfile.mkstemp(dir=self.root, suffix=".tmp")
@@ -297,42 +293,34 @@ class SpillDir:
                 raise
         except OSError:
             return None   # spill is an optimization tier, not a fault
-        t1 = time.monotonic_ns()
         m = get_metrics()
         m.counter("spill.writes").add(1)
         m.counter("spill.bytes_written").add(len(blob))
-        get_ledger().record_spill("spill_write", len(blob), t0, t1)
         return path
 
     def append(self, name: str, blob: bytes) -> bool:
         """Unframed append spool (streamed edge runs): NOT atomic and
         NOT digest-framed — spools are same-call scratch, never
         checkpoints, so a crash discards the whole spool rather than
-        resuming from it. Same ledger/counter accounting as write()."""
-        t0 = time.monotonic_ns()
+        resuming from it. Same counter accounting as write()."""
         try:
             with open(self.path(name), "ab") as f:
                 f.write(blob)
         except OSError:
             return False
-        t1 = time.monotonic_ns()
         m = get_metrics()
         m.counter("spill.writes").add(1)
         m.counter("spill.bytes_written").add(len(blob))
-        get_ledger().record_spill("spill_write", len(blob), t0, t1)
         return True
 
     def read(self, name: str) -> Optional[bytes]:
-        t0 = time.monotonic_ns()
         try:
             blob = self.path(name).read_bytes()
         except OSError:
             return None
-        t1 = time.monotonic_ns()
         m = get_metrics()
         m.counter("spill.reads").add(1)
         m.counter("spill.bytes_read").add(len(blob))
-        get_ledger().record_spill("spill_read", len(blob), t0, t1)
         return blob
 
     def delete(self, name: str) -> None:

@@ -24,9 +24,8 @@ as absent and the lane recomputes from the start — slower, never wrong.
 
 RSS accounting: the lane reports ``peak_rss_mb`` as the DELTA of
 ``ru_maxrss`` over the lane (store/spill.py rss_mb), checked against the
-``host_rss_budget_mb`` knob — the long-haul bench gate
-(tools/bench_compare.py ``longhaul_peak_rss_mb``, inverted: lower is
-better) holds the whole out-of-core claim to a pinned ceiling.
+``host_rss_budget_mb`` knob (``rss_ok``), which holds the whole
+out-of-core claim to a pinned ceiling.
 """
 
 from __future__ import annotations

@@ -33,7 +33,6 @@ from http.server import SimpleHTTPRequestHandler, ThreadingHTTPServer
 from .. import obs
 from ..obs import (METRICS_FILE, TELEMETRY_FILE, export, health,
                    read_jsonl, read_metrics)
-from ..obs import ledger as obs_ledger
 from ..store import Store
 
 
@@ -471,48 +470,6 @@ def _metrics_table_html(metrics: dict) -> str:
             f"{''.join(rows)}</table>")
 
 
-def _ledger_waterfall_html(run_dir) -> str:
-    """The scaling-ledger waterfall panel (ISSUE 16): merge the run's
-    per-process ledger-<proc>.jsonl files into one pod timeline and
-    render the loss-bucket decomposition — where the chip-seconds went.
-    Empty string when the run carries no ledger files; merge warnings
-    (truncated / meta-less files) surface in the panel, never a 500."""
-    paths = obs_ledger.ledger_paths(run_dir)
-    if not paths:
-        return ""
-    try:
-        merged = obs_ledger.merge_ledgers(paths)
-        att = obs_ledger.attribute(merged["records"])
-    except Exception as e:   # a torn ledger must not 500 the page
-        return (f"<h3>scaling ledger</h3><p class='err'>ledger "
-                f"unreadable: {html.escape(str(e))}</p>")
-    parts = [f"<h3>scaling ledger</h3>",
-             f"<p class='a'>{len(paths)} file(s), processes "
-             f"{merged['procs'] or [0]}; window "
-             f"{att['window_s']:.3f}s, {att['launches']} launches, "
-             f"coverage {100 * att['coverage']:.1f}%</p>"]
-    for w in merged["warnings"]:
-        parts.append(f"<p class='warn'>&#9888; {html.escape(w)}</p>")
-    wall = max(att["wall_s"], 1e-9)
-    rows = []
-    for name, secs in sorted(att["buckets"].items(),
-                             key=lambda kv: -kv[1]):
-        pct = 100.0 * secs / wall
-        bar = ("<div style='background:#2a6db0;height:10px;"
-               f"width:{min(100.0, pct):.1f}%'></div>")
-        rows.append(f"<tr><td><code>{html.escape(name)}</code></td>"
-                    f"<td>{secs:.3f}s</td><td>{pct:.1f}%</td>"
-                    f"<td style='width:220px'>{bar}</td></tr>")
-    parts.append("<table><tr><th>bucket</th><th>seconds</th>"
-                 "<th>share</th><th></th></tr>" + "".join(rows)
-                 + "</table>")
-    top = att.get("top_losses") or []
-    if top:
-        parts.append("<p class='a'>top losses: " + ", ".join(
-            f"{html.escape(k)}={v:.3f}s" for k, v in top[:3]) + "</p>")
-    return "".join(parts)
-
-
 def _telemetry_html(store: Store, rel: str) -> str | None:
     """Render <store>/<rel>'s telemetry artifacts; None -> 404 (missing
     run, no artifacts, or a path escaping the store root)."""
@@ -539,7 +496,6 @@ def _telemetry_html(store: Store, rel: str) -> str | None:
         f"<p><a href='/'>index</a> · "
         f"<a href='{urllib.parse.quote(f'/files/{rel}/')}'>run files</a></p>",
         _perf_summary_html(run_dir),
-        _ledger_waterfall_html(run_dir),
     ]
     if tele.exists():
         records = read_jsonl(tele)
@@ -636,14 +592,14 @@ start one with <code>jepsen-tpu test &hellip; --live-port</code></p>
 <th>ops ok</th><th>ops/s</th><th>ops fail</th><th>stream overlap</th>
 <th>watermark lag</th><th>frontier peak</th><th>serve queue</th>
 <th>batch fill</th><th>campaign specs</th><th>falsified</th>
-<th>banked</th><th>chip util</th><th>SLO p99</th>
+<th>banked</th><th>SLO p99</th>
 <th>SLO burn</th></tr><tr>
 <td id='ok'>0</td><td id='rate'>&ndash;</td><td id='fail'>0</td>
 <td id='overlap'>&ndash;</td><td id='lag'>&ndash;</td>
 <td id='frontier'>&ndash;</td><td id='squeue'>&ndash;</td>
 <td id='sfill'>&ndash;</td><td id='cspecs'>&ndash;</td>
 <td id='cfals'>&ndash;</td><td id='cbank'>&ndash;</td>
-<td id='lutil'>&ndash;</td><td id='slop99'>&ndash;</td>
+<td id='slop99'>&ndash;</td>
 <td id='sloburn'>&ndash;</td></tr></table>
 <h3>nemesis / events</h3><ul id='events'></ul>
 <h3>span tree</h3><ul class='tree' id='spans'></ul>
@@ -676,23 +632,11 @@ function met(name, m){
     el('cfals').textContent = m.value;
   else if (name === 'campaign.banked')
     el('cbank').textContent = m.value;
-  else if (name === 'ledger.execute_s'){
-    ledgerExec = m.value; updUtil();
-  } else if (name === 'ledger.dispatch_gap_s'){
-    ledgerGap = m.value; updUtil();
-  } else if (name === 'serve.slo_p99_s' && m.last !== null)
+  else if (name === 'serve.slo_p99_s' && m.last !== null)
     el('slop99').textContent = (1000 * m.last).toFixed(0) + ' ms';
   else if (name === 'serve.slo_burn_rate' && m.last !== null)
     el('sloburn').textContent = m.last.toFixed(2) + 'x';
   else if (name === 'health.state') setHealth(m.last);
-}
-let ledgerExec = 0, ledgerGap = 0;
-// Utilization derived from the scaling ledger's cumulative buckets:
-// device-busy seconds over device-busy + host dispatch gap.
-function updUtil(){
-  const busy = ledgerExec + ledgerGap;
-  if (busy > 0)
-    el('lutil').textContent = (100 * ledgerExec / busy).toFixed(0) + '%';
 }
 function setHealth(v){
   const s = typeof v === 'string' ? v

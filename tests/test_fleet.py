@@ -535,38 +535,70 @@ class TestFleetSubprocessEndToEnd:
 
 @pytest.mark.slow
 class TestBenchFleetLane:
-    def test_lane_contract_tiny_scale(self, healthy_supervisor):
-        """The open-loop lane at toy scale: schema complete (the
-        bench_compare gate and the schema check both pass on it),
-        verdict parity certified, both arms measured. The affine-beats-
-        random assertion is left to the real bench run — at this scale
-        the win is not statistically forced."""
-        import sys
-        from pathlib import Path
+    def test_lane_contract_tiny_scale(self, healthy_supervisor, rng,
+                                      tmp_path):
+        """The same histories through a fresh 2-replica fleet under
+        shape-blind random routing, then under affine routing: every
+        verdict of both arms equals the analyze route's (invalid ones
+        included), and each arm's replicas looked kernels up."""
+        from jepsen_etcd_demo_tpu.checkers import Linearizable
 
-        import bench
+        hists = [_hist(rng, n_ops=8 + 6 * i, invalid=(i % 3 == 2))
+                 for i in range(10)]
+        lin = Linearizable(model="cas-register")
+        analyzed = [lin.check({}, h, {}) for h in hists]
+        assert any(a["valid"] is not True for a in analyzed)
+        for mode in (RANDOM, AFFINE):
+            router = FleetRouter(salt=0, spillover_mode=mode,
+                                 poll_interval_s=0.5,
+                                 request_timeout_s=300.0)
+            store = str(tmp_path / f"store-{mode}")
+            sup = FleetSupervisor(store, n=2, router=router,
+                                  env=dict(_CHILD_ENV), max_inflight=32)
+            httpd = None
+            try:
+                sup.start()
+                httpd = ThreadingHTTPServer(
+                    ("127.0.0.1", 0), make_fleet_handler(store, router,
+                                                         sup))
+                threading.Thread(target=httpd.serve_forever,
+                                 daemon=True).start()
+                front = f"http://127.0.0.1:{httpd.server_address[1]}"
+                served = [None] * len(hists)
 
-        sys.path.insert(0, str(Path(bench.__file__).parent / "tools"))
-        import bench_compare
+                def client(c):
+                    for i in range(c, len(hists), 3):
+                        st, body, _ = _post_url(
+                            front + "/check",
+                            {"tenant": f"tenant-{c}",
+                             "model": "cas-register", "wait": True,
+                             "history": _op_dicts(hists[i])})
+                        assert st == 200, body
+                        served[i] = body
 
-        lane = bench.bench_fleet(MODEL, n_hist=10, replicas=2,
-                                 ops_range=(8, 64), max_knee_rungs=1,
-                                 assert_win=False)
-        for key in bench_compare.FLEET_LANE_KEYS:
-            assert key in lane, key
-        json.dumps(lane)
-        assert lane["verdicts_identical"] is True
-        assert lane["invalid"] > 0
-        assert lane["agg_eps"] > 0 and lane["p99_s"] > 0
-        for arm in ("affine", "random"):
-            for key in bench_compare.FLEET_ARM_KEYS:
-                assert key in lane[arm], (arm, key)
-            assert lane[arm]["lookups"] > 0
-        rec = {"metric": "wgl_check_throughput", "value": 1.0,
-               "degraded": False, "backend": "cpu",
-               "fleet": obs.fleet_stats(None),
-               "detail": {"fleet": lane}}
-        assert bench_compare.check_fleet_record(rec) == []
+                ts = [threading.Thread(target=client, args=(c,))
+                      for c in range(3)]
+                for th in ts:
+                    th.start()
+                for th in ts:
+                    th.join(300)
+                for got, want in zip(served, analyzed):
+                    assert got is not None, "client thread died"
+                    assert got["valid"] == want["valid"]
+                    if "dead_step" in want:
+                        assert got["dead_step"] == int(want["dead_step"])
+                st, stats = _get_url(front + "/serve/stats")
+                assert st == 200
+                lookups = sum(
+                    rep["scheduler"]["kernel_cache"]["hits"]
+                    + rep["scheduler"]["kernel_cache"]["misses"]
+                    for rep in stats["replicas"].values())
+                assert lookups > 0, mode
+            finally:
+                if httpd is not None:
+                    httpd.shutdown()
+                    httpd.server_close()
+                sup.close()
 
 
 class TestFleetPlatformGuard:

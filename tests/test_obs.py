@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 
 import pytest
 
@@ -202,6 +203,26 @@ class TestKernelAttribution:
         assert snap.get("wgl.compile_calls", {"value": 0})["value"] == 0
         assert snap["wgl.execute_calls"]["value"] == 1
         assert snap["wgl.compile_s"]["value"] == 0   # pre-registered zero
+
+
+    def test_instrument_kernel_emits_compile_then_execute(self):
+        """The first call's wall lands in wgl.compile_s (and its
+        per-kernel histogram and a wgl.compile event), the second's in
+        wgl.execute_s."""
+        naps = iter([0.03, 0.01])
+        fn = obs.instrument_kernel("wgl-k",
+                                   lambda: time.sleep(next(naps)))
+        with obs.capture() as cap:
+            fn()
+            fn()
+        snap = cap.metrics.snapshot()
+        assert 0.03 <= snap["wgl.compile_s"]["value"] < 0.5
+        assert 0.01 <= snap["wgl.execute_s"]["value"] < 0.03
+        assert snap["wgl.compile_s.wgl-k"]["count"] == 1
+        assert snap["wgl.execute_s.wgl-k"]["count"] == 1
+        events = [r for r in cap.tracer.records()
+                  if r.get("name") == "wgl.compile"]
+        assert len(events) == 1 and events[0]["attrs"]["kernel"] == "wgl-k"
 
 
 class TestEndToEndArtifacts:

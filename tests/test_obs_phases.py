@@ -171,3 +171,80 @@ def test_compile_counters_count_under_a_capture_only():
     seen = {n: _value(cap, n) for n in obs.COMPILE_COUNTERS}
     jax.jit(lambda x: x * 5 - 2)(jnp.ones((9,), jnp.float32))
     assert {n: _value(cap, n) for n in obs.COMPILE_COUNTERS} == seen
+
+
+@pytest.mark.parametrize("nested", [False, True], ids=["outermost", "nested"])
+@pytest.mark.parametrize("name", obs.PHASES)
+def test_phase_counter_advances_only_when_outermost(name, nested):
+    """Each phase's <phase>_s counter takes the phase's seconds when it is
+    the outermost open phase, and nothing when it opens inside another
+    phase (whose counter takes them instead)."""
+    outer = next(p for p in obs.PHASES if p != name)
+    counter = obs.PHASE_SECONDS[obs.PHASES.index(name)]
+    outer_counter = obs.PHASE_SECONDS[obs.PHASES.index(outer)]
+    with obs.capture() as cap:
+        with obs.phase(outer) if nested else nullcontext():
+            with obs.phase(name):
+                time.sleep(0.01)
+    if nested:
+        assert _value(cap, counter) == 0
+        assert _value(cap, outer_counter) >= 0.01
+    else:
+        assert _value(cap, counter) >= 0.01
+        assert all(_value(cap, c) == 0 for c in obs.PHASE_SECONDS
+                   if c != counter)
+
+
+# The counters the benchmark's per-layer metrics read (BENCHMARK.json,
+# benchmark/run.py): each is in the capture after a check, zero or not.
+BENCHMARK_COUNTERS = (obs.COMPILE_COUNTERS + obs.PHASE_SECONDS
+                      + ("encode.encode_s", "sched.steps_real",
+                         "sched.steps_padded", "sched.shard_steps_max",
+                         "sched.launches", obs.GC_COUNTER))
+
+# What one small check of each kind certainly moves (the tests' platform
+# has 8 CPU devices, so the keys of the independent check are sharded).
+ADVANCES = {
+    "independent": {"encode.encode_s", "check.encode_s", "sched.pack_s",
+                    "sched.steps_real", "sched.steps_padded",
+                    "sched.shard_steps_max", "sched.launches"},
+    "linearizable": {"encode.encode_s", "check.encode_s"},
+}
+
+
+@pytest.fixture(scope="module")
+def check_snapshots():
+    import random
+
+    from jepsen_etcd_demo_tpu.checkers.independent import IndependentChecker
+    from jepsen_etcd_demo_tpu.checkers.linearizable import Linearizable
+    from jepsen_etcd_demo_tpu.utils.fuzz import (gen_register_history,
+                                                 interleave_keyed,
+                                                 mutate_history)
+
+    rng = random.Random(28)
+    per_key = {k: gen_register_history(rng, n_ops=30, n_procs=3)
+               for k in range(6)}
+    per_key[2] = mutate_history(rng, per_key[2])
+    one = gen_register_history(rng, n_ops=80, n_procs=4)
+    snaps = {}
+    with obs.capture() as cap:
+        res = IndependentChecker(Linearizable(backend="jax")).check(
+            {}, interleave_keyed(per_key))
+    assert len(res["results"]) == 6
+    snaps["independent"] = cap.metrics.snapshot()
+    with obs.capture() as cap:
+        assert Linearizable(backend="jax").check({}, one)["valid"] is True
+    snaps["linearizable"] = cap.metrics.snapshot()
+    return snaps
+
+
+@pytest.mark.parametrize("counter", BENCHMARK_COUNTERS)
+@pytest.mark.parametrize("checker", sorted(ADVANCES))
+def test_benchmark_counter_is_in_the_capture(check_snapshots, checker,
+                                             counter):
+    rec = check_snapshots[checker].get(counter)
+    assert rec is not None and rec["type"] == "counter", counter
+    assert rec["value"] >= 0
+    if counter in ADVANCES[checker]:
+        assert rec["value"] > 0, (checker, counter)

@@ -6,15 +6,13 @@ The load-bearing contract is bit-identity: the device encoder against
 the host encoder (golden + fuzz, crashed-op pinning and LIFO slot
 reuse included), the shard-aware bucketer against the legacy one-launch
 discipline, and the mesh against the single-device arm — the perf work
-must move seconds between ledger buckets without moving a single
-verdict bit.
+must move time between layers without moving a single verdict bit.
 """
 
 from __future__ import annotations
 
 import json
 import random
-import sys
 from dataclasses import replace
 from pathlib import Path
 
@@ -33,12 +31,6 @@ from jepsen_etcd_demo_tpu.plan import LaunchPipeline
 from jepsen_etcd_demo_tpu.sched import lpt_shard_order
 from jepsen_etcd_demo_tpu.utils.fuzz import (gen_register_history,
                                              mutate_history)
-
-TOOLS = Path(__file__).resolve().parent.parent / "tools"
-sys.path.insert(0, str(TOOLS))
-
-import bench_compare  # noqa: E402
-import scaling_report  # noqa: E402
 
 MODEL = CASRegister()
 
@@ -294,10 +286,14 @@ def test_warmup_plans_record_passes_ledger_contract(tmp_path):
     assert rec["launches"] >= 1 and rec["value"] == rec["launches"]
     assert any(f.startswith("wgl3-dense") for f in rec["families"])
     assert rec["cache_dir"] is None or Path(rec["cache_dir"]).exists()
-    # The zeros-never-absent ledger object the bench contract requires.
-    assert bench_compare.check_ledger_record(rec) == []
-    for key in bench_compare.LEDGER_STATS_KEYS:
-        assert key in rec["ledger"]
+    assert set(rec) == {"value", "backend", "cache_dir", "mesh_shape",
+                        "families", "launches", "wall_s", "compile_s",
+                        "execute_s"}
+    json.dumps(rec)
+    # Every warmup launch is instrumented: the record's kernel seconds
+    # come from the capture's wgl.* counters.
+    assert rec["compile_s"] > 0.0 or rec["execute_s"] > 0.0
+    assert rec["wall_s"] >= rec["compile_s"]
 
 
 def test_warmup_env_kill_switch(tmp_path, monkeypatch):
@@ -306,73 +302,3 @@ def test_warmup_env_kill_switch(tmp_path, monkeypatch):
 
     monkeypatch.setenv(NO_WARMUP_ENV, "1")
     assert startup_warmup() is None
-
-
-def _att(wall, execute, padding, straggler):
-    other = max(0.0, wall - execute - padding - straggler)
-    return {"wall_s": wall, "coverage": 0.99, "launches": 4,
-            "buckets": {"encode_s": 0.0, "h2d_s": 0.0, "compile_s": 0.0,
-                        "execute_s": execute, "padding_s": padding,
-                        "straggler_s": straggler, "dispatch_gap_s": 0.0,
-                        "other_s": other}}
-
-
-def test_scaling_report_diff_gates_regressions(tmp_path):
-    old = {"parsed": {"scaling": {"ledger": _att(10, 4.5, 3.5, 2.0)}}}
-    good = {"scaling": {"ledger": _att(8, 6.4, 0.9, 0.6)}}
-    bad = _att(9, 2.0, 5.5, 1.3)          # padding share blew up
-    paths = {}
-    for name, rec in (("old", old), ("good", good), ("bad", bad)):
-        p = tmp_path / f"{name}.json"
-        p.write_text(json.dumps(rec))
-        paths[name] = str(p)
-    assert scaling_report.main(
-        ["--diff", paths["old"], paths["good"]]) == 0
-    assert scaling_report.main(
-        ["--diff", paths["old"], paths["bad"]]) == 1
-    res = scaling_report.diff_records(old, bad)
-    assert res["comparable"] and "padding_s" in res["regressions"]
-    # execute_s collapse alone is NOT a gated regression (ungated).
-    assert "execute_s" not in res["regressions"]
-    # Records without a ledger arm are not comparable (and not fatal).
-    empty = tmp_path / "empty.json"
-    empty.write_text("{}")
-    assert scaling_report.main(
-        ["--diff", str(empty), paths["good"]]) == 0
-
-
-def test_bench_compare_scaling_lane_tight_ratchet():
-    """scaling_eps_per_chip gates at the tighter per-lane 5% while the
-    other lanes stay on the global threshold."""
-    def rec(per_chip):
-        return {"value": 1000.0,
-                "scaling": {"events_per_chip": per_chip,
-                            "efficiency_vs_single": 0.5,
-                            "mesh_shape": {"batch": 8}}}
-
-    res = bench_compare.compare(rec(1000.0), rec(930.0),
-                                threshold_pct=10.0)
-    assert "scaling_eps_per_chip" in res["regressions"]   # -7% > 5%
-    res = bench_compare.compare(rec(1000.0), rec(970.0),
-                                threshold_pct=10.0)
-    assert res["regressions"] == []                       # -3% < 5%
-
-
-def test_multichip_r07_record_loads_and_diff_gates_clean():
-    """The committed MULTICHIP_r07.json is ledger-armed: it loads
-    through the driver-wrapper path, self-compares clean on every
-    bench lane, and self-diffs clean through the gated loss-bucket
-    report (scaling_report --diff)."""
-    repo = Path(__file__).resolve().parent.parent
-    rec = bench_compare.load_record(repo / "MULTICHIP_r07.json")
-    scal = rec["scaling"]
-    assert scal["mesh_shape"] == {"batch": 8}
-    assert scal["efficiency_vs_single"] >= 0.45   # the ISSUE 17 gate
-    led = scal["ledger"]
-    assert led["coverage"] >= 0.95
-    wall = led["wall_s"]
-    lost = led["buckets"]["padding_s"] + led["buckets"]["straggler_s"]
-    assert lost / wall <= 0.276    # >=2x cut vs r06's 55.2% loss share
-    assert bench_compare.compare(rec, rec)["regressions"] == []
-    res = scaling_report.diff_records(rec, rec)
-    assert res["comparable"] and res["regressions"] == []

@@ -354,3 +354,88 @@ class TestEncodeCache:
         encode_cache.store(h, "cas-register", 24,
                            encode_register_history(h))
         assert list(tmp_path.iterdir()) == []
+
+
+# -- launch accounting ------------------------------------------------------
+
+def _corpus_of(lengths: list[int], seed: int):
+    rng = random.Random(seed)
+    return [encode_register_history(
+        gen_register_history(rng, n_ops=n, n_procs=3, p_info=0.0),
+        k_slots=8)
+        for n in lengths]
+
+
+CORPORA = {
+    "uniform": [40] * 12,
+    "ragged": [10, 25, 40, 55, 70, 85, 100, 115, 20, 35, 50, 65, 80],
+    "one-long": [20] * 11 + [300],
+    "many-short": [6] * 40,
+}
+
+
+class TestShardRealSteps:
+    @pytest.mark.parametrize("counts,n_shards,want", [
+        ([3, 2, 1, 0], 2, [5, 1]),
+        ([3, 2, 1], 2, [6]),              # uneven: one total
+        ([4, 0, 0, 4, 1, 1, 0, 2], 4, [4, 4, 2, 2]),
+        ([7, 7], 1, [14]),
+    ])
+    def test_contiguous_blocks(self, counts, n_shards, want):
+        from jepsen_etcd_demo_tpu.sched.engine import _shard_real_steps
+
+        assert _shard_real_steps(counts, n_shards) == want
+
+
+class TestLaunchCounters:
+    @pytest.mark.parametrize("n_shards", [1, 2, 4])
+    @pytest.mark.parametrize("corpus", sorted(CORPORA))
+    def test_counters_match_a_recount_of_the_launches(
+            self, corpus, n_shards, monkeypatch):
+        """sched.steps_real / steps_padded / launches / shard_steps_max
+        after check_corpus equal a recount of the batches it stacked: per
+        launch the real steps, batch x bucket, and on a mesh the largest
+        contiguous shard block's real steps x the shard count."""
+        from jepsen_etcd_demo_tpu.parallel.mesh import MESH_SHAPE_ENV
+
+        monkeypatch.setenv(MESH_SHAPE_ENV, str(n_shards))
+        encs = _corpus_of(CORPORA[corpus], seed=len(corpus) + n_shards)
+        launched = []
+        stack = wgl3.stack_steps3
+
+        def spy(steps, r_cap):
+            launched.append(([s.n_steps for s in steps], int(r_cap)))
+            return stack(steps, r_cap)
+
+        monkeypatch.setattr(wgl3, "stack_steps3", spy)
+        with obs.capture() as cap:
+            results, _, stats = sched.check_corpus(encs, MODEL)
+        snap = cap.metrics.snapshot()
+
+        def counter(name):
+            return snap[name]["value"]
+
+        want_real = sum(sum(c) for c, _ in launched)
+        want_padded = sum(len(c) * r for c, r in launched)
+        want_max = 0
+        if n_shards > 1:
+            for c, _ in launched:
+                per = len(c) // n_shards
+                assert per * n_shards == len(c), "batch off the mesh"
+                want_max += n_shards * max(
+                    sum(c[i * per:(i + 1) * per])
+                    for i in range(n_shards))
+        assert launched and counter("sched.launches") == len(launched)
+        assert counter("sched.steps_real") == want_real
+        assert counter("sched.steps_padded") == want_padded
+        assert counter("sched.shard_steps_max") == want_max
+        assert counter("sched.shard_launches") == (
+            len(launched) if n_shards > 1 else 0)
+        # Every history was launched exactly once, and the stats dict
+        # agrees with the counters.
+        assert want_real == sum(encode_return_steps(e).n_steps
+                                for e in encs)
+        assert (stats["launches"], stats["steps_real"],
+                stats["steps_padded"]) == (len(launched), want_real,
+                                           want_padded)
+        assert all(r is not None for r in results)

@@ -3,7 +3,8 @@
 Runs in interpreter mode on the virtual-CPU platform (conftest forces it),
 differentially against the XLA dense kernel (ops/wgl3.py) and the oracle —
 the pallas kernel must agree bit-for-bit on every field, including the
-search metrics. The compiled path is exercised on real TPU by bench.py.
+search metrics. The compiled path is exercised on real TPU by
+chip_smoke.py and the benchmark (benchmark/).
 """
 
 from __future__ import annotations
