@@ -100,6 +100,10 @@ PHASE_GAUGE = "wgl.frontier_peak"
 # jtflow: metrics preregistered
 SCHED_COUNTERS = ("sched.steps_real", "sched.steps_padded",
                   "sched.launches",
+                  # Histories the launch pack expanded in one lockstep
+                  # pass, and histories it had to reslot first
+                  # (ops/encode.py stack_return_steps).
+                  "sched.pack.batched_histories", "sched.pack.reslotted",
                   "sched.cache_hits", "sched.cache_misses",
                   "encode.cache_hits", "encode.cache_misses")
 # Sparse active-tile sweep engine (ops/wgl3_sparse.py) accounting:

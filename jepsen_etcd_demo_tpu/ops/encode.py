@@ -34,6 +34,7 @@ surviving config has linearized the op, so its bit is cleared everywhere).
 
 from __future__ import annotations
 
+import dataclasses
 import heapq
 import itertools
 import time
@@ -710,4 +711,127 @@ def _encode_return_steps_host(enc: EncodedHistory) -> ReturnSteps:
         targets=slots[ret_pos].astype(np.int32),
         n_steps=len(ret_pos), n_ops=enc.n_ops, k_slots=k,
         max_pending=enc.max_pending, max_value=enc.max_value)
+
+
+#: The lockstep expansion of stack_return_steps pays a few numpy steps
+#: per event position whatever the batch (15-25 us each on a CPU core);
+#: the per-history one pays about 80 us a history plus its E x K work.
+#: Lockstep is chosen while the bucket's longest history has at most
+#: this many events per history in the bucket: below it lockstep won or
+#: tied at every measured shape (CPU micro-A/B in PERF.md).
+LOCKSTEP_EVENTS_PER_HISTORY = 8
+
+
+def lockstep_wins(n_histories: int, max_events: int) -> bool:
+    """Whether a bucket of `n_histories` whose longest history has
+    `max_events` events expands faster in lockstep than per history."""
+    return max_events <= LOCKSTEP_EVENTS_PER_HISTORY * n_histories
+
+
+def _fit_slots(enc: EncodedHistory, k_slots: int) -> EncodedHistory:
+    """`enc` at k_slots: its own event rows when every slot id is below
+    k_slots, else reslotted. Encoder output takes ids lowest-free, so
+    its ids already are what reslot_events gives at any k_slots >=
+    max_pending."""
+    ids = enc.events[: enc.n_events, 1]
+    if not ids.size or int(ids.max()) < k_slots:
+        return (enc if enc.k_slots == k_slots
+                else dataclasses.replace(enc, k_slots=k_slots))
+    get_metrics().counter("sched.pack.reslotted").add(1)
+    return reslot_events(enc, k_slots)
+
+
+def stack_return_steps(encs: Sequence[EncodedHistory], k_slots: int,
+                       r_cap: int, batch: Optional[int] = None,
+                       order: Optional[Sequence[int]] = None
+                       ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """One launch's return-step arrays, expanded straight into the stack:
+    tabs [B, r_cap, k_slots, 4] int32, act [B, r_cap, k_slots] bool and
+    tgt [B, r_cap] int32, B = `batch` (default len(encs)). Rows past
+    len(encs) are all-pad; with `order`, row j holds entry order[j].
+
+    Equal, bit for bit, to stacking each history's
+    encode_return_steps(...).padded_to(r_cap) in that order. A history
+    whose slot ids all lie below k_slots is expanded as it is (for
+    encoder output that is what reslot_events gives); any other is
+    reslotted first.
+
+    Buckets where lockstep_wins walk the event positions once for the
+    whole bucket: at an invoke the row's fields go into a running
+    [n, K, 4] slot table and its slot turns active; at a return the
+    table and active mask are copied out as that history's next step,
+    then the slot is freed (its fields stay, as in the per-history
+    expansion). Other buckets, and encode_mode 2, expand per history."""
+    from .limits import limits
+
+    t0 = time.monotonic()
+    n = len(encs)
+    b = n if batch is None else batch
+    k = k_slots
+    rows = (np.arange(n) if order is None
+            else np.argsort(np.asarray(order))[:n])
+    tabs = np.zeros((b, r_cap, k, 4), np.int32)
+    act = np.zeros((b, r_cap, k), bool)
+    tgt = np.full((b, r_cap), -1, np.int32)
+    lens = np.fromiter((e.n_events for e in encs), np.int64, n)
+    emax = int(lens.max()) if n else 0
+    if limits().encode_mode == 2 or not lockstep_wins(n, emax):
+        for i, e in enumerate(encs):
+            rs = encode_return_steps(_fit_slots(e, k))
+            if rs.n_steps > r_cap:
+                raise EncodeError(
+                    f"cannot pad {rs.n_steps} return steps to {r_cap}")
+            j, r = rows[i], rs.n_steps
+            tabs[j, :r] = rs.slot_tabs
+            act[j, :r] = rs.slot_active
+            tgt[j, :r] = rs.targets
+        return tabs, act, tgt
+
+    # Every real event row, history after history, in one array.
+    cat = np.concatenate([e.events[: e.n_events] for e in encs]
+                         + [np.zeros((0, EVENT_WIDTH), np.int32)])
+    starts = np.cumsum(lens) - lens
+    nonempty = np.flatnonzero(lens)
+    if nonempty.size:
+        top = np.maximum.reduceat(cat[:, 1], starts[nonempty])
+        for i in nonempty[top >= k]:
+            cat[starts[i]: starts[i] + lens[i]] = _fit_slots(encs[i], k).events
+    kind, slot = cat[:, 0], cat[:, 1]
+    rets = np.concatenate([[0], np.cumsum(kind == EV_RETURN)])
+    n_ret = rets[starts + lens] - rets[starts]
+    if n and int(n_ret.max()) > r_cap:
+        raise EncodeError(
+            f"cannot pad {int(n_ret.max())} return steps to {r_cap}")
+
+    # Every event in position order (history order within a position);
+    # every index below is a flat row number.
+    at_p, of_h = np.nonzero(np.arange(emax)[:, None] < lens)
+    at_k = kind[starts[of_h] + at_p]
+    is_inv, is_ret = at_k == EV_INVOKE, at_k == EV_RETURN
+    inv = starts[of_h[is_inv]] + at_p[is_inv]
+    ret = starts[of_h[is_ret]] + at_p[is_ret]
+    i_cell, i_fields = of_h[is_inv] * k + slot[inv], cat[inv, 2:6]
+    r_hist = of_h[is_ret]
+    r_cell = r_hist * k + slot[ret]
+    r_out = rows[r_hist] * r_cap + rets[ret] - rets[starts[r_hist]]
+    tgt.reshape(-1)[r_out] = slot[ret]
+    i_cut = np.searchsorted(at_p[is_inv], np.arange(emax + 1))
+    r_cut = np.searchsorted(at_p[is_ret], np.arange(emax + 1))
+    run_tab = np.zeros((n * k, 4), np.int32)
+    run_act = np.zeros(n * k, bool)
+    tab_rows, act_rows = run_tab.reshape(n, k * 4), run_act.reshape(n, k)
+    out_tab, out_act = tabs.reshape(-1, k * 4), act.reshape(-1, k)
+    for p in range(emax):
+        lo, hi = i_cut[p], i_cut[p + 1]
+        run_tab[i_cell[lo:hi]] = i_fields[lo:hi]
+        run_act[i_cell[lo:hi]] = True
+        lo, hi = r_cut[p], r_cut[p + 1]
+        at, rh = r_out[lo:hi], r_hist[lo:hi]
+        out_tab[at] = tab_rows[rh]
+        out_act[at] = act_rows[rh]
+        run_act[r_cell[lo:hi]] = False
+    m = get_metrics()
+    m.counter("sched.pack.batched_histories").add(n)
+    m.counter("encode.encode_s").add(time.monotonic() - t0)
+    return tabs, act, tgt
 

@@ -943,7 +943,7 @@ def prepare_dense(enc: EncodedHistory, model: Model,
         enc = reslot_events(enc, k)
     rs = encode_return_steps(enc)
     padded = rs.padded_to(step_bucket(rs.n_steps))
-    _record_padding([rs], padded.slot_tabs.shape[0])
+    _record_padding(rs.n_steps, 1, padded.slot_tabs.shape[0])
     return cfg, padded
 
 
@@ -978,7 +978,7 @@ def batch_steps3(encs: Sequence[EncodedHistory], model: Model,
     return cfg, steps, r_cap
 
 
-def _record_padding(steps, r_cap: int) -> None:
+def _record_padding(real: int, batch: int, r_cap: int) -> None:
     """Telemetry (obs/): per-launch step-bucket padding waste. Pads are
     cheap (the closure exits immediately; the fused kernel never even
     executes them) but the scan still walks them in the XLA path — the
@@ -988,8 +988,8 @@ def _record_padding(steps, r_cap: int) -> None:
     <2x bucket-waste bound is stated in), plus running real/padded step
     counters so consumers can aggregate an exact corpus-wide ratio
     instead of averaging per-launch gauges."""
-    real = int(sum(s.n_steps for s in steps))
-    total = len(steps) * int(r_cap)
+    real = int(real)
+    total = int(batch) * int(r_cap)
     if total:
         m = get_metrics()
         m.gauge("wgl.step_padding_pct").set(100.0 * (1.0 - real / total))
@@ -1005,7 +1005,14 @@ def stack_steps3(steps, r_cap: int):
     tabs = np.stack([p.slot_tabs for p in padded])
     act = np.stack([p.slot_active for p in padded])
     tgt = np.stack([p.targets for p in padded])
-    _record_padding(steps, r_cap)
+    return stage_steps3(tabs, act, tgt, sum(s.n_steps for s in steps))
+
+
+def stage_steps3(tabs: np.ndarray, act: np.ndarray, tgt: np.ndarray,
+                 real: int):
+    """Record a stacked launch's padding and bytes, then transfer it;
+    `real` is its real (non-pad) step count."""
+    _record_padding(real, tabs.shape[0], tabs.shape[1])
     nbytes = int(tabs.nbytes + act.nbytes + tgt.nbytes)
     get_metrics().counter("wgl.h2d_bytes").add(nbytes)
     return jnp.asarray(tabs), jnp.asarray(act), jnp.asarray(tgt)

@@ -117,18 +117,6 @@ def _batch_bucket(n: int, cap: int) -> int:
     return max(b, n)
 
 
-def _pad_rs(k_slots: int):
-    """An all-pad (0-step) ReturnSteps history for batch-axis padding:
-    every step a pad, trivially valid, zero search work."""
-    from ..ops.encode import ReturnSteps
-
-    return ReturnSteps(
-        slot_tabs=np.zeros((0, k_slots, 4), np.int32),
-        slot_active=np.zeros((0, k_slots), bool),
-        targets=np.zeros((0,), np.int32),
-        n_steps=0, n_ops=0, k_slots=k_slots, max_pending=0, max_value=0)
-
-
 def _dense_bucket_launcher(model, cfg, b: int, r: int):
     """Resolved packed checker for one (batch, step) bucket shape,
     through the KernelPlan layer (plan/dispatch.py plan_dense_batch —
@@ -263,7 +251,8 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
     copy) — the scheduler only changes HOW the dense majority is padded
     and launched, never which kernel checks what."""
     from ..ops import wgl3, wgl3_pallas
-    from ..ops.encode import encode_return_steps, reslot_events
+    from ..ops.encode import (encode_return_steps, reslot_events,
+                              stack_return_steps)
 
     if model is None:
         from ..models import CASRegister
@@ -299,20 +288,18 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                     general_idx = sorted(general_idx + dense_idx)
                     dense_idx = []
             lim = limits()
-            steps_of: dict[int, Any] = {}
+            # Step counts from the event rows; the short lane expands its
+            # return steps per launch, straight into the stacked arrays.
+            n_steps = {i: _return_count(encs[i]) for i in dense_idx}
             long_idx, short_idx = [], []
             for i in dense_idx:
-                e = encs[i]
-                rs = encode_return_steps(
-                    reslot_events(e, k) if e.k_slots != k else e)
-                steps_of[i] = rs
-                (long_idx if rs.n_steps > lim.long_scan_max
+                (long_idx if n_steps[i] > lim.long_scan_max
                  else short_idx).append(i)
             # The bucketed batched lanes: group by padded step length.
             buckets: dict[int, list[int]] = {}
             for i, r in zip(short_idx,
                             assign_step_buckets(
-                                [steps_of[i].n_steps for i in short_idx])):
+                                [n_steps[i] for i in short_idx])):
                 buckets.setdefault(r, []).append(i)
 
         if dense_idx:
@@ -322,7 +309,11 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
             # (wgl3.check_steps3_long -> sparse_plan); the per-mode step
             # counts land in the stats dict.
             for i in long_idx:
-                one = wgl3_pallas.run_long_dense(steps_of[i], model, cfg)
+                e = encs[i]
+                one = wgl3_pallas.run_long_dense(
+                    encode_return_steps(
+                        reslot_events(e, k) if e.k_slots != k else e),
+                    model, cfg)
                 results[i] = one
                 kernels.add(one["kernel"])
                 stats.record_sweep(one)
@@ -330,7 +321,7 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
             # Every bucket's launches dispatch async and are fetched at
             # drain.
             def _fetch_launch(entry):
-                part, part_steps, dev, perm = entry
+                part, part_encs, dev, perm = entry
                 try:
                     with obs.phase("sched.fetch_wait"):
                         fetched = np.asarray(dev)
@@ -351,7 +342,7 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                         rows = fetched[np.argsort(perm)[:len(part)]]
                     out = wgl3.unpack_np(rows)
                     for i, one in zip(part, wgl3.assemble_batch_results(
-                            out, part_steps, cfg)):
+                            out, part_encs, cfg)):
                         results[i] = one
 
             # In-flight launch window (plan/dispatch.py LaunchPipeline,
@@ -371,7 +362,7 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                 for c0 in range(0, len(idxs), chunk):
                     with obs.phase("sched.pack"):
                         part = idxs[c0:c0 + chunk]
-                        part_steps = [steps_of[i] for i in part]
+                        part_encs = [encs[i] for i in part]
                         # Bucket FIRST, then derive the sharding multiple
                         # from the bucketed size: the launcher picks the
                         # sharded kernel by the PADDED batch, so a part the
@@ -385,8 +376,10 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                         b = (b0 + mult - 1) // mult * mult
                         run, plan_obj = _dense_bucket_launcher(model, cfg,
                                                                b, r)
-                        padded = part_steps + [_pad_rs(k)] * (b - len(part))
-                        real = sum(s.n_steps for s in part_steps)
+                        # Batch pads are all-pad (0-step) lanes.
+                        counts = [n_steps[i] for i in part] + [0] * (
+                            b - len(part))
+                        real = sum(counts)
                         perm = None
                         shard_real = None
                         n_shards = plan_obj.n_shards
@@ -396,18 +389,17 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
                                 # batch so contiguous per-shard blocks carry
                                 # balanced REAL steps (pads interleave
                                 # instead of stacking on the tail shards).
-                                perm = lpt_shard_order(
-                                    [s.n_steps for s in padded], n_shards)
-                                if perm == list(range(len(padded))):
+                                perm = lpt_shard_order(counts, n_shards)
+                                if perm == list(range(b)):
                                     perm = None
                                 else:
-                                    padded = [padded[j] for j in perm]
-                            shard_real = _shard_real_steps(
-                                [s.n_steps for s in padded], n_shards)
-                        arrays = wgl3.stack_steps3(padded, r)
+                                    counts = [counts[j] for j in perm]
+                            shard_real = _shard_real_steps(counts, n_shards)
+                        arrays = wgl3.stage_steps3(*stack_return_steps(
+                            part_encs, k, r, batch=b, order=perm), real)
                     with obs.phase("sched.dispatch"):
                         dev = run(*arrays)
-                    pipe.submit((part, part_steps, dev, perm))
+                    pipe.submit((part, part_encs, dev, perm))
                     stats.record_launch(real, b, r, shard_real)
                     kernels.add(plan_obj.label)
             pipe.drain()
@@ -425,6 +417,13 @@ def check_corpus(encs: Sequence, model=None, f_cap: int = 256
         return results, kernel, stats.to_dict()
 
 
+def _return_count(e) -> int:
+    """An encoded history's return steps, counted from its event rows."""
+    from ..ops.encode import EV_RETURN
+
+    return int(np.count_nonzero(e.events[: e.n_events, 0] == EV_RETURN))
+
+
 def _check_general(encs, general_idx, model, results, kernels,
                    f_cap: int) -> None:
     """The non-dense partition (wide pending sets / huge values): the
@@ -433,16 +432,11 @@ def _check_general(encs, general_idx, model, results, kernels,
     per-history exact ladder for whatever the tiers couldn't settle —
     the same tail policy as check_batch_encoded_auto."""
     from ..ops import wgl3_pallas
-    from ..ops.encode import EV_RETURN
-
-    def return_count(e) -> int:
-        ev = np.asarray(e.events[: e.n_events])
-        return int((ev[:, 0] == EV_RETURN).sum()) if e.n_events else 0
 
     groups: dict[int, list[int]] = {}
     for i, r in zip(general_idx,
                     assign_step_buckets(
-                        [return_count(encs[i]) for i in general_idx])):
+                        [_return_count(encs[i]) for i in general_idx])):
         groups.setdefault(r, []).append(i)
     overflow_seeds: list[tuple[int, int]] = []   # (idx, seed f_cap)
     too_long_all: list[int] = []

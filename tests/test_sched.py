@@ -11,7 +11,7 @@ import pytest
 
 from jepsen_etcd_demo_tpu import obs, sched
 from jepsen_etcd_demo_tpu.models import CASRegister
-from jepsen_etcd_demo_tpu.ops import wgl3, wgl3_pallas
+from jepsen_etcd_demo_tpu.ops import encode, wgl3, wgl3_pallas
 from jepsen_etcd_demo_tpu.ops.encode import (encode_register_history,
                                              encode_return_steps)
 from jepsen_etcd_demo_tpu.utils.fuzz import (gen_register_history,
@@ -401,13 +401,16 @@ class TestLaunchCounters:
         monkeypatch.setenv(MESH_SHAPE_ENV, str(n_shards))
         encs = _corpus_of(CORPORA[corpus], seed=len(corpus) + n_shards)
         launched = []
-        stack = wgl3.stack_steps3
+        stack = encode.stack_return_steps
 
-        def spy(steps, r_cap):
-            launched.append(([s.n_steps for s in steps], int(r_cap)))
-            return stack(steps, r_cap)
+        def spy(encs, k_slots, r_cap, batch=None, order=None):
+            arrays = stack(encs, k_slots, r_cap, batch=batch, order=order)
+            # Real steps of every lane as launched: its non-pad targets.
+            launched.append(([int(n) for n in (arrays[2] >= 0).sum(axis=1)],
+                             int(r_cap)))
+            return arrays
 
-        monkeypatch.setattr(wgl3, "stack_steps3", spy)
+        monkeypatch.setattr(encode, "stack_return_steps", spy)
         with obs.capture() as cap:
             results, _, stats = sched.check_corpus(encs, MODEL)
         snap = cap.metrics.snapshot()
@@ -439,3 +442,210 @@ class TestLaunchCounters:
                 stats["steps_padded"]) == (len(launched), want_real,
                                            want_padded)
         assert all(r is not None for r in results)
+
+
+# -- batched launch pack (ops/encode.py stack_return_steps) ------------------
+
+def _per_key_stack(encs, k, r_cap, batch=None, order=None):
+    """The per-key pack stack_return_steps stands in for: reslot where
+    the width differs, expand, append all-pad lanes, permute, pad to
+    r_cap and stack (as numpy)."""
+    from jepsen_etcd_demo_tpu.ops.encode import ReturnSteps, reslot_events
+
+    steps = [encode_return_steps(reslot_events(e, k) if e.k_slots != k
+                                 else e) for e in encs]
+    pad = ReturnSteps(np.zeros((0, k, 4), np.int32), np.zeros((0, k), bool),
+                      np.zeros((0,), np.int32), 0, 0, k, 0)
+    steps += [pad] * ((len(encs) if batch is None else batch) - len(encs))
+    if order is not None:
+        steps = [steps[j] for j in order]
+    return tuple(np.asarray(a) for a in wgl3.stack_steps3(steps, r_cap))
+
+
+def _bucket_shape(encs):
+    """(k, r_cap) of one launch over encs, as check_corpus derives them."""
+    k = max(wgl3.tight_k_slots(e) for e in encs)
+    return k, wgl3.step_bucket(max(sched.engine._return_count(e)
+                                   for e in encs))
+
+
+def _fuzz_encs(seed, n, lo, hi, p_info, k_slots=24):
+    rng = random.Random(seed)
+    return [encode_register_history(
+        gen_register_history(rng, n_ops=rng.randrange(lo, hi),
+                             n_procs=rng.randrange(2, 8), p_info=p_info),
+        k_slots=k_slots) for _ in range(n)]
+
+
+def _benchmark_pool():
+    """One unit of the register test's 1024-key pool, encoded per key in
+    place as the independent checker does."""
+    import json
+    from pathlib import Path
+
+    from benchmark import load
+    from jepsen_etcd_demo_tpu.checkers.independent import route_by_key
+    from jepsen_etcd_demo_tpu.checkers.linearizable import Linearizable
+    from jepsen_etcd_demo_tpu.ops.op import Op
+
+    root = Path(__file__).resolve().parents[1] / "benchmark"
+    cfg = json.loads((root / "configs/etcd-register.json").read_text())
+    mix = json.loads((root / "traffic/indep-1024.json").read_text())
+    tr = load.Traffic(cfg, mix, seed=2800000019)
+    lin = Linearizable(cfg["model"])
+    return [lin.encode(h, keyed=True) for h in route_by_key(
+        load.program_ops(tr.unit_keys(0), Op, keyed=True)).values()]
+
+
+def _hand_built_wide():
+    """Two overlapping ops named by slot ids 20 and 7 of a 24-slot table:
+    id 20 reaches a tight width, so the pack must reslot it."""
+    from jepsen_etcd_demo_tpu.ops.encode import (EV_INVOKE, EV_RETURN,
+                                                 EncodedHistory, F_READ,
+                                                 F_WRITE, NIL)
+
+    ev = np.array([[EV_INVOKE, 20, F_WRITE, 3, 0, NIL],
+                   [EV_INVOKE, 7, F_READ, 0, 0, 3],
+                   [EV_RETURN, 20, F_WRITE, 3, 0, NIL],
+                   [EV_INVOKE, 20, F_WRITE, 4, 0, NIL],
+                   [EV_RETURN, 7, F_READ, 0, 0, 3],
+                   [EV_RETURN, 20, F_WRITE, 4, 0, NIL]], np.int32)
+    return EncodedHistory(events=ev, n_events=len(ev), n_ops=3, k_slots=24,
+                          max_pending=2, max_value=4)
+
+
+def _pack_case(name):
+    """(encs, batch, order) of one bit-identity case."""
+    empty = encode_register_history([], k_slots=24)
+    if name == "indep-1024":
+        return _benchmark_pool(), None, None
+    if name == "fuzz-info":
+        return _fuzz_encs(29, 48, 5, 60, p_info=0.15), None, None
+    if name == "no-return":
+        from jepsen_etcd_demo_tpu.ops.op import INVOKE, Op
+
+        # Five writes invoked and never completed: pending forever.
+        h = [Op(type=INVOKE, f="write", value=v, process=v, index=v)
+             for v in range(5)]
+        return [encode_register_history(h, k_slots=24)] + _fuzz_encs(
+            3, 5, 5, 20, p_info=0.0), None, None
+    if name == "empty":
+        return [empty], None, None
+    if name == "mixed-lengths":
+        return (_fuzz_encs(5, 30, 4, 10, p_info=0.02)
+                + _fuzz_encs(6, 4, 120, 160, p_info=0.02)), None, None
+    if name == "pad-rows":
+        return [empty] + _fuzz_encs(8, 11, 5, 40, p_info=0.05), 16, None
+    if name == "lpt-order":
+        encs = _fuzz_encs(9, 13, 5, 60, p_info=0.05)
+        counts = [sched.engine._return_count(e) for e in encs] + [0] * 3
+        order = sched.lpt_shard_order(counts, 4)
+        assert order != list(range(16))
+        return encs, 16, order
+    assert name == "reslot"
+    return [_hand_built_wide()] + _fuzz_encs(4, 6, 5, 30, p_info=0.0), \
+        None, None
+
+
+PACK_CASES = ("indep-1024", "fuzz-info", "no-return", "empty",
+              "mixed-lengths", "pad-rows", "lpt-order", "reslot")
+
+
+class TestBatchedPack:
+    @pytest.mark.parametrize("lockstep", [True, False],
+                             ids=["lockstep", "per-history"])
+    @pytest.mark.parametrize("case", PACK_CASES)
+    def test_bit_identical_to_the_per_key_pack(self, case, lockstep,
+                                               monkeypatch):
+        """Both expansions of stack_return_steps equal the per-key
+        reslot + expand + pad + stack, bit for bit; the counters say
+        which expansion ran and how many histories were reslotted."""
+        encs, batch, order = _pack_case(case)
+        k, r_cap = _bucket_shape(encs)
+        monkeypatch.setattr(encode, "LOCKSTEP_EVENTS_PER_HISTORY",
+                            10 ** 9 if lockstep else -1)
+        with obs.capture() as cap:
+            got = encode.stack_return_steps(encs, k, r_cap, batch=batch,
+                                            order=order)
+        snap = cap.metrics.snapshot()
+        want = _per_key_stack(encs, k, r_cap, batch, order)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+        assert snap["sched.pack.batched_histories"]["value"] == (
+            len(encs) if lockstep else 0)
+        assert snap["sched.pack.reslotted"]["value"] == (
+            1 if case == "reslot" else 0)
+
+    @pytest.mark.parametrize("seed", [1, 2, 3])
+    def test_reslot_is_identity_on_encoder_output(self, seed):
+        """The encoder takes slot ids lowest-free, so reslotting its
+        output to any width that holds max_pending changes no id."""
+        from jepsen_etcd_demo_tpu.ops.encode import reslot_events
+
+        for e in _fuzz_encs(seed, 24, 5, 120, p_info=0.1):
+            for k in range(e.max_pending, e.k_slots + 1):
+                np.testing.assert_array_equal(
+                    reslot_events(e, k).events, e.events[: e.n_events])
+
+    def test_shape_rule_sends_a_10k_history_per_history(self):
+        """One 10k-op history expands per history (the lockstep pass
+        would walk its ~14,000 positions for one lane); a bucket of the
+        register test's 1024 keys expands in lockstep."""
+        import json
+        from pathlib import Path
+
+        from benchmark.gen import register_history
+        from benchmark.load import program_ops
+        from jepsen_etcd_demo_tpu.ops.op import Op
+
+        root = Path(__file__).resolve().parents[1] / "benchmark"
+        g = json.loads((root / "configs/etcd-register-10k.json")
+                       .read_text())["generator"]
+        h = register_history(random.Random(10), n_ops=g["ops_per_key"],
+                             n_procs=g["threads_per_key"],
+                             value_range=g["value_range"], p_read=g["p_read"],
+                             p_write=g["p_write"], p_info=g["p_info"],
+                             p_fail_read=g["p_fail_read"])
+        e = encode_register_history(program_ops([h], Op, keyed=False),
+                                    k_slots=24)
+        assert e.n_events > 10000
+        assert not encode.lockstep_wins(1, e.n_events)
+        assert encode.lockstep_wins(1024, 200)
+        k, r_cap = _bucket_shape([e])
+        with obs.capture() as cap:
+            got = encode.stack_return_steps([e], k, r_cap)
+        assert cap.metrics.snapshot()[
+            "sched.pack.batched_histories"]["value"] == 0
+        for g_, w in zip(got, _per_key_stack([e], k, r_cap)):
+            np.testing.assert_array_equal(g_, w)
+
+    @pytest.mark.parametrize("n_shards", [1, 2, 4])
+    def test_corpus_results_equal_the_per_key_pack(self, n_shards,
+                                                   monkeypatch):
+        """check_corpus over a 1024-key corpus on a virtual mesh gives
+        every key the verdict and search counts it gets when its launch
+        is packed key by key."""
+        from jepsen_etcd_demo_tpu.parallel.mesh import MESH_SHAPE_ENV
+
+        monkeypatch.setenv(MESH_SHAPE_ENV, str(n_shards))
+        encs = _fuzz_encs(40 + n_shards, 1024, 4, 24, p_info=0.02)
+        rng = random.Random(n_shards)
+        for i in rng.sample(range(len(encs)), 64):
+            h = gen_register_history(random.Random(i), n_ops=16, n_procs=3)
+            encs[i] = encode_register_history(mutate_history(rng, h),
+                                              k_slots=24)
+        with obs.capture() as cap:
+            batched, _, stats = sched.check_corpus(encs, MODEL)
+        assert cap.metrics.snapshot()[
+            "sched.pack.batched_histories"]["value"] == len(encs)
+        monkeypatch.setattr(encode, "stack_return_steps", _per_key_stack)
+        per_key, _, stats_ref = sched.check_corpus(encs, MODEL)
+        assert stats == stats_ref
+        assert any(not r["valid"] for r in per_key)
+        for a, b in zip(batched, per_key):
+            assert ({f: a[f] for f in ("valid", "dead_step", "max_frontier",
+                                       "configs_explored")}
+                    == {f: b[f] for f in ("valid", "dead_step",
+                                          "max_frontier",
+                                          "configs_explored")})
